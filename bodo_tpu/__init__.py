@@ -25,12 +25,16 @@ jax.config.update("jax_enable_x64", True)
 
 from bodo_tpu.config import config, set_config, set_verbose_level  # noqa: E402
 
-if config.compile_cache_dir:
+if jax.config.jax_compilation_cache_dir:
     # persistent XLA compilation cache: compiled kernels survive process
     # restarts (the reference's @bodo.jit(cache=True) Numba on-disk
-    # cache, exercised by its caching_tests/)
-    jax.config.update("jax_compilation_cache_dir", config.compile_cache_dir)
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.1)
+    # cache, exercised by its caching_tests/). JAX itself reads
+    # JAX_COMPILATION_CACHE_DIR; the library never places the cache, it
+    # only lowers the write thresholds (never raises what the caller
+    # set) and counts hits and misses.
+    jax.config.update(
+        "jax_persistent_cache_min_compile_time_secs",
+        min(jax.config.jax_persistent_cache_min_compile_time_secs, 0.1))
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
     from bodo_tpu.utils import tracing as _tracing
     _tracing.install_compile_cache_listener()

@@ -135,15 +135,15 @@ def _src(eqn) -> str:
 
 
 def _sub_jaxprs(params: dict) -> List[Tuple[str, Any]]:
-    """(param_key, jax.core.Jaxpr) for every sub-jaxpr hiding in an
+    """(param_key, Jaxpr) for every sub-jaxpr hiding in an
     eqn's params (jaxpr / closed jaxpr / tuples of either)."""
-    import jax
+    from jax.extend import core as jcore
     out: List[Tuple[str, Any]] = []
 
     def _coerce(v):
-        if isinstance(v, jax.core.ClosedJaxpr):
+        if isinstance(v, jcore.ClosedJaxpr):
             return v.jaxpr
-        if isinstance(v, jax.core.Jaxpr):
+        if isinstance(v, jcore.Jaxpr):
             return v
         return None
 
@@ -167,8 +167,8 @@ def _aval_bytes(aval) -> int:
 
 
 def _is_literal(v) -> bool:
-    import jax
-    return isinstance(v, jax.core.Literal)
+    from jax.extend import core as jcore
+    return isinstance(v, jcore.Literal)
 
 
 def _collective_facets(eqn, path: str) -> dict:
@@ -616,23 +616,16 @@ def _self_check_programs():
                   jax.jit(lambda x: jnp.cumsum(x), donate_argnums=(0,)),  # shardcheck: ignore[unregistered-jit]
                   (jnp.arange(8, dtype=jnp.float32),), {}))
 
-    devs = jax.devices()
-    try:
-        from jax.sharding import Mesh, PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
-        mesh = Mesh(devs[:1], ("x",))
+    from jax.sharding import Mesh, PartitionSpec as P
+    mesh = Mesh(jax.devices()[:1], ("x",))
 
-        def body(x):
-            # traced, never dispatched: the enclosing try guards mesh
-            # construction on meshless backends, not the dispatch
-            return jax.lax.psum(x, "x")  # shardcheck: ignore[swallowed-collective]
+    def body(x):
+        return jax.lax.psum(x, "x")
 
-        fn = jax.jit(shard_map(body, mesh=mesh, in_specs=(P(),),  # shardcheck: ignore[unregistered-jit]
-                               out_specs=P(), check_rep=False))
-        progs.append(("selfcheck:collective", fn,
-                      (jnp.arange(4, dtype=jnp.float32),), {}))
-    except Exception:  # noqa: BLE001 - no mesh on this backend
-        pass
+    fn = jax.jit(jax.shard_map(body, mesh=mesh, in_specs=(P(),),  # shardcheck: ignore[unregistered-jit]
+                               out_specs=P(), check_vma=False))
+    progs.append(("selfcheck:collective", fn,
+                  (jnp.arange(4, dtype=jnp.float32),), {}))
     return progs
 
 

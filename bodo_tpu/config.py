@@ -163,14 +163,6 @@ class Config:
         default_factory=lambda: _env_int(
             "BODO_TPU_DEVICE_DECODE_MIN_BYTES", 1 << 20)
     )
-    # Total wall-clock budget (seconds) for the bench accelerator probe
-    # across ALL retry attempts; <= 0 means the per-attempt
-    # timeout x attempts product is the only cap. Guards against the
-    # r05-style retry storm (6 x 75s timeouts before CPU fallback).
-    bench_probe_budget_s: float = field(
-        default_factory=lambda: _env_float("BODO_TPU_BENCH_PROBE_BUDGET",
-                                           150.0)
-    )
     # -- frontend ------------------------------------------------------------
     # Fall back to real pandas for unsupported args (reference:
     # bodo/pandas/utils.py:346 check_args_fallback).
@@ -328,13 +320,6 @@ class Config:
     # Empty = in-process observations only (no persistence).
     stats_store_dir: str = field(
         default_factory=lambda: _env_str("BODO_TPU_STATS_DIR", "")
-    )
-    # Persistent XLA compilation cache directory (the @jit(cache=True)
-    # analogue — reference: Numba on-disk JIT cache, caching_tests/).
-    # Set to a path to survive process restarts; empty disables. Applied
-    # at import and again by set_config(compile_cache_dir=...).
-    compile_cache_dir: str = field(
-        default_factory=lambda: _env_str("BODO_TPU_COMPILE_CACHE_DIR", "")
     )
     # SQL plan cache directory (analogue BODO_SQL_PLAN_CACHE_DIR).
     sql_plan_cache_dir: str = field(
@@ -653,23 +638,6 @@ def set_config(**kwargs) -> None:
                 os.environ["BODO_TPU_FAULTS"] = v
             else:
                 os.environ.pop("BODO_TPU_FAULTS", None)
-        if k == "compile_cache_dir" and v:
-            import jax
-            jax.config.update("jax_compilation_cache_dir", v)
-            jax.config.update(
-                "jax_persistent_cache_min_compile_time_secs", 0.1)
-            jax.config.update(
-                "jax_persistent_cache_min_entry_size_bytes", 0)
-            try:
-                # jax latches cache-in-use on the FIRST compile of the
-                # process; without a reset, enabling the dir after any
-                # compile has happened is silently a no-op
-                from jax._src import compilation_cache
-                compilation_cache.reset_cache()
-            except Exception:
-                pass
-            from bodo_tpu.utils import tracing
-            tracing.install_compile_cache_listener()
         if k == "io_threads":
             # drop the shared executor so the next I/O rebuilds it at
             # the new width

@@ -525,27 +525,6 @@ def _hybrid_expand_body(jnp, data, starts, is_rle, vals, bits, bw,
     return jnp.where(rv >= 0, rv, packed)
 
 
-def _hybrid_expand_route(jnp, data, starts, is_rle, vals, bits, bw,
-                         n_bucket):
-    """Run expansion through the Pallas hybrid kernel when its gate is
-    open (ops/pallas_kernels.hybrid_expand — the RLE/bit-packed decode
-    inner loop on-device), else the XLA searchsorted body. Traced inside
-    the jitted page program, so engagement is per compiled spec."""
-    from bodo_tpu.ops import pallas_kernels as PK
-    try:
-        out = PK.hybrid_expand(data, starts, is_rle, vals, bits, bw,
-                               n_bucket)
-    except Exception as e:  # trace failure -> permanent XLA fallback
-        PK.disable_runtime(f"hybrid_expand: {e}")
-        out = None
-    if out is not None:
-        from bodo_tpu.runtime import io_pool
-        io_pool.count("pallas_expand_traced")
-        return out
-    return _hybrid_expand_body(jnp, data, starts, is_rle, vals, bits,
-                               bw, n_bucket)
-
-
 def _assemble_plain_body(jnp, lax, data, val_off, itemsize, out_dtype,
                          n_bucket):
     """PLAIN fixed-width: dynamic-slice the dense value region, assemble
@@ -592,7 +571,7 @@ def _build_page_program(spec: _PageSpec):
         i = jnp.arange(spec.n_bucket, dtype=jnp.int32)
         in_rows = i < n_values
         if spec.has_defs:
-            levels = _hybrid_expand_route(
+            levels = _hybrid_expand_body(
                 jnp, data, dstarts, disrle, dvals, dbits, 1, spec.n_bucket)
             valid = (levels == 1) & in_rows
         else:
@@ -610,7 +589,7 @@ def _build_page_program(spec: _PageSpec):
                                          spec.n_bucket)
             vals_at = dense[pos]
         elif spec.kind == "dict":
-            codes = _hybrid_expand_route(
+            codes = _hybrid_expand_body(
                 jnp, data, vstarts, visrle, vvals, vbits, spec.bit_width,
                 spec.n_bucket)
             codes = codes[pos]
@@ -629,7 +608,7 @@ def _build_page_program(spec: _PageSpec):
             vals_at = ((data[jnp.clip(byte0, 0, nb - 1)]
                         >> (bits_i & 7).astype(jnp.uint8)) & 1) > 0
         elif spec.kind == "boolrle":
-            dense = _hybrid_expand_route(
+            dense = _hybrid_expand_body(
                 jnp, data, vstarts, visrle, vvals, vbits, 1, spec.n_bucket)
             vals_at = dense[pos] > 0
         else:  # pragma: no cover - spec construction guards this
@@ -707,22 +686,7 @@ def _run_page_program(spec: _PageSpec, page_bytes: bytes, n_values: int,
                jnp.asarray(db), jnp.asarray(vs), jnp.asarray(vr),
                jnp.asarray(vv), jnp.asarray(vb), np.int32(val_off),
                jnp.asarray(dpad))
-    try:
-        out = fn(*args_in)
-    except Exception as e:
-        # a pallas-routed page program can fail at backend compile time
-        # (e.g. Mosaic rejecting the dynamic byte gathers): permanently
-        # fall back and rebuild this spec on the XLA body once
-        from bodo_tpu.ops import pallas_kernels as PK
-        if PK._runtime_disabled and not PK.FORCE_INTERPRET:
-            raise
-        PK.disable_runtime(f"page program {spec.kind}: {e}")
-        with _programs_lock:
-            _programs.pop(spec)
-        fn = _build_page_program(spec)
-        with _programs_lock:
-            _programs[spec] = fn
-        out = fn(*args_in)
+    out = fn(*args_in)
     if compiled:
         h = _programs.handle_for(spec)
         progcheck.check_jit(fn, args_in,

@@ -151,11 +151,7 @@ def _group_segments(keys: Sequence[Tuple], count, row_valid=None):
     operands: list = []
     for d, v in keys:
         operands.extend(SE.key_operands(d, v, padmask=padmask))
-    num_key_ops = len(operands)
-    operands.append(jnp.arange(cap))
-    sorted_ops = lax.sort(tuple(operands), num_keys=num_key_ops,
-                          is_stable=True)
-    perm = sorted_ops[-1]
+    perm = SE.stable_argsort(operands)
     padmask_s = padmask[perm]
 
     pos = jnp.arange(cap)
@@ -635,9 +631,7 @@ def _hashed_sort_groups(gkeys, gvals, gvalid, out_capacity: int):
     operands: list = []
     for a in gkeys:
         operands.extend(SE.key_operands(a, None, padmask=gvalid))
-    nko = len(operands)
-    operands.append(jnp.arange(ng_cap))
-    gperm = lax.sort(tuple(operands), num_keys=nko, is_stable=True)[-1]
+    gperm = SE.stable_argsort(operands)
 
     def scatter(a):
         z = jnp.zeros((out_capacity,), dtype=a.dtype)

@@ -29,7 +29,6 @@ from typing import List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax import lax
 
 from bodo_tpu.ops import kernels as K
 from bodo_tpu.ops import sort_encoding as SE
@@ -72,12 +71,10 @@ def _union_gids(probe_keys, build_keys, p_padmask, b_padmask,
             dz = jnp.where(nf, jnp.zeros((), d.dtype), d)
             rank = jnp.where(nf, jnp.uint8(2), jnp.uint8(1))
             rank = jnp.where(unionmask, rank, jnp.uint8(3))
-            operands.extend([rank, SE.encode_value(dz)])
+            operands.extend([(rank, 2), SE.encode_field(dz)])
         else:
             operands.extend(SE.key_operands(d, v, padmask=unionmask))
-    nko = len(operands)
-    operands.append(jnp.arange(ucap))
-    perm = lax.sort(tuple(operands), num_keys=nko, is_stable=True)[-1]
+    perm = SE.stable_argsort(operands)
     umask_s = unionmask[perm]
     pos = jnp.arange(ucap)
     diff = jnp.zeros(ucap, dtype=bool).at[0].set(True)
@@ -152,8 +149,8 @@ def _join_plan(probe_keys, build_keys, probe_count, build_count,
         unresolved = jnp.zeros((), bool)
 
     # order build rows by gid (sentinel rows last)
-    gid_b_s, b_perm = lax.sort((gid_b, jnp.arange(bcap)), num_keys=1,
-                               is_stable=True)
+    b_perm = SE.stable_argsort([(gid_b.astype(jnp.uint32), 32)])
+    gid_b_s = gid_b[b_perm]
     bc = jax.ops.segment_sum(jnp.ones(bcap, dtype=jnp.int64),
                              jnp.minimum(gid_b, ucap),
                              num_segments=ucap + 1)

@@ -10,8 +10,9 @@ recipe). This is the TPU-native replacement for the reference's
 hash-table accumulate loop (bodo/libs/groupby/_groupby.cpp update step).
 
 Kernels run on TPU only (gated by `use_pallas()`); every caller keeps an
-XLA `segment_sum` fallback, and correctness is tested on CPU through
-`interpret=True`.
+XLA body for shapes outside a kernel's gate, correctness is tested on
+CPU through `interpret=True`, and tests/test_chip_compile.py compiles
+each kernel for a described v5e.
 """
 
 from __future__ import annotations
@@ -29,13 +30,19 @@ MAX_MATMUL_SLOTS = 4096
 
 _I0 = np.int32(0)  # int32 BlockSpec index constant (see in_specs comment)
 
+# every [N, 1] (or [N, <128]) operand is laid out 128 lanes wide in HBM,
+# 512 bytes a row (the v5e compiler's memory_analysis shows it as
+# temporaries); a gate admits only row counts whose padded operands fit
+_PADDED_ROW_BYTES = 512
+_PADDED_BUDGET = 8 << 30
+
+
+def _rows_fit(n: int, n_operands: int) -> bool:
+    return n * n_operands * _PADDED_ROW_BYTES <= _PADDED_BUDGET
+
 # test hook: run kernels through the pallas interpreter on CPU
 FORCE_INTERPRET = False
 
-
-# set when a kernel fails to compile/run on the actual backend: callers
-# permanently fall back to the XLA path for the rest of the process
-_runtime_disabled = False
 
 # number of times the pallas MXU path was TRACED into a jitted groupby
 # or fused pipeline stage (trace-time, not per-execution:
@@ -64,21 +71,11 @@ def reset_trace_counts() -> None:
         trace_counts[k] = 0
 
 
-def disable_runtime(reason: str) -> None:
-    global _runtime_disabled
-    _runtime_disabled = True
-    import sys
-    print(f"[bodo_tpu] pallas kernels disabled: {reason}", file=sys.stderr)
-
-
 def use_pallas() -> bool:
-    """Pallas kernels engage only on real TPU backends."""
-    if _runtime_disabled:
-        return False
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except Exception:  # pragma: no cover
-        return False
+    """Pallas kernels engage only on real TPU backends. A kernel that a
+    gate admits and the chip's compiler refuses raises: nothing demotes
+    the process to the XLA bodies behind its back."""
+    return jax.devices()[0].platform == "tpu"
 
 
 def _round_up(n: int, m: int) -> int:
@@ -225,7 +222,8 @@ def matmul_gather(codes, lut, interpret: Optional[bool] = None):
     so this caps the build side at 16M rows (checked by the caller's
     gate, not here)."""
     interp = bool(interpret) if interpret is not None else FORCE_INTERPRET
-    if (use_pallas() or interp) and lut.shape[0] <= MAX_MATMUL_SLOTS:
+    if ((use_pallas() or interp) and lut.shape[0] <= MAX_MATMUL_SLOTS
+            and _rows_fit(codes.shape[0], 2)):
         _engage("gather")
         return _matmul_gather_kernel(codes, lut, lut.shape[0],
                                      interpret=interp)
@@ -243,7 +241,8 @@ def bucket_counts(dest, ok, num_buckets: int,
     gate guarantees. Returns int32 [num_buckets]."""
     interp = bool(interpret) if interpret is not None else FORCE_INTERPRET
     if ((use_pallas() or interp) and num_buckets <= MAX_MATMUL_SLOTS
-            and dest.shape[0] < MAX_GATHER_VALUE):
+            and dest.shape[0] < MAX_GATHER_VALUE
+            and _rows_fit(dest.shape[0], 2)):
         _engage("partition")
         vals = ok.astype(jnp.float32)[:, None]
         sums = matmul_groupby_sum(dest.astype(jnp.int32), vals,
@@ -262,7 +261,8 @@ def dense_accumulate(codes, cols: Sequence, ok_masks: Sequence,
     columns. Elsewhere: per-column XLA segment_sum (scatter). Returns a
     list of f32/f64 [n_slots] arrays aligned with `cols`."""
     interp = bool(interpret) if interpret is not None else FORCE_INTERPRET
-    if (use_pallas() or interp) and n_slots <= MAX_MATMUL_SLOTS:
+    if ((use_pallas() or interp) and n_slots <= MAX_MATMUL_SLOTS
+            and _rows_fit(codes.shape[0], 2)):
         _engage("groupby")
         vals = jnp.stack(
             [jnp.where(ok, c, 0).astype(jnp.float32)
@@ -341,7 +341,9 @@ def _hash_probe_kernel(h_m, step_m, probe_planes, active0, slot_tab,
 
         def cond(st):
             r, idx, active = st
-            return (r < max_rounds) & jnp.any(active > 0)
+            # a 32-bit reduction: Mosaic has no scalar from the bool
+            # any() that x64 mode widens
+            return (r < max_rounds) & (jnp.max(active) > np.float32(0))
 
         def body(st):
             r, idx, active = st
@@ -405,7 +407,7 @@ def hash_probe(build_codes: Sequence, owner, probe_codes: Sequence, ok,
     XLA while_loop)."""
     interp = bool(interpret) if interpret is not None else FORCE_INTERPRET
     if not ((use_pallas() or interp) and T <= MAX_MATMUL_SLOTS
-            and T // 2 < MAX_GATHER_VALUE):
+            and T // 2 < MAX_GATHER_VALUE and _rows_fit(h.shape[0], 6)):
         return None
     _engage("probe")
     maskT = np.uint64(T - 1)
@@ -515,7 +517,8 @@ def partition_rank(dest, ok, num_buckets: int,
     counts int32 [num_buckets]) or None when the gate is closed."""
     interp = bool(interpret) if interpret is not None else FORCE_INTERPRET
     if not ((use_pallas() or interp) and num_buckets <= MAX_MATMUL_SLOTS
-            and dest.shape[0] < MAX_GATHER_VALUE):
+            and dest.shape[0] < MAX_GATHER_VALUE
+            and _rows_fit(dest.shape[0], 3)):
         return None
     _engage("partition")
     return _partition_rank_kernel(dest.astype(jnp.int32), ok,
@@ -523,112 +526,8 @@ def partition_rank(dest, ok, num_buckets: int,
 
 
 # ---------------------------------------------------------------------------
-# RLE/bit-packed hybrid run expansion + dictionary gather (device decode)
+# dictionary gather (device decode)
 # ---------------------------------------------------------------------------
-
-# run-table bound for the in-kernel searchsorted (a [BLK, R] compare)
-MAX_EXPAND_RUNS = 2048
-
-
-# shardcheck: ignore[unregistered-jit]
-@functools.partial(jax.jit, static_argnames=("bw", "n_bucket", "n_runs",
-                                             "interpret"))
-def _hybrid_expand_kernel(data, starts, is_rle, vals, bits, bw: int,
-                          n_bucket: int, n_runs: int,
-                          interpret: bool = False):
-    """Hybrid RLE/bit-packed run expansion in one kernel: output index →
-    owning run via an in-register compare-count over the (small) run
-    table, run fields gathered by one-hot MXU matmul, bit-packed values
-    extracted through a 4-byte little-endian gather window. The byte
-    gathers use dynamic indexing (jnp.take) — interpret-proven; a
-    backend that rejects it falls back via disable_runtime."""
-    from jax.experimental import pallas as pl
-
-    r_pad = _round_up(max(n_runs, 128), 128)
-    c_pad = 128
-    n_pad = _round_up(max(n_bucket, _BLK), _BLK)
-    nb = data.shape[0]
-    sentinel = np.float32(n_bucket + 1)
-    st = jnp.full((1, r_pad), sentinel, jnp.float32).at[0, :n_runs].set(
-        starts.astype(jnp.float32))
-    tab = jnp.zeros((r_pad, c_pad), jnp.float32)
-    tab = tab.at[:n_runs, 0].set(starts.astype(jnp.float32))
-    tab = tab.at[:n_runs, 1].set(is_rle.astype(jnp.float32))
-    tab = tab.at[:n_runs, 2].set(vals.astype(jnp.float32))
-    tab = tab.at[:n_runs, 3].set(bits.astype(jnp.float32))
-    data2 = data.astype(jnp.uint32)[:, None]
-
-    def kernel(data_ref, st_ref, tab_ref, out_ref):
-        step = pl.program_id(0)
-        i = (step * _BLK + jax.lax.broadcasted_iota(
-            jnp.int32, (_BLK, 1), 0)).astype(jnp.float32)
-        # searchsorted(starts, i, 'right') - 1 == count(starts <= i) - 1
-        cnt = jnp.sum((st_ref[:] <= i).astype(jnp.float32), axis=1,
-                      keepdims=True)
-        r = jnp.clip(cnt - 1.0, 0.0, np.float32(n_runs - 1))
-        onehot = (r == jax.lax.broadcasted_iota(
-            jnp.float32, (1, r_pad), 1)).astype(jnp.float32)
-        g = jax.lax.dot_general(
-            onehot, tab_ref[:],
-            dimension_numbers=(((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-            precision=jax.lax.Precision.HIGHEST)          # [BLK, C]
-        start_r = g[:, 0:1]
-        isrle_r = g[:, 1:2]
-        val_r = g[:, 2:3]
-        bit_r = g[:, 3:4]
-        if bw > 0:
-            rel = i - start_r
-            bp = (bit_r + rel * np.float32(bw)).astype(jnp.int32)
-            byte0 = bp >> 3
-            dat = data_ref[:]                              # [nb, 1]
-            w = jnp.take(dat, jnp.clip(byte0, 0, nb - 1),
-                         axis=0)[:, :, 0]
-            w = w | (jnp.take(dat, jnp.clip(byte0 + 1, 0, nb - 1),
-                              axis=0)[:, :, 0] << 8)
-            w = w | (jnp.take(dat, jnp.clip(byte0 + 2, 0, nb - 1),
-                              axis=0)[:, :, 0] << 16)
-            w = w | (jnp.take(dat, jnp.clip(byte0 + 3, 0, nb - 1),
-                              axis=0)[:, :, 0] << 24)
-            packed = ((w >> jnp.bitwise_and(bp, 7).astype(jnp.uint32))
-                      & np.uint32((1 << bw) - 1)).astype(jnp.float32)
-        else:
-            packed = jnp.zeros((_BLK, 1), jnp.float32)
-        out_ref[:] = jnp.where(isrle_r > 0, val_r, packed)
-
-    # shardcheck: ignore[unregistered-jit]
-    out = pl.pallas_call(
-        kernel,
-        grid=(n_pad // _BLK,),
-        in_specs=[
-            pl.BlockSpec((nb, 1), lambda i: (_I0, _I0)),
-            pl.BlockSpec((1, r_pad), lambda i: (_I0, _I0)),
-            pl.BlockSpec((r_pad, c_pad), lambda i: (_I0, _I0)),
-        ],
-        out_specs=pl.BlockSpec((_BLK, 1), lambda i: (i, _I0)),
-        out_shape=jax.ShapeDtypeStruct((n_pad, 1), jnp.float32),
-        interpret=interpret,
-    )(data2, st, tab)
-    return out[:n_bucket, 0].astype(jnp.int32)
-
-
-def hybrid_expand(data, starts, is_rle, vals, bits, bw: int,
-                  n_bucket: int, interpret: Optional[bool] = None):
-    """Pallas route for io/device_decode's hybrid run expansion (the
-    RLE/bit-packed decode inner loop — dict index streams, RLE booleans,
-    definition levels). Inputs are the already-padded device run tables.
-    Returns int32 [n_bucket] expanded values, or None when the gate is
-    closed (caller keeps the XLA searchsorted body)."""
-    interp = bool(interpret) if interpret is not None else FORCE_INTERPRET
-    n_runs = starts.shape[0]
-    if not ((use_pallas() or interp) and n_runs <= MAX_EXPAND_RUNS
-            and n_bucket < MAX_GATHER_VALUE
-            and data.shape[0] * 8 < MAX_GATHER_VALUE and 0 <= bw <= 24):
-        return None
-    _engage("decode")
-    return _hybrid_expand_kernel(data, starts, is_rle, vals, bits, bw,
-                                 n_bucket, n_runs, interpret=interp)
-
 
 def dict_gather(codes, lut, interpret: Optional[bool] = None):
     """Pallas dictionary gather for decode: ``lut[codes]`` through the
@@ -638,7 +537,8 @@ def dict_gather(codes, lut, interpret: Optional[bool] = None):
     or None when the gate is closed."""
     interp = bool(interpret) if interpret is not None else FORCE_INTERPRET
     if not ((use_pallas() or interp)
-            and lut.shape[0] <= MAX_MATMUL_SLOTS):
+            and lut.shape[0] <= MAX_MATMUL_SLOTS
+            and _rows_fit(codes.shape[0], 2)):
         return None
     _engage("decode")
     return _matmul_gather_kernel(codes, lut, lut.shape[0],
@@ -705,7 +605,8 @@ def range_partition(pk, splitters, interpret: Optional[bool] = None):
     destinations or None when the gate is closed."""
     interp = bool(interpret) if interpret is not None else FORCE_INTERPRET
     n_spl = splitters.shape[0]
-    if not ((use_pallas() or interp) and 0 < n_spl <= MAX_MATMUL_SLOTS):
+    if not ((use_pallas() or interp) and 0 < n_spl <= MAX_MATMUL_SLOTS
+            and _rows_fit(pk.shape[0], 2)):
         return None
     _engage("range")
     pk_planes = _split_u64_planes([pk])

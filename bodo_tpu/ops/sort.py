@@ -47,9 +47,7 @@ def sort_local(arrays, count, num_keys: int, ascending: Tuple[bool, ...],
     cap = arrays[0][0].shape[0]
     padmask = K.row_mask(count, cap)
     ops = _sort_operands(arrays[:num_keys], ascending, na_last, padmask)
-    nko = len(ops)
-    ops.append(jnp.arange(cap))
-    perm = lax.sort(tuple(ops), num_keys=nko, is_stable=True)[-1]
+    perm = SE.stable_argsort(ops)
     out = tuple((None if d is None else d[perm],
                  None if v is None else v[perm]) for d, v in arrays)
     return out, perm

@@ -355,10 +355,7 @@ def _sorted_segments(key_arrays, order_arrays, count, ascending, na_last,
     for (d, v), asc in zip(order_arrays, ascending):
         operands.extend(SE.key_operands(d, v, ascending=asc,
                                         na_last=na_last, padmask=padmask))
-    nko = len(operands)
-    operands.append(jnp.arange(cap))
-    perm = lax.sort(tuple(operands), num_keys=max(nko, 1),
-                    is_stable=True)[-1]
+    perm = SE.stable_argsort(operands) if operands else jnp.arange(cap)
     padmask_s = padmask[perm]
     pos = jnp.arange(cap)
 

@@ -25,10 +25,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
-try:
-    from jax import shard_map
-except ImportError:  # jax < 0.5: shard_map still under experimental
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from bodo_tpu.config import config
@@ -47,13 +44,7 @@ from bodo_tpu.runtime.resilience import maybe_inject as _inject
 # shardcheck trace-time-side-effect lint flags exactly this pattern;
 # the inline suppressions below mark it as the one intentional case.
 
-if hasattr(lax, "axis_size"):
-    axis_size = lax.axis_size
-else:
-    def axis_size(ax):
-        # jax < 0.5 has no lax.axis_size; psum of a literal constant
-        # folds to the static axis size inside any bound axis context.
-        return lax.psum(1, ax)
+axis_size = lax.axis_size
 
 
 def rank(axis: Optional[str] = None):
@@ -201,20 +192,11 @@ def _round_cap(n: int) -> int:
 # shard_map convenience wrapper
 # --------------------------------------------------------------------------
 
-try:  # the replication-check kwarg was renamed check_rep -> check_vma
-    import inspect
-    _SMAP_CHECK_KW = ("check_vma" if "check_vma"
-                      in inspect.signature(shard_map).parameters
-                      else "check_rep")
-except (ValueError, TypeError):  # pragma: no cover - unintrospectable
-    _SMAP_CHECK_KW = "check_vma"
-
-
 def smap(fn, in_specs, out_specs, mesh=None):
     """shard_map over the active mesh with the data axis bound."""
     m = mesh or mesh_mod.get_mesh()
     return shard_map(fn, mesh=m, in_specs=in_specs, out_specs=out_specs,
-                     **{_SMAP_CHECK_KW: False})
+                     check_vma=False)
 
 
 ROW = None  # placeholder; use P(config.data_axis) / P() at call sites

@@ -30,6 +30,7 @@ from bodo_tpu.ops.groupby import (COMBINE_OF, DECOMPOSE, HASH_OPS,
                                   result_dtype)
 from bodo_tpu.ops.hashing import dest_shard, hash_columns
 from bodo_tpu.ops import pallas_kernels as PK
+from bodo_tpu.ops.sort_encoding import stable_argsort
 from bodo_tpu.parallel import collectives as C
 from bodo_tpu.parallel import mesh as mesh_mod
 from bodo_tpu.plan.fusion import fusion_stage
@@ -77,7 +78,8 @@ def bucket_rows(dest, arrays: Sequence, count, num_shards: int,
         send_counts = jnp.minimum(counts.astype(jnp.int64), bucket_cap)
         return packed, send_counts, overflow
     # stable sort rows by destination
-    d_s, perm = lax.sort((d, jnp.arange(cap)), num_keys=1, is_stable=True)
+    perm = stable_argsort([(d.astype(jnp.uint32), 32)])
+    d_s = d[perm]
     pos = jnp.arange(cap)
     is_new = (d_s != jnp.roll(d_s, 1)) | (pos == 0)
     group_start = lax.cummax(jnp.where(is_new, pos, 0))

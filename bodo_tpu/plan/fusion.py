@@ -877,17 +877,12 @@ def _run_fused_agg(t: Table, group: FusionGroup, donate: bool):
         from bodo_tpu.runtime.memory_governor import governor
         if resilience.is_degradable(e) or governor().is_oom(e):
             raise
-        if not compiled:
-            raise  # cached program failing at dispatch = runtime fault
-        if use_mxu:
-            # pallas kernel failed on this backend: XLA scatter path for
-            # the rest of the process (mirrors _groupby_agg_dense). No
-            # negative cache — the retry signature has use_mxu=False.
-            from bodo_tpu.ops import pallas_kernels as PK
-            PK.disable_runtime("fused dense-agg matmul kernel failed")
-            _programs.pop(sig, None)
-        else:
-            _failed.add(fp_sig)
+        if not compiled or use_mxu:
+            # a cached program failing at dispatch is a runtime fault;
+            # a pallas kernel its gate admitted and the backend refused
+            # is an error too, never a silent XLA re-run
+            raise
+        _failed.add(fp_sig)
         raise FusionFallback(str(e)) from e
     dt_s = _time.perf_counter() - t0
     if compiled:

@@ -1072,19 +1072,8 @@ def _groupby_agg_dense(t: Table, keys, aggs, ranges) -> Table:
         fn = jax.jit(body)
         _jit_cache[key] = fn
 
-    try:
-        out_keys, out_vals, ng = fn(tsel.device_data(),
-                                    jnp.asarray(t.nrows))
-        nrows_arr = jax.device_get(ng)  # async-dispatch errors surface here
-    except Exception:
-        if not use_mxu:
-            raise
-        # pallas kernel failed on this backend: fall back to XLA scatter
-        # for the rest of the process (use_pallas() is now False)
-        PK.disable_runtime("dense groupby matmul kernel failed to compile")
-        _jit_cache.pop(key, None)
-        return _groupby_agg_dense(t, keys, aggs, ranges)
-    nrows = int(nrows_arr)
+    out_keys, out_vals, ng = fn(tsel.device_data(), jnp.asarray(t.nrows))
+    nrows = int(jax.device_get(ng))
     cols: Dict[str, Column] = {}
     for kname, kd in zip(keys, out_keys):
         src = t.column(kname)

@@ -4,7 +4,7 @@ The all-or-nothing fault story (retry the whole gang, or degrade one
 stage to replicated) is intolerable once the gang is a long-lived
 shared service: one lost rank kills every tenant's in-flight query and
 cold-starts every cache. TPU fleet data treats rank loss and wedged
-device tunnels as routine, and the SPMD answer to per-task lineage
+hosts as routine, and the SPMD answer to per-task lineage
 recovery is recovery at the *stage*: checkpoint pipeline state at
 stage boundaries, re-mesh onto the survivors, and resume the plan
 suffix on the smaller mesh.

@@ -70,7 +70,6 @@ def _zero() -> dict:
         "raw_bytes": 0,               # raw (compressed) page bytes shipped
         # Pallas kernel engagement inside page programs (trace-time
         # counters: bumped when the kernel routes into a compiled spec)
-        "pallas_expand_traced": 0,    # hybrid RLE/bit-packed expand
         "pallas_dict_gather": 0,      # dictionary-decode gather
     }
 

@@ -42,8 +42,8 @@ from typing import Dict, List, Optional
 from bodo_tpu.config import config
 from bodo_tpu.utils.logging import log
 
-# TPU HBM per chip, bytes — used when memory_stats() is unavailable
-# (older runtimes / some plugin backends). Keyed by device_kind prefix.
+# TPU HBM per chip, bytes — used when memory_stats() is unavailable.
+# Keyed by device_kind prefix.
 _TPU_HBM_BYTES = {
     "TPU v2": 8 << 30,
     "TPU v3": 16 << 30,
@@ -88,7 +88,8 @@ def _probe_device_budget() -> int:
                                   key=lambda kv: -len(kv[0])):
             if kind.startswith(prefix):
                 return hbm
-        return 16 << 30  # unknown TPU generation: conservative default
+        raise ValueError(f"no HBM size known for TPU kind {kind!r}: "
+                         f"add it to _TPU_HBM_BYTES")
     # CPU (and unknown platforms): a fraction of host RAM, split across
     # the virtual devices sharing it
     ram = _host_ram_bytes()

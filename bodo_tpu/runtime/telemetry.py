@@ -6,7 +6,7 @@ EXPLAIN ANALYZE, the metrics registry); this module covers the gaps
 future serving layer (runtime/scheduler.py, ROADMAP item 2) scrapes per
 tenant, and the Pathways-style controller function of watching a
 gang-scheduled fleet centrally (PAPERS §2: health monitoring is a
-first-class controller concern; §4: TPU rank loss and wedged tunnels
+first-class controller concern; §4: TPU rank loss and wedged hosts
 are routine fleet events, so the diagnostic artifact must be produced
 by default).
 

@@ -389,13 +389,19 @@ def test_parquet_stats_cache_invalidation(mesh8, tmp_path):
 def test_compile_cache_dir_and_counters(mesh8, tmp_path):
     import jax
     import jax.numpy as jnp
-    from bodo_tpu.config import set_config
+    from jax.experimental.compilation_cache import compilation_cache
     from bodo_tpu.utils import tracing
-    old = jax.config.jax_compilation_cache_dir
+    old = (jax.config.jax_compilation_cache_dir,
+           jax.config.jax_persistent_cache_min_compile_time_secs,
+           jax.config.jax_persistent_cache_min_entry_size_bytes)
     try:
-        set_config(compile_cache_dir=str(tmp_path))
-        # drop the 0.1s floor so this toy kernel is cache-eligible
+        jax.config.update("jax_compilation_cache_dir", str(tmp_path))
+        # no floors, so this toy kernel is cache-eligible
         jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+        jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+        # jax latches cache-in-use on the FIRST compile of the process
+        compilation_cache.reset_cache()
+        tracing.install_compile_cache_listener()
         before = tracing.compile_cache_stats()
 
         @jax.jit
@@ -408,8 +414,12 @@ def test_compile_cache_dir_and_counters(mesh8, tmp_path):
             before["hits"] + before["misses"]
         assert os.listdir(str(tmp_path))  # entries actually persisted
     finally:
-        set_config(compile_cache_dir="")
-        jax.config.update("jax_compilation_cache_dir", old)
+        jax.config.update("jax_compilation_cache_dir", old[0])
+        jax.config.update("jax_persistent_cache_min_compile_time_secs",
+                          old[1])
+        jax.config.update("jax_persistent_cache_min_entry_size_bytes",
+                          old[2])
+        compilation_cache.reset_cache()
 
 
 # ---------------------------------------------------------------------------

@@ -1,0 +1,158 @@
+"""Ask the v5e's compiler, without a chip: every Pallas kernel in
+ops/pallas_kernels.py at the shapes chip_smoke.py gives it, and the
+multi-key sort, compiled for a described `v5e:2x2` device.
+
+Nothing runs, so this says nothing about results or speed; it catches
+what interpret mode cannot (Mosaic refusing an op or a layout, a program
+that does not fit HBM, a sort whose compile takes minutes). The topology
+is described inside a fixture — never at import — because only one
+process may load the TPU library, and every xdist worker imports this
+file. All cases live in this one file for the same reason.
+"""
+
+import time
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from bodo_tpu.ops import pallas_kernels as PK
+from bodo_tpu.ops import sort as S
+
+# sort_local compiled alone for the described v5e in 33.7 s at one key
+# and 32.1 s at six (PR 25, 8-core sandbox, 2M rows; the variadic
+# lax.sort it replaced: 69 s at one uint64 key, six keys not finished
+# after 280 s). Six keys stay out of this file to keep it short; the
+# ceiling is 3x the measurement so a loaded box does not flap it.
+SORT_COMPILE_CEILING_S = 100.0
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - no TPU compiler here
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def shape(topo):
+    one_chip = SingleDeviceSharding(topo.devices[0])
+
+    def make(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+    return make
+
+
+@pytest.fixture(scope="module", autouse=True)
+def no_compile_cache():
+    # a compile for a described device is written to the persistent
+    # cache but cannot be read back without a chip
+    from jax.experimental.compilation_cache import compilation_cache
+    old = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", old)
+    compilation_cache.reset_cache()
+
+
+def _groupby(shape, n, k, c):
+    return PK.matmul_groupby_sum, (shape((n,), jnp.int32),
+                                   shape((n, c), jnp.float32)), \
+        dict(n_slots=k, n_cols=c), 2
+
+
+def _gather(shape, n, k):
+    return PK._matmul_gather_kernel, (shape((n,), jnp.int32),
+                                      shape((k,), jnp.int32)), \
+        dict(n_slots=k), 2
+
+
+def _probe(shape, n, t, planes):
+    return PK._hash_probe_kernel, (
+        shape((n,), jnp.int32), shape((n,), jnp.int32),
+        shape((n, planes), jnp.float32), shape((n,), jnp.bool_),
+        shape((t, 1 + planes), jnp.float32)), \
+        dict(T=t, n_planes=planes, max_rounds=64), 6
+
+
+def _partition(shape, n, b):
+    return PK._partition_rank_kernel, (shape((n,), jnp.int32),
+                                       shape((n,), jnp.bool_)), \
+        dict(num_buckets=b), 3
+
+
+def _range(shape, n, s):
+    return PK._range_partition_kernel, (
+        shape((n, 4), jnp.float32), shape((s, 4), jnp.float32),
+        shape((s,), jnp.bool_)), dict(n_spl=s), 2
+
+
+# (id, builder, args): the shapes the smoke's phases hand each kernel —
+# Q1's grouping over SF1 lineitem, one parquet row group of dictionary
+# codes, the taxi merge's probe at 2M rows (larger probes close the
+# gate), one shard's rows of the 20M-row four-chip shuffle — and each
+# kernel's widest slot space
+KERNELS = [
+    ("groupby_sf1_lineitem", _groupby, (6_001_664, 6, 2)),
+    ("groupby_max_slots", _groupby, (1 << 20, 4096, 8)),
+    ("gather_rowgroup_dict", _gather, (1 << 20, 4)),
+    ("gather_max_slots", _gather, (1 << 20, 4096)),
+    ("probe_taxi_2m", _probe, (2_000_000, 512, 4)),
+    ("probe_max_slots", _probe, (1 << 20, 4096, 8)),
+    ("partition_4_shards", _partition, (5_000_064, 4)),
+    ("partition_max_buckets", _partition, (1 << 20, 4096)),
+    ("range_4_shards", _range, (5_000_064, 3)),
+    ("range_max_splitters", _range, (1 << 20, 4096)),
+]
+
+
+@pytest.mark.parametrize("build,args", [k[1:] for k in KERNELS],
+                         ids=[k[0] for k in KERNELS])
+def test_kernel_compiles_for_v5e(shape, build, args):
+    fn, shapes, static, n_padded_operands = build(shape, *args)
+    n = args[0]
+    assert PK._rows_fit(n, n_padded_operands), "shape is outside the gate"
+    compiled = fn.lower(*shapes, **static).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    # the gate's arithmetic is the compiler's: [N, 1] operands pad to
+    # 512 bytes a row, and that is where the temporaries go
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp <= 1.25 * n * n_padded_operands * PK._PADDED_ROW_BYTES \
+        + (64 << 20), f"{temp >> 20} MiB of temporaries"
+    assert temp <= PK._PADDED_BUDGET
+
+
+def test_sort_local_compiles_for_v5e(shape):
+    """One int64 key and a float payload at 2M rows. The sort is one
+    (uint32, int32) `lax.sort` in a loop whatever the key list, so its
+    compile time does not grow with the keys."""
+    n, num_keys = 2_000_000, 1
+    arrays = ((shape((n,), jnp.int64), None),
+              (shape((n,), jnp.float64), None))
+    fn = jax.jit(S.sort_local.__wrapped__,
+                 static_argnames=("num_keys", "ascending", "na_last"))
+    t0 = time.perf_counter()
+    compiled = fn.lower(arrays, shape((), jnp.int64), num_keys=num_keys,
+                        ascending=(True,) * num_keys).compile()
+    took = time.perf_counter() - t0
+    assert took < SORT_COMPILE_CEILING_S, f"{took:.0f}s to compile"
+    assert compiled.memory_analysis().temp_size_in_bytes < (1 << 30)
+
+
+def test_sort_local_float64_key_compiles_for_v5e(shape):
+    """The TPU compiler cannot bitcast float64 to integer bits (the
+    first chip run of PR 25 failed there, in Q5's `order by revenue
+    desc`), so a float64 key is compared natively in a pass of its own.
+    4096 rows: below the size where that 64-bit sort takes 87 s."""
+    n = 4096
+    arrays = ((shape((n,), jnp.float64), None),
+              (shape((n,), jnp.int32), None))
+    fn = jax.jit(S.sort_local.__wrapped__,
+                 static_argnames=("num_keys", "ascending", "na_last"))
+    fn.lower(arrays, shape((), jnp.int64), num_keys=2,
+             ascending=(False, True)).compile()

@@ -571,14 +571,15 @@ class TestBenchwatch:
         assert benchwatch.main(["--dir", d2, "--check"]) == 0
 
     def test_repo_trajectory_is_valid(self):
-        """The committed BENCH_r*.json artifacts parse clean — the
-        runtests gate depends on it."""
+        """Whatever BENCH_r*.json artifacts the repo holds parse clean —
+        the runtests gate depends on it. (The records made through the
+        old device path were deleted; the next ones come from a
+        `benchmark` PR.)"""
         from bodo_tpu import benchwatch
         repo = os.path.dirname(os.path.dirname(
             os.path.abspath(__file__)))
         traj = benchwatch.load_trajectory(repo)
         assert traj["errors"] == []
-        assert traj["records"], "no BENCH artifacts in repo"
 
 
 # ------------------------------------------------- lint: swallowed

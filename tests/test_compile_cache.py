@@ -37,7 +37,7 @@ def test_persistent_compile_cache(tmp_path):
     # saw. Pin AQE off and the persistent-cache write threshold to 0
     # (by default jax skips writing compilations faster than ~1s) so
     # entry-set equality is deterministic on a drifting shared box.
-    env = dict(os.environ, BODO_TPU_COMPILE_CACHE_DIR=cache,
+    env = dict(os.environ, JAX_COMPILATION_CACHE_DIR=cache,
                BODO_TPU_AQE="0",
                JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS="0")
     env.pop("JAX_PLATFORMS", None)

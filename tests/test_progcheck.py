@@ -42,12 +42,11 @@ def _shard_mapped(body, mesh8, n_in=1):
     # mesh8 guarantees the 8-device env; build a local mesh so the
     # bodies' literal axis name "x" is independent of config.data_axis
     from jax.sharding import Mesh, PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
     mesh = Mesh(np.array(jax.devices()), axis_names=("x",))
     specs = tuple(P("x") for _ in range(n_in))
-    return jax.jit(shard_map(  # shardcheck: ignore[unregistered-jit]
+    return jax.jit(jax.shard_map(  # shardcheck: ignore[unregistered-jit]
         body, mesh=mesh, in_specs=specs, out_specs=P("x"),
-        check_rep=False))
+        check_vma=False))
 
 
 # ---------------------------------------------------------------------------

@@ -347,7 +347,8 @@ def _has_registering_decorator(fn: ast.AST) -> bool:
 
 
 def _is_jit_decorator(d: ast.AST) -> bool:
-    """@jax.jit, or @partial(jax.jit, ...) / @functools.partial(...)."""
+    """@jax.jit, or @partial(jax.jit, ...) / @functools.partial(...),
+    likewise around kernel_cache.named_jit."""
     if _dotted(d) == "jax.jit":
         return True
     if isinstance(d, ast.Call) and _terminal(d.func) == "jit" \
@@ -355,7 +356,7 @@ def _is_jit_decorator(d: ast.AST) -> bool:
         return True
     if isinstance(d, ast.Call) and _terminal(d.func) == "partial":
         for a in d.args:
-            if _dotted(a) == "jax.jit":
+            if _dotted(a) in ("jax.jit", "named_jit"):
                 return True
     return False
 
@@ -615,7 +616,7 @@ class _Checker(ast.NodeVisitor):
                 f"splits the fused pipeline (or fails to trace)")
         if not self._reg_depth and \
                 ((t == "jit" and _root(node.func) == "jax")
-                 or t == "pallas_call"):
+                 or t in ("named_jit", "pallas_call")):
             self._add(
                 "unregistered-jit", node,
                 f"direct {_dotted(node.func) or t!r} call outside a "

@@ -16,12 +16,11 @@ import pyarrow.csv as pacsv
 from bodo_tpu.io.arrow_bridge import arrow_to_table
 from bodo_tpu.runtime import resilience
 from bodo_tpu.table.table import Table
+from bodo_tpu.utils import tracing
 
 
-from bodo_tpu.utils.tracing import traced_table_op as _traced
-
-
-@_traced
+@tracing.traced_table_op
+@tracing.event("scan.host_fallback")  # CSV is parsed on the host
 def read_csv(path: str, columns: Optional[Sequence[str]] = None,
              parse_dates: Optional[Sequence[str]] = None) -> Table:
     convert = {}

@@ -67,6 +67,7 @@ from bodo_tpu.analysis import progcheck
 from bodo_tpu.config import config
 from bodo_tpu.table import dtypes as dt
 from bodo_tpu.table.table import Column, REP, Table, round_capacity
+from bodo_tpu.utils import tracing
 
 # ---------------------------------------------------------------------------
 # format constants
@@ -976,6 +977,7 @@ def _make_page(plan: _ColPlan, hdr: _PageHeader, data: bytes,
 # fetch (pool side): raw ranges in, page bundles out
 # ---------------------------------------------------------------------------
 
+@tracing.event("scan.fetch")  # a fresh span each call (ContextDecorator)
 def fetch_row_group(f: str, rg: int, columns: Optional[Sequence[str]],
                     *, inject: bool = True) -> RawRowGroup:
     """Pool task: ship one row group as raw pages. Device-decodable
@@ -998,7 +1000,8 @@ def fetch_row_group(f: str, rg: int, columns: Optional[Sequence[str]],
         try:
             plan = _plan_chunk(md, arrow_schema, rg, name)
             raw = _raw_range(f, plan.start, plan.size)
-            rc = _split_chunk_pages(plan, raw)
+            with tracing.event("scan.split", bytes=plan.size):
+                rc = _split_chunk_pages(plan, raw)
             if plan.is_string and rc.dictionary is None and \
                     plan.num_values > 0:
                 raise Unsupported("string chunk without dictionary page")
@@ -1177,7 +1180,9 @@ def decode_row_group(bundle: RawRowGroup,
         rc = bundle.device_cols.get(name)
         if rc is not None:
             try:
-                cols[name] = _decode_column(rc, cap)
+                with tracing.event("scan.column", pages=len(rc.pages),
+                                   bytes=rc.raw_bytes):
+                    cols[name] = _decode_column(rc, cap)
                 n_pages += len(rc.pages)
                 dev_bytes += rc.plan.num_values * \
                     max(np.dtype(rc.plan.out_dtype).itemsize, 1)
@@ -1190,21 +1195,22 @@ def decode_row_group(bundle: RawRowGroup,
         cols[name] = None  # host-filled below
     missing = [n for n, c in cols.items() if c is None]
     if missing:
-        at = bundle.host_table
-        have = set() if at is None else set(at.column_names)
-        need = [n for n in missing if n not in have]
-        if need:
-            import pyarrow.parquet as pq
+        with tracing.event("scan.host_fallback", columns=len(missing)):
+            at = bundle.host_table
+            have = set() if at is None else set(at.column_names)
+            need = [n for n in missing if n not in have]
+            if need:
+                import pyarrow.parquet as pq
 
-            from bodo_tpu.io.parquet import _opened, footer_metadata
-            with _opened(bundle.file) as src:
-                pf = pq.ParquetFile(src,
-                                    metadata=footer_metadata(bundle.file))
-                extra = pf.read_row_group(bundle.rg, columns=need)
-            io_pool.count("host_decode_bytes", int(extra.nbytes))
-            at = extra if at is None else _merge_tables(at, extra)
-        for n in missing:
-            cols[n] = _arrow_column(at.column(n), cap)
+                from bodo_tpu.io.parquet import _opened, footer_metadata
+                with _opened(bundle.file) as src:
+                    pf = pq.ParquetFile(
+                        src, metadata=footer_metadata(bundle.file))
+                    extra = pf.read_row_group(bundle.rg, columns=need)
+                io_pool.count("host_decode_bytes", int(extra.nbytes))
+                at = extra if at is None else _merge_tables(at, extra)
+            for n in missing:
+                cols[n] = _arrow_column(at.column(n), cap)
     t = Table(cols, bundle.nrows, REP, None)
     t._device_decoded = bool(bundle.device_cols)
     io_pool.count("device_decode_pages", n_pages)

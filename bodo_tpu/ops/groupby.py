@@ -541,7 +541,7 @@ HASH_OPS = frozenset({
 
 
 @bounded_jit
-def _hashed_claim(key_arrays, count):
+def _groupby_hashed_claim(key_arrays, count):
     """Claim dense group ids for arbitrary keys (no row sort)."""
     from bodo_tpu.ops import hashtable as HT
 
@@ -556,8 +556,8 @@ def _hashed_claim(key_arrays, count):
 
 
 @bounded_jit(static_argnames=("specs", "num_keys", "ng_cap"))
-def _hashed_agg(arrays, seg, group_row, ok, specs: Tuple[str, ...],
-                num_keys: int, ng_cap: int):
+def _groupby_hashed_agg(arrays, seg, group_row, ok,
+                        specs: Tuple[str, ...], num_keys: int, ng_cap: int):
     """Aggregate into the ng_cap-sized group space (hash order).
 
     The segment space is the (host-synced, rounded) GROUP count, not the
@@ -624,7 +624,7 @@ def _hashed_agg(arrays, seg, group_row, ok, specs: Tuple[str, ...],
 
 
 @bounded_jit(static_argnames=("out_capacity",))
-def _hashed_sort_groups(gkeys, gvals, gvalid, out_capacity: int):
+def _groupby_hashed_sort(gkeys, gvals, gvalid, out_capacity: int):
     """Sort the group table by keys ascending and emit [out_capacity]
     outputs packed at the front (pandas sort=True)."""
     ng_cap = gvalid.shape[0]
@@ -656,12 +656,12 @@ def groupby_local_hashed_static(arrays, count, specs: Tuple[str, ...],
     out_capacity == row capacity that holds by construction.
 
     Returns (out_keys, out_vals, n_groups, unresolved)."""
-    seg, group_row, ok, n_groups, unresolved = _hashed_claim(
+    seg, group_row, ok, n_groups, unresolved = _groupby_hashed_claim(
         arrays[:num_keys], count)
-    gkeys, gvals, gvalid = _hashed_agg(arrays, seg, group_row, ok, specs,
-                                       num_keys, out_capacity)
-    out_keys, out_vals = _hashed_sort_groups(gkeys, gvals, gvalid,
-                                             out_capacity)
+    gkeys, gvals, gvalid = _groupby_hashed_agg(
+        arrays, seg, group_row, ok, specs, num_keys, out_capacity)
+    out_keys, out_vals = _groupby_hashed_sort(gkeys, gvals, gvalid,
+                                              out_capacity)
     return out_keys, out_vals, n_groups, unresolved
 
 
@@ -682,15 +682,15 @@ def groupby_local_hashed(arrays, count, specs: Tuple[str, ...],
     caller must fall back to the sort kernel."""
     from bodo_tpu.table.table import round_capacity
 
-    seg, group_row, ok, n_groups, unresolved = _hashed_claim(
+    seg, group_row, ok, n_groups, unresolved = _groupby_hashed_claim(
         arrays[:num_keys], count)
     ng, unres = jax.device_get((n_groups, unresolved))
     if bool(unres):
         return None, None, 0, True
     cap = arrays[0][0].shape[0]
     ng_cap = min(round_capacity(max(int(ng), 1)), cap)
-    gkeys, gvals, gvalid = _hashed_agg(arrays, seg, group_row, ok, specs,
-                                       num_keys, ng_cap)
-    out_keys, out_vals = _hashed_sort_groups(gkeys, gvals, gvalid,
-                                             out_capacity)
+    gkeys, gvals, gvalid = _groupby_hashed_agg(
+        arrays, seg, group_row, ok, specs, num_keys, ng_cap)
+    out_keys, out_vals = _groupby_hashed_sort(gkeys, gvals, gvalid,
+                                              out_capacity)
     return out_keys, out_vals, int(ng), False

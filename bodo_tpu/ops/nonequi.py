@@ -36,7 +36,7 @@ from bodo_tpu.table.table import Column, REP, Table, round_capacity
 # pair-grid budget: tile_rows * build_cap <= this (elements per pred col)
 _GRID_BUDGET = 1 << 22
 
-from bodo_tpu.utils.kernel_cache import KernelCache
+from bodo_tpu.utils.kernel_cache import KernelCache, named_jit
 
 _jit_cache = KernelCache(maxsize=config.kernel_cache_size,
                          subsystem="nonequi")
@@ -89,7 +89,7 @@ def _build_tile_kernel(sig, pred_key, names_l: Tuple[str, ...],
             return out, cnt, matched
         return out, cnt
 
-    fn = jax.jit(body)
+    fn = named_jit("join_nonequi", body)
     _jit_cache[key] = fn
     return fn
 

@@ -24,7 +24,8 @@ from bodo_tpu.ops import kernels as K
 from bodo_tpu.ops import sort_encoding as SE
 from bodo_tpu.parallel import collectives as C
 from bodo_tpu.parallel import mesh as mesh_mod
-from bodo_tpu.utils.kernel_cache import bounded_jit, cached_builder
+from bodo_tpu.utils.kernel_cache import (bounded_jit, cached_builder,
+                                         named_jit)
 
 # oversampling factor for splitter selection (samples per shard = OS * S)
 _OVERSAMPLE = 8
@@ -139,7 +140,7 @@ def _build_sort_sharded(mesh_key, num_arrays: int, num_keys: int,
 
     shd = C.smap(body, in_specs=(P(axis), P(axis)),
                  out_specs=(P(axis), P(axis), P(axis)), mesh=mesh)
-    return jax.jit(shd)
+    return named_jit("sort_sharded", shd)
 
 
 def sort_sharded(arrays, counts, num_keys: int, ascending: Tuple[bool, ...],

@@ -34,7 +34,7 @@ from bodo_tpu.ops.sort_encoding import stable_argsort
 from bodo_tpu.parallel import collectives as C
 from bodo_tpu.parallel import mesh as mesh_mod
 from bodo_tpu.plan.fusion import fusion_stage
-from bodo_tpu.utils.kernel_cache import cached_builder
+from bodo_tpu.utils.kernel_cache import cached_builder, named_jit
 
 
 # ---------------------------------------------------------------------------
@@ -216,7 +216,7 @@ def _build_groupby_partial(mesh_key, num_keys: int, specs: Tuple[str, ...],
 
     shd = C.smap(body, in_specs=(P(axis), P(axis)),
                  out_specs=(P(axis), P(axis), P(axis)), mesh=mesh)
-    return jax.jit(shd)
+    return named_jit("groupby_sharded_partial", shd)
 
 
 def shuffle_partials(pk, pv, num_keys: int, S: int, bucket_cap: int,
@@ -282,7 +282,7 @@ def _build_groupby_combine(mesh_key, num_keys: int, specs: Tuple[str, ...],
 
     shd = C.smap(body, in_specs=(P(axis), P(axis)),
                  out_specs=(P(axis), P(axis), P(axis)), mesh=mesh)
-    return jax.jit(shd)
+    return named_jit("groupby_sharded_combine", shd)
 
 
 _MESHES = {}

@@ -117,7 +117,7 @@ from bodo_tpu.plan import logical as L
 from bodo_tpu.table import dtypes as dt
 from bodo_tpu.table.table import Column, ONED, REP, Table
 from bodo_tpu.runtime import xla_observatory as xobs
-from bodo_tpu.utils.kernel_cache import FusionProgramCache
+from bodo_tpu.utils.kernel_cache import FusionProgramCache, named_jit
 from bodo_tpu.utils.logging import log
 
 # NOTE: bodo_tpu.relational imports this module at module level (for
@@ -651,11 +651,13 @@ def _run_chain(t: Table, steps, donate: bool = False) -> Table:
             def sharded(tree, counts):
                 out, cnt = body(tree, counts[0])
                 return out, cnt[None]
-            fn = jax.jit(C.smap(sharded, in_specs=(P(ax), P(ax)),
-                                out_specs=(P(ax), P(ax)), mesh=m))
+            fn = named_jit("fusedchain", C.smap(
+                sharded, in_specs=(P(ax), P(ax)), out_specs=(P(ax), P(ax)),
+                mesh=m))
         else:
-            fn = jax.jit(_compile_chain(meta, in_names, jit_names),
-                         donate_argnums=(0,) if donate else ())
+            fn = named_jit(
+                "fusedchain", _compile_chain(meta, in_names, jit_names),
+                donate_argnums=(0,) if donate else ())
         lockstep.register_fusion_manifest(
             fp, _member_kinds(steps),
             1 if t.distribution == ONED and t.num_shards > 1 else 0)
@@ -859,7 +861,8 @@ def _run_fused_agg(t: Table, group: FusionGroup, donate: bool):
             return R.dense_agg_tail(atree, mask, kn, vn, specs, sizes,
                                     los, n_slots, use_mxu)
 
-        fn = jax.jit(fused, donate_argnums=(0,) if donate else ())
+        fn = named_jit(
+            "fusedagg", fused, donate_argnums=(0,) if donate else ())
         lockstep.register_fusion_manifest(
             fp, _member_kinds(steps, agg), 0)
         progcheck.check_jit(

@@ -328,7 +328,7 @@ _build_cache: "OrderedDict[tuple, Optional[dict]]" = OrderedDict()
 
 # build-program cache keyed ("joinbuild", key dtypes, T, layout):
 # registered with the program observatory like every other kernel cache
-from bodo_tpu.utils.kernel_cache import KernelCache  # noqa: E402
+from bodo_tpu.utils.kernel_cache import KernelCache, named_jit  # noqa: E402
 _build_jit_cache = KernelCache(maxsize=config.kernel_cache_size,
                          subsystem="fusion_join")
 
@@ -395,7 +395,7 @@ def build_hash_table(right: Table, right_on, null_cols,
             dup = jnp.any(cnt > 1)
             return codes, owner, dup | unresolved
 
-        fn = jax.jit(bbody)
+        fn = named_jit("join_build_fused", bbody)
         _build_jit_cache[sig] = fn
     karrays = tuple((c.data, c.valid) for c in kcols)
     if built_fresh:
@@ -882,11 +882,11 @@ def _dispatch_chain(t, b, group, body, bargs, bspecs, out_names,
             def sharded(ptree, pcounts, bargs_):
                 out, cnt, unres = fused(ptree, pcounts[0], bargs_)
                 return out, cnt[None], unres[None]
-            fn = jax.jit(C.smap(
+            fn = named_jit("fusedjoin", C.smap(
                 sharded, in_specs=(P(ax), P(ax), bspecs),
                 out_specs=(P(ax), P(ax), P(ax)), mesh=m))
         else:
-            fn = jax.jit(fused)
+            fn = named_jit("fusedjoin", fused)
         _register_manifest(group, fp, multi, inprogram=False,
                            gather=build_inprogram)
         if t.distribution == ONED:
@@ -1061,7 +1061,7 @@ def _dispatch_agg(t, b, group, body, bargs, bspecs, agg_plan,
                                       _flatten_tree(cur3, post_names))
                 return (outp, ng3[None], ovf[None], p_unres[None])
 
-            fn = jax.jit(C.smap(
+            fn = named_jit("fusedjoin_groupby", C.smap(
                 sharded, in_specs=(P(ax), P(ax), bspecs),
                 out_specs=(P(ax), P(ax), P(ax), P(ax)), mesh=m))
             _register_manifest(group, fp, multi, inprogram=True,

@@ -21,6 +21,7 @@ from bodo_tpu.plan import logical as L
 from bodo_tpu.plan.optimizer import optimize
 from bodo_tpu.runtime import resilience, result_cache as _rcache
 from bodo_tpu.table.table import ONED, REP, Table
+from bodo_tpu.utils import tracing
 from bodo_tpu.utils.logging import log
 
 # session-level semantic result cache (runtime/result_cache.py): entries
@@ -36,8 +37,14 @@ _degrade_tls = threading.local()
 
 
 def execute(node: L.Node, optimize_first: bool = True) -> Table:
+    with tracing.event("query"):
+        return _execute(node, optimize_first)
+
+
+def _execute(node: L.Node, optimize_first: bool) -> Table:
     if optimize_first:
-        node = optimize(node)
+        with tracing.event("plan.optimize"):
+            node = optimize(node)
         if config.dump_plans:
             _dump(node)
     if config.plan_validate:
@@ -46,17 +53,18 @@ def execute(node: L.Node, optimize_first: bool = True) -> Table:
         # collectives dispatch — PlanInvariantError in milliseconds
         # instead of wrong answers or a wedged gang
         from bodo_tpu.analysis.plan_validator import validate_plan
-        validate_plan(node)
+        with tracing.event("plan.validate"):
+            validate_plan(node)
     # whole-stage fusion planning: annotate maximal pipeline-compatible
     # regions (filter/project chains + dense-agg roots) so _exec_inner
     # dispatches each as ONE compiled program. Planning is best-effort —
     # a failure here must cost per-node execution, never the query.
     try:
         from bodo_tpu.plan.fusion import plan_fusion_groups
-        plan_fusion_groups(node)
+        with tracing.event("plan.fusion"):
+            plan_fusion_groups(node)
     except Exception as e:  # noqa: BLE001 - fusion is an optimization
         log(1, f"fusion planning failed, executing unfused: {e}")
-    from bodo_tpu.utils import tracing
     if not tracing.is_tracing():
         return _rcache.cached_execute(node, _exec)
     # every traced execution belongs to a query: adopt the caller's
@@ -102,7 +110,6 @@ def _maybe_shard(t: Table) -> Table:
 
 
 def _exec(node: L.Node) -> Table:
-    from bodo_tpu.utils import tracing
     traced = tracing.is_tracing()
     if node._cached is not None:
         if traced:
@@ -275,7 +282,6 @@ def _exec_with_oom_retry(node: L.Node) -> Table:
                     return out
                 raise
             last = e
-            from bodo_tpu.utils import tracing
             with tracing.event("oom_retry", stage=type(node).__name__,
                                attempt=attempt + 1):
                 if not gov.handle_oom(e):
@@ -308,7 +314,6 @@ def _try_degrade(node: L.Node, err: Exception):
     for c in node.children:
         if c._cached is not None and c._cached.distribution == ONED:
             c._cached = c._cached.gather()
-    from bodo_tpu.utils import tracing
     _degrade_tls.force_rep = True
     try:
         with tracing.event("degrade_replicated", stage=stage):
@@ -409,7 +414,6 @@ def _exec_inner(node: L.Node) -> Table:
                 from bodo_tpu.analysis.plan_validator import \
                     validate_rewrite
                 validate_rewrite(node, repl)
-            from bodo_tpu.utils import tracing
             if tracing.is_tracing():
                 # re-anchor the substituted subtree's EXPLAIN paths
                 # under the join it replaced, flagged as replanned

@@ -45,7 +45,7 @@ from bodo_tpu.parallel.shuffle import _finalize, _plan_decomposition
 from bodo_tpu.plan import logical as L
 from bodo_tpu.table import dtypes as dt
 from bodo_tpu.table.table import (Column, REP, Table, round_capacity)
-from bodo_tpu.utils.kernel_cache import cached_builder
+from bodo_tpu.utils.kernel_cache import cached_builder, named_jit
 from bodo_tpu.utils.logging import log
 
 
@@ -678,7 +678,8 @@ def _build_reduce_step(sig: Tuple, cap: int, donate: bool):
                 ci += 1
         return tuple(out)
 
-    return jax.jit(step, donate_argnums=(0,) if donate else ())
+    return named_jit(
+        "reduce_stream", step, donate_argnums=(0,) if donate else ())
 
 
 class ReduceAccumulator:

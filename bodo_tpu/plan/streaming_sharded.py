@@ -46,7 +46,7 @@ from bodo_tpu.parallel.shuffle import (_MESHES, _mesh_key,
 from bodo_tpu.plan.streaming import _bucket_cap as _pow2_cap
 from bodo_tpu.table import dtypes as dt
 from bodo_tpu.table.table import Column, ONED, REP, Table
-from bodo_tpu.utils.kernel_cache import cached_builder
+from bodo_tpu.utils.kernel_cache import cached_builder, named_jit
 from bodo_tpu.utils.logging import log
 
 
@@ -69,8 +69,8 @@ def _build_recap(mesh_key, old_per: int, new_per: int):
             return jnp.concatenate([a, pad])
         return {n: (one(d), one(v)) for n, (d, v) in tree.items()}
 
-    return jax.jit(C.smap(body, in_specs=(P(axis),), out_specs=P(axis),
-                          mesh=mesh))
+    return named_jit("stream_recapacity", C.smap(
+        body, in_specs=(P(axis),), out_specs=P(axis), mesh=mesh))
 
 
 def shard_recapacity(t: Table, new_per: int, mesh=None) -> Table:
@@ -101,8 +101,8 @@ def _build_slicer(mesh_key, per: int, bcap: int):
             return lax.dynamic_slice_in_dim(a, o, bcap)
         return {n: (one(d), one(v)) for n, (d, v) in tree.items()}
 
-    return jax.jit(C.smap(body, in_specs=(P(axis), P(axis)),
-                          out_specs=P(axis), mesh=mesh))
+    return named_jit("stream_slice", C.smap(
+        body, in_specs=(P(axis), P(axis)), out_specs=P(axis), mesh=mesh))
 
 
 def table_batches_sharded(t: Table, batch_rows: int,
@@ -218,7 +218,7 @@ def _build_sharded_step(mesh_key, num_keys: int, specs: Tuple[str, ...],
 
     shd = C.smap(body, in_specs=(P(axis), P(axis), P(axis), P(axis)),
                  out_specs=(P(axis), P(axis), P(axis)), mesh=mesh)
-    return jax.jit(shd)
+    return named_jit("groupby_stream_sharded", shd)
 
 
 class ShardedGroupbyAccumulator:
@@ -859,8 +859,9 @@ def _build_append(mesh_key, state_cap: int, batch_cap: int, new_cap: int):
             out.append(z.at[idx].set(ba, mode="drop"))
         return tuple(out), (s0 + b0)[None]
 
-    return jax.jit(C.smap(body, in_specs=(P(ax), P(ax), P(ax), P(ax)),
-                          out_specs=(P(ax), P(ax)), mesh=mesh))
+    return named_jit("stream_append", C.smap(
+        body, in_specs=(P(ax), P(ax), P(ax), P(ax)),
+        out_specs=(P(ax), P(ax)), mesh=mesh))
 
 
 def append_sharded(state: Optional[Table], batch: Table,

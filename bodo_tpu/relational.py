@@ -39,7 +39,7 @@ from bodo_tpu.table import dtypes as dt
 from bodo_tpu.table.dict_utils import unify_dictionaries
 from bodo_tpu.table.table import Column, ONED, REP, Table, round_capacity
 
-from bodo_tpu.utils.kernel_cache import KernelCache
+from bodo_tpu.utils.kernel_cache import KernelCache, named_jit
 
 # relational cache keys are ("kind", schema/dist/mesh/static parts...):
 # the generic facet split in the observatory attributes retraces per kind
@@ -397,7 +397,7 @@ def assign_columns(t: Table, new: Dict[str, Expr]) -> Table:
         if fn is None:
             exprs = dict(new)
 
-            @jax.jit
+            @partial(named_jit, "project")
             def fn(tree):
                 # return ONLY the new columns: passing untouched inputs
                 # through a jitted function copies them (no donation) —
@@ -507,12 +507,13 @@ def filter_table(t: Table, predicate: Expr) -> Table:
             def sharded(tree, counts):
                 out_tree, cnt = body(tree, counts[0])
                 return out_tree, cnt[None]
-            fn = jax.jit(C.smap(sharded, in_specs=(P(ax), P(ax)),
-                                out_specs=(P(ax), P(ax)), mesh=m))
+            fn = named_jit("filter", C.smap(
+                sharded, in_specs=(P(ax), P(ax)), out_specs=(P(ax), P(ax)),
+                mesh=m))
         else:
             def rep(tree, count):
                 return body(tree, count)
-            fn = jax.jit(rep)
+            fn = named_jit("filter", rep)
         _jit_cache[key] = fn
 
     if t.distribution == ONED:
@@ -873,7 +874,7 @@ def _packed_key_table(t: Table, pack, with_valid: bool = True) -> Table:
     if fn is None:
         pk = tuple(pack)
 
-        @jax.jit
+        @partial(named_jit, "pack_keys")
         def fn(tree):
             return _pack_keys_kernel(tree, pk, None)
         _jit_cache[key] = fn
@@ -897,7 +898,7 @@ def _groupby_agg_packed(t: Table, keys, aggs, pack) -> Table:
     if fn is None:
         pk = tuple(pack)
 
-        @jax.jit
+        @partial(named_jit, "groupby_unpack_keys")
         def fn(packed):
             return _unpack_keys(packed, pk)
         _jit_cache[key_un] = fn
@@ -1069,7 +1070,7 @@ def _groupby_agg_dense(t: Table, keys, aggs, ranges) -> Table:
             return dense_agg_tail(tree, K.row_mask(count, cap), kn, vn,
                                   specs, sizes, los, n_slots, use_mxu)
 
-        fn = jax.jit(body)
+        fn = named_jit("groupby_dense", body)
         _jit_cache[key] = fn
 
     out_keys, out_vals, ng = fn(tsel.device_data(), jnp.asarray(t.nrows))
@@ -1115,8 +1116,9 @@ def _groupby_agg_colocated(t: Table, keys, aggs) -> Table:
                                        len(kn))
             return (pk, pv), ng[None]
 
-        fn = jax.jit(C.smap(sharded, in_specs=(P(ax), P(ax)),
-                            out_specs=(P(ax), P(ax)), mesh=m))
+        fn = named_jit("groupby_colocated", C.smap(
+            sharded, in_specs=(P(ax), P(ax)), out_specs=(P(ax), P(ax)),
+            mesh=m))
         _jit_cache[key] = fn
 
     (out_keys, out_vals), ngs = fn(t.device_data(), t.counts_device())
@@ -1379,7 +1381,7 @@ def _join_dense_try(left, right, left_on, right_on, how, suffixes,
                 jnp.arange(cap, dtype=jnp.int32), mode="drop")
             return lut, dup
 
-        bfn = jax.jit(bbody)
+        bfn = named_jit("join_build_dense", bbody)
         _jit_cache[bkey] = bfn
 
     lut, dup = bfn(ba, jnp.asarray(right.nrows))
@@ -1422,7 +1424,7 @@ def _join_dense_try(left, right, left_on, right_on, how, suffixes,
             out_p2 = tuple((d, v) for d, v in p_arrays)
             return out_p2, tuple(out_b), pcount
 
-        pfn = jax.jit(pbody)
+        pfn = named_jit("join_probe_dense", pbody)
         _jit_cache[pkey] = pfn
 
     out_p, out_b, cnt = pfn(pa, ba, lut, jnp.asarray(left.nrows))
@@ -1483,7 +1485,7 @@ def _join_hash_try(left, right, left_on, right_on, how, suffixes,
                 dup = jnp.any(cnt > 1)
                 return codes, owner, dup | unresolved
 
-            bfn = jax.jit(bbody)
+            bfn = named_jit("join_build_hash", bbody)
             _jit_cache[bkey] = bfn
 
         bcodes, owner, bad = bfn(ba, jnp.asarray(right.nrows))
@@ -1520,7 +1522,7 @@ def _join_hash_try(left, right, left_on, right_on, how, suffixes,
             out_p2 = tuple((d, v) for d, v in p_arrays)
             return out_p2, tuple(out_b), pcount, p_unres
 
-        pfn = jax.jit(pbody)
+        pfn = named_jit("join_probe_hash", pbody)
         _jit_cache[pkey] = pfn
 
     out_p, out_b, cnt, p_unres = pfn(pa, ba, bcodes, owner,
@@ -1662,11 +1664,12 @@ def _build_join_sharded_fn(mesh_key, nk, how, out_cap, broadcast: bool,
             null_equal, method)
         return out_p, out_b, cnt[None], ovf[None], unres[None]
 
-    fn = jax.jit(C.smap(body,
-                        in_specs=(P(ax), P() if broadcast else P(ax),
-                                  P(ax), P() if broadcast else P(ax)),
-                        out_specs=(P(ax), P(ax), P(ax), P(ax), P(ax)),
-                        mesh=mesh))
+    fn = named_jit("join_sharded", C.smap(
+        body,
+        in_specs=(P(ax), P() if broadcast else P(ax),
+                  P(ax), P() if broadcast else P(ax)),
+        out_specs=(P(ax), P(ax), P(ax), P(ax), P(ax)),
+        mesh=mesh))
     _jit_cache[key] = fn
     return fn
 
@@ -1717,7 +1720,7 @@ def _join_sharded(left, right, left_on, right_on, how, suffixes,
                 return join_count(p_arrays[:nk], b_arrays[:nk], pcounts[0],
                                   bcounts_[0], nk, how, null_equal,
                                   method)[0][None]
-            cfn = jax.jit(C.smap(
+            cfn = named_jit("join_count_sharded", C.smap(
                 cbody,
                 in_specs=(P(ax), P() if broadcast else P(ax), P(ax),
                           P() if broadcast else P(ax)),
@@ -1775,8 +1778,9 @@ def _cross_join(left, right, suffixes) -> Table:
                                           bcount[0], out_cap)
                 return op, ob, cnt[None]
 
-            fn = jax.jit(C.smap(body, in_specs=(P(ax), P(), P(ax), P()),
-                                out_specs=(P(ax), P(ax), P(ax)), mesh=m))
+            fn = named_jit("crossjoin", C.smap(
+                body, in_specs=(P(ax), P(), P(ax), P()),
+                out_specs=(P(ax), P(ax), P(ax)), mesh=m))
             _jit_cache[key] = fn
         out_p, out_b, cnts = fn(pa, ba, left.counts_device(),
                                 jnp.asarray([right.nrows], dtype=jnp.int64))
@@ -1874,12 +1878,12 @@ def window_table(t: Table, specs: Sequence[Tuple[str, str, Optional[int],
 
             def sharded_fn(tree, counts):
                 return body(tree, counts, True)
-            fn = jax.jit(C.smap(sharded_fn, in_specs=(P(ax), P(ax)),
-                                out_specs=P(ax), mesh=m))
+            fn = named_jit("window", C.smap(
+                sharded_fn, in_specs=(P(ax), P(ax)), out_specs=P(ax), mesh=m))
         else:
             def rep_fn(tree, counts):
                 return body(tree, counts, False)
-            fn = jax.jit(rep_fn)
+            fn = named_jit("window", rep_fn)
         _jit_cache[key] = fn
 
     counts = t.counts_device() if t.distribution == ONED \
@@ -2030,8 +2034,8 @@ def _global_rank_sharded(t: Table, order_by, specs, ascending,
                 out.append(jnp.where(padmask, r.astype(jnp.int64), 0))
             return tuple(out)
 
-        fn = jax.jit(C.smap(body, in_specs=(P(ax), P(ax)),
-                            out_specs=P(ax), mesh=m))
+        fn = named_jit("window_rank_global", C.smap(
+            body, in_specs=(P(ax), P(ax)), out_specs=P(ax), mesh=m))
         _jit_cache[key] = fn
     outs = fn(t2.device_data(), t2.counts_device())
     res = t2.with_columns(t2.columns)
@@ -2065,10 +2069,10 @@ def _rank_window_exec(t: Table, partition_by, order_by, specs,
 
             def sharded(tree, counts):
                 return body(tree, counts[0])
-            fn = jax.jit(C.smap(sharded, in_specs=(P(ax), P(ax)),
-                                out_specs=P(ax), mesh=m))
+            fn = named_jit("window_rank", C.smap(
+                sharded, in_specs=(P(ax), P(ax)), out_specs=P(ax), mesh=m))
         else:
-            fn = jax.jit(body)
+            fn = named_jit("window_rank", body)
         _jit_cache[key] = fn
 
     counts = t.counts_device() if t.distribution == ONED \
@@ -2226,10 +2230,10 @@ def _agg_window_exec(t: Table, partition_by, order_by, specs,
 
             def sharded(tree, counts):
                 return body(tree, counts[0])
-            fn = jax.jit(C.smap(sharded, in_specs=(P(ax), P(ax)),
-                                out_specs=P(ax), mesh=m))
+            fn = named_jit("window_agg", C.smap(
+                sharded, in_specs=(P(ax), P(ax)), out_specs=P(ax), mesh=m))
         else:
-            fn = jax.jit(body)
+            fn = named_jit("window_agg", body)
         _jit_cache[key] = fn
 
     counts = t.counts_device() if t.distribution == ONED \
@@ -2367,13 +2371,13 @@ def reduce_table(t: Table, aggs: Sequence[Tuple[str, str, str]]) -> Dict:
 
             def sharded(tree, counts):
                 return tuple(o[None] for o in body(tree, counts[0]))
-            fn = jax.jit(C.smap(sharded, in_specs=(P(ax), P(ax)),
-                                out_specs=tuple(P(ax) for _ in specs),
-                                mesh=m))
+            fn = named_jit("reduce", C.smap(
+                sharded, in_specs=(P(ax), P(ax)),
+                out_specs=tuple(P(ax) for _ in specs), mesh=m))
         else:
             def rep(tree, count):
                 return tuple(o[None] for o in body(tree, count))
-            fn = jax.jit(rep)
+            fn = named_jit("reduce", rep)
         _jit_cache[key] = fn
 
     counts_in = t.counts_device() if t.distribution == ONED \
@@ -2440,7 +2444,7 @@ def _reduce_quantile(t: Table, col: str, q: float) -> float:
             cnt = jnp.sum(ok)
             return s_val, cnt
 
-        fn = jax.jit(body)
+        fn = named_jit("reduce_quantile", body)
         _jit_cache[key] = fn
     s_val, cnt = fn(src.device_data(), jnp.asarray(src.nrows))
     n = int(jax.device_get(cnt))
@@ -2498,7 +2502,7 @@ def _shrink_fn(S: int, old_cap: int, new_cap: int):
     key = ("shrink", S, old_cap, new_cap)
     fn = _jit_cache.get(key)
     if fn is None:
-        @jax.jit
+        @partial(named_jit, "shrink_to_fit")
         def fn(tree):
             out = {}
             for n, (d, v) in tree.items():
@@ -2579,8 +2583,9 @@ def shuffle_by_key(t: Table, key_cols: Sequence[str]) -> Table:
                 out, cnt2, _ = shuffle_rows(dest, flat, cnt, S, cap, ax)
                 return _rebuild_from_flat(out, tuple(slots2)), cnt2[None]
             slots2 = [t.column(n).valid is not None for n in korder]
-            fn = jax.jit(C.smap(body, in_specs=(P(ax), P(ax)),
-                                out_specs=(P(ax), P(ax)), mesh=m))
+            fn = named_jit("shuffle_by_key", C.smap(
+                body, in_specs=(P(ax), P(ax)), out_specs=(P(ax), P(ax)),
+                mesh=m))
             _jit_cache[key] = fn
         karrays = tuple((t.column(n).data, t.column(n).valid)
                         for n in korder)

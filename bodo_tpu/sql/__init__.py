@@ -53,17 +53,20 @@ class BodoSQLContext:
             return ddl
         from bodo_tpu.pandas_api.frame import BodoDataFrame
         from bodo_tpu.sql import plan_cache
-        sig = self._schema_sig()
-        ast = plan_cache.get(query, sig)
-        if ast is None:
-            ast = parse_sql(query)
-            # pickle to disk BEFORE planning — the planner rewrites AST
-            # nodes in place, so only cache-served objects need copying
-            plan_cache.put(query, sig, ast)
-        else:
-            import copy
-            ast = copy.deepcopy(ast)
-        plan, names = Planner(self._tables).plan(ast)
+        from bodo_tpu.utils import tracing
+        with tracing.event("plan.sql"):
+            sig = self._schema_sig()
+            ast = plan_cache.get(query, sig)
+            if ast is None:
+                ast = parse_sql(query)
+                # pickle to disk BEFORE planning — the planner rewrites
+                # AST nodes in place, so only cache-served objects need
+                # copying
+                plan_cache.put(query, sig, ast)
+            else:
+                import copy
+                ast = copy.deepcopy(ast)
+            plan, names = Planner(self._tables).plan(ast)
         return BodoDataFrame(plan)
 
     def _try_ddl(self, query: str):
@@ -124,9 +127,12 @@ class BodoSQLContext:
     def generate_plan(self, query: str):
         """Return the optimized logical plan (EXPLAIN analogue)."""
         from bodo_tpu.plan.optimizer import optimize
-        ast = parse_sql(query)
-        plan, _ = Planner(self._tables).plan(ast)
-        return optimize(plan)
+        from bodo_tpu.utils import tracing
+        with tracing.event("plan.sql"):
+            ast = parse_sql(query)
+            plan, _ = Planner(self._tables).plan(ast)
+        with tracing.event("plan.optimize"):
+            return optimize(plan)
 
     def explain(self, query: str) -> str:
         """Pretty-printed optimized plan."""

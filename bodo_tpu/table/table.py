@@ -307,14 +307,14 @@ class Table:
 
     def to_pandas(self) -> pd.DataFrame:
         from bodo_tpu.utils import tracing
-        with tracing.event("to_pandas") as ev:
+        with tracing.event("to_pandas", rows=self.nrows):
             t = self.gather() if self.distribution == ONED else self
             out = {}
-            for name, col in t.columns.items():
-                out[name] = col.to_numpy(t.nrows)
-            if ev is not None:
-                ev["rows"] = t.nrows
-            return pd.DataFrame(out)
+            with tracing.event("result.d2h"):
+                for name, col in t.columns.items():
+                    out[name] = col.to_numpy(t.nrows)
+            with tracing.event("result.frame"):
+                return pd.DataFrame(out)
 
     # ---- distribution ----------------------------------------------------
     def shard(self) -> "Table":
@@ -331,6 +331,11 @@ class Table:
         any single host."""
         if self.distribution == ONED:
             return self
+        from bodo_tpu.utils import tracing
+        with tracing.event("dist.shard", rows=self.nrows):
+            return self._shard_inner()
+
+    def _shard_inner(self) -> "Table":
         m = mesh_mod.get_mesh()
         s = mesh_mod.num_shards(m)
         per = round_capacity(-(-max(self.nrows, 1) // s))
@@ -387,8 +392,10 @@ class Table:
         if self.distribution == REP:
             return self
         from bodo_tpu.parallel import comm
-        with comm.collective_span("gather",
-                                  bytes_in=comm.table_bytes(self)) as _sp:
+        from bodo_tpu.utils import tracing
+        with tracing.event("dist.gather", rows=self.nrows), \
+                comm.collective_span(
+                    "gather", bytes_in=comm.table_bytes(self)) as _sp:
             out = self._gather_inner()
             _sp["bytes_out"] = comm.table_bytes(out)
         return out

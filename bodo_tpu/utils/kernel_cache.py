@@ -216,6 +216,20 @@ def _leaf_key(x):
     return ("v", x)
 
 
+def named_jit(name: str, fun, **jit_kwargs):
+    """`jax.jit` of an operator's closure under its program family's
+    name. XLA calls a program `jit_<function name>`, and the closures
+    are all `body`, `fused`, `sharded`...: the name is what a device
+    trace, a compile log and the persistent cache's file names tell one
+    operator's program from another's by (`jit_groupby_dense`,
+    `jit_fusedjoin`). Same registration duty as a bare `jax.jit`: the
+    caller stores the result in a subsystem-tagged KernelCache."""
+    import jax
+    fun.__name__ = fun.__qualname__ = name
+    # the caller registers it  # shardcheck: ignore[unregistered-jit]
+    return jax.jit(fun, **jit_kwargs)
+
+
 def bounded_jit(fun=None, *, static_argnames=(), maxsize=None):
     """`jax.jit` whose live compiled executables are BOUNDED.
 

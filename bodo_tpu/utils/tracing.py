@@ -8,6 +8,13 @@ executor wraps every physical operator in an event, so `dump()` yields a
 chrome://tracing-loadable timeline and `profile()` the per-operator
 aggregate table.
 
+Device traces: whatever the tracing level, `event()` also enters a
+`jax.profiler.TraceAnnotation` named `bodo:<name>` while a profiler
+session listens (and costs one inactive-check while none does), so any
+`jax.profiler` trace shows the engine's spans on the device events'
+clock, nested by thread. `event()` is the one span entry point: new
+host timings belong in it, not in another clock beside it.
+
 Query scoping: a `query_span()` context assigns every event inside it a
 query id (contextvar; exported as BODO_TPU_QUERY_ID so spawned gang
 workers inherit the same identity), and the per-operator aggregates are
@@ -177,14 +184,46 @@ def query_wall_s(qid: str) -> Optional[float]:
 # events
 # ---------------------------------------------------------------------------
 
+_NO_SPAN = contextlib.nullcontext()
+
+
+def profiler_listening() -> bool:
+    """True while a `jax.profiler` session records host spans. jax is
+    never imported from here (gang workers load this module before jax):
+    where it is not loaded yet, nothing can be listening."""
+    prof = sys.modules.get("jax.profiler")
+    return prof is not None and prof.TraceAnnotation.is_enabled()
+
+
+def _profiler_span(name: str, args: dict):
+    """The span as the profiler's trace holds it: a TraceAnnotation
+    `bodo:<name>` on the device events' clock, its parent given by
+    nesting on its thread, `args` and the query id as its arguments.
+    Nothing is built or formatted unless a session listens."""
+    if not profiler_listening():
+        return _NO_SPAN
+    qid = current_query_id()
+    if qid is not None:
+        args = {**args, "query_id": qid}
+    return sys.modules["jax.profiler"].TraceAnnotation("bodo:" + name,
+                                                       **args)
+
+
 @contextlib.contextmanager
 def event(name: str, **args):
-    """Trace one operator/phase. Cheap no-op when tracing is off. The
-    active query id (if any) is attached to the event and keys the
-    per-query aggregate row."""
-    if not is_tracing():
-        yield None
-        return
+    """Trace one operator/phase: always as a `bodo:<name>` span of any
+    listening `jax.profiler` session, and with tracing on (level >= 1)
+    also as a ring-buffer event, where the active query id (if any) is
+    attached and keys the per-query aggregate row. Yields None, at the
+    cost of one inactive-check, when neither listens."""
+    with _profiler_span(name, args):
+        if not is_tracing():
+            yield None
+            return
+        yield from _ring_event(name, args)
+
+
+def _ring_event(name: str, args: dict):
     t0 = time.perf_counter()
     qid = current_query_id()
     info: dict = {}
@@ -217,7 +256,7 @@ def event(name: str, **args):
             a["count"] += 1
             a["total_s"] += dur
             a["max_s"] = max(a["max_s"], dur)
-            a["rows"] += int(info.get("rows", 0))
+            a["rows"] += int(ev_args.get("rows", 0))
 
 
 def reset() -> None:
@@ -741,15 +780,13 @@ def traced_table_op(fn):
     traced frame records (operators re-enter each other — distributed
     groupby calls local groupby, windows call sort — and double-counting
     would make profile totals exceed wall time). No-op when tracing is
-    off (one predicate check)."""
+    off and no profiler session listens (two predicate checks)."""
     import functools
 
     @functools.wraps(fn)
     def wrapper(*a, **k):
-        if not is_tracing():
-            return fn(*a, **k)
-        depth = getattr(_op_depth, "d", 0)
-        if depth:
+        if not (is_tracing() or profiler_listening()) \
+                or getattr(_op_depth, "d", 0):
             return fn(*a, **k)
         _op_depth.d = 1
         try:

@@ -95,9 +95,13 @@ class Config:
         default_factory=lambda: _env_float("BODO_TPU_SHUFFLE_SKEW", 2.0)
     )
     # Dense (sort-free) groupby: when the exact product of key ranges is at
-    # most this many slots, rows scatter straight into dense slots and all
-    # aggregations are one segment pass (no lax.sort). ~4M slots * 8B * a
-    # few columns of transient dense arrays.
+    # most this many slots, every row gets a dense slot and the
+    # aggregations run per slot (no lax.sort). How they run is
+    # relational.dense_route's choice by shape, not a setting: masked
+    # reductions over the rows up to relational.DENSE_REDUCE_MAX_SLOTS
+    # slots, segment scatters above (~4M slots * 8B * a few columns of
+    # transient dense arrays), the MXU one-hot matmul for float32 values
+    # with Pallas on.
     dense_groupby_max_slots: int = field(
         default_factory=lambda: _env_int("BODO_TPU_DENSE_GROUPBY_SLOTS",
                                          1 << 22)

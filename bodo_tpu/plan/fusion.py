@@ -29,10 +29,15 @@ This module implements the plan-level version of that inversion:
                     eval-then-mask order of relational.filter_table).
                     One `K.compact` runs at group exit — or ZERO when a
                     terminal dense Aggregate consumes the mask directly
-                    via `relational.dense_agg_tail`, which routes the
-                    MXU one-hot-matmul accumulate
-                    (`ops/pallas_kernels.dense_accumulate`) into the
-                    pipeline when the gate admits it.
+                    via `relational.dense_agg_tail`, which aggregates
+                    per slot by one of three routes
+                    (`relational.dense_route`): the MXU one-hot-matmul
+                    accumulate (`ops/pallas_kernels.dense_accumulate`)
+                    when its gate admits it, masked reductions over
+                    the rows when the slot space is a handful
+                    (`DENSE_REDUCE_MAX_SLOTS`), `segment_*` scatters
+                    otherwise. The `fused_group` span carries the
+                    route as `dense_route`; `stats()` counts by it.
 
   sharding          derived from the shardcheck REP/DIST lattice
                     (`analysis/plan_validator.check_fusion_boundary`
@@ -118,6 +123,7 @@ from bodo_tpu.table import dtypes as dt
 from bodo_tpu.table.table import Column, ONED, REP, Table
 from bodo_tpu.runtime import xla_observatory as xobs
 from bodo_tpu.utils.kernel_cache import FusionProgramCache, named_jit
+from bodo_tpu.utils import tracing
 from bodo_tpu.utils.logging import log
 
 # NOTE: bodo_tpu.relational imports this module at module level (for
@@ -155,6 +161,8 @@ _programs = FusionProgramCache(maxsize=config.kernel_cache_size,
 _stats = {"groups_planned": 0, "groups_executed": 0, "stream_chains": 0,
           "partial_agg": 0, "fallbacks": 0, "donated": 0,
           "budget_spent": 0,
+          # fused terminal aggregates by relational.dense_route
+          "dense_reduce": 0, "dense_scatter": 0, "dense_mxu": 0,
           # scan batches entering fused chains straight off the device
           # decode path (io/device_decode.py) — no host round-trip
           # between ingest and the compiled chain body
@@ -844,8 +852,10 @@ def _run_fused_agg(t: Table, group: FusionGroup, donate: bool):
     vn = [c for c, _, _ in agg.aggs]
     specs = tuple(op for _, op, _ in agg.aggs)
     donate = bool(donate) and jax.default_backend() in ("tpu", "gpu")
+    route = R.dense_route(n_slots, specs, use_mxu)
+    tracing.annotate(dense_route=route)
     sig = ("fusedagg", R._sig(t), _steps_sig(steps), tuple(kn),
-           tuple(agg.aggs), sizes, los, use_mxu, donate)
+           tuple(agg.aggs), sizes, los, route, donate)
     fp = _group_fp(fp_sig)
     fn = _programs.lookup(sig)
     compiled = fn is None
@@ -894,6 +904,7 @@ def _run_fused_agg(t: Table, group: FusionGroup, donate: bool):
         _programs.record_compile("fused_stage", dt_s)
     if donate:
         _stats["donated"] += 1
+    _stats["dense_" + route] += 1
 
     import types as _types
     cols: Dict[str, Column] = {}
@@ -915,6 +926,7 @@ def _run_fused_agg(t: Table, group: FusionGroup, donate: bool):
     res._fusion_compile_s = dt_s if compiled else 0.0
     res._fusion_donated = donate  # type: ignore[attr-defined]
     res._fusion_pallas = use_mxu  # type: ignore[attr-defined]
+    res._fusion_dense_route = route  # type: ignore[attr-defined]
     return res
 
 
@@ -929,7 +941,6 @@ def execute_group(group: FusionGroup, exec_child) -> Optional[Table]:
     Runtime faults (OOM, degradable collectives, armed chaos faults)
     propagate — the stage-boundary envelope in physical.py owns them."""
     from bodo_tpu.plan import physical
-    from bodo_tpu.utils import tracing
 
     t = exec_child(group.input)
     force_rep = getattr(physical._degrade_tls, "force_rep", False)
@@ -1010,8 +1021,9 @@ def _finish_group(group: FusionGroup, t: Table, out: Table) -> None:
     }
     if getattr(out, "_fusion_pallas", False):
         info["pallas"] = True
+    if hasattr(out, "_fusion_dense_route"):
+        info["dense_route"] = out._fusion_dense_route
     group.root._fusion_info = info
-    from bodo_tpu.utils import tracing
     if tracing.is_tracing():
         from bodo_tpu.plan import explain
         root_path = getattr(group.root, "_explain_path", None)

@@ -104,6 +104,7 @@ def _sig(t: Table) -> Tuple:
 # projection / assignment
 # ---------------------------------------------------------------------------
 
+from bodo_tpu.utils import tracing
 from bodo_tpu.utils.tracing import traced_table_op as _traced
 
 
@@ -945,10 +946,16 @@ def _dense_slots(key_arrays, los, sizes, mask, strict_range: bool = False):
 @fusion_stage
 def dense_agg_tail(tree, live, kn, vn, specs, sizes, los, n_slots: int,
                    use_mxu: bool):
-    """Traced dense-groupby tail: scatter `live` rows into mixed-radix
-    dense slots and reduce every aggregation in one segment (or MXU
-    one-hot matmul) pass, then decode slot indices back into key
-    columns and compact the present slots ascending.
+    """Traced dense-groupby tail: give every `live` row its mixed-radix
+    dense slot, aggregate per slot, then decode slot indices back into
+    key columns and compact the present slots ascending. The per-slot
+    aggregation takes one of three routes (`dense_route`): `mxu`, one
+    one-hot matmul over float32 columns, when the caller's `use_mxu`
+    gate (`dense_mxu_ok`, Pallas on) admits it; else `reduce`, masked
+    tree reductions over the rows, one a slot, in the values' own
+    dtype, when the slot space is at most `DENSE_REDUCE_MAX_SLOTS` and
+    every spec is in `DENSE_REDUCE_OPS`; else `scatter`, one
+    `segment_*` scatter pass a spec (`ops/groupby._segment_agg`).
 
     Shared between `_groupby_agg_dense` (live = row_mask(count)) and
     the whole-stage fusion agg stage (plan/fusion.py — live = the fused
@@ -960,7 +967,8 @@ def dense_agg_tail(tree, live, kn, vn, specs, sizes, los, n_slots: int,
     from bodo_tpu.ops.groupby import _segment_agg
     cap = tree[kn[0]][0].shape[0]
     slot, padmask = _dense_slots([tree[n] for n in kn], los, sizes, live)
-    if use_mxu:
+    route = dense_route(n_slots, specs, use_mxu)
+    if route == "mxu":
         # one fused one-hot matmul: [present | per-spec columns]
         mcols, moks = [padmask.astype(jnp.float32)], [padmask]
         plan = []
@@ -994,6 +1002,9 @@ def dense_agg_tail(tree, live, kn, vn, specs, sizes, los, n_slots: int,
                 cnt = sums[cnt_idx]
                 m = sums[s_idx] / jnp.maximum(cnt, 1.0)
                 outs.append((jnp.where(cnt > 0, m, jnp.nan), None))
+    elif route == "reduce":
+        present, outs = _dense_reduce_aggs(tree, vn, specs, slot, padmask,
+                                           n_slots)
     else:
         present = jax.ops.segment_sum(
             padmask.astype(jnp.int32), slot,
@@ -1031,13 +1042,121 @@ def dense_mxu_ok(capacity: int, val_dtypes, specs) -> bool:
             and all(_ok(d, op) for d, op in zip(val_dtypes, specs)))
 
 
+# The reduce route of `dense_agg_tail` costs rows x n_slots
+# compare-select-accumulates an aggregate, the scatter route a fixed
+# cost a row whatever the slots (66 ns a row a scatter at 6 slots, 153
+# ns at 3.8M; v5e, ledger, PR 27). This is the largest slot count of
+# the chip sweep (`chip_dense_sweep.py`: 6, 25, 64, 256, 1024 slots on
+# the Q1-shaped tail, 3,000,064 rows, eight float64 specs) at which
+# the reduce route was still at least twice as fast: at 1024 slots it
+# read 0.149 s against the scatter route's 3.41 s, so the crossover
+# lies above the sweep. The table is in PERF.md section 6 (PR 28).
+DENSE_REDUCE_MAX_SLOTS = 1024
+
+# specs the reduce route implements; any other keeps the whole tail on
+# the scatter route, as `dense_mxu_ok` keeps it off the MXU
+DENSE_REDUCE_OPS = frozenset(
+    ("sum", "sumnull", "sum64", "count", "size", "mean", "min", "max"))
+
+
+def dense_route(n_slots: int, specs, use_mxu: bool) -> str:
+    """Which realisation `dense_agg_tail` takes for this shape: `mxu`,
+    `reduce` or `scatter` (its docstring says what each is). Callers
+    put it on their span and into their program-cache key."""
+    if use_mxu:
+        return "mxu"
+    if n_slots <= DENSE_REDUCE_MAX_SLOTS and \
+            all(op in DENSE_REDUCE_OPS for op in specs):
+        return "reduce"
+    return "scatter"
+
+
+def _dense_reduce_aggs(tree, vn, specs, slot, padmask, n_slots: int):
+    """The reduce route of `dense_agg_tail`: for each slot g, every
+    aggregate is `reduce(where(slot == g & ok, v, identity))` over the
+    rows, in the dtype `_segment_agg` accumulates in (float64 sums stay
+    float64, int sums int64; counts are int32 sums widened at the end).
+    Distinct terms are computed once: one count per distinct `ok` mask,
+    one sum per (column, dtype) shared by `sum` and `mean`. The `vmap`
+    over slots keeps compare and select inside the reductions' fusion:
+    nothing of [rows, n_slots] is materialised.
+    Returns (present bool[n_slots], [(data, valid)] per spec)."""
+    masks = {None: padmask}  # ok-mask key -> mask; None: every live row
+    terms = {}  # term key -> (mask key, values, identity, reducer)
+
+    def count_term(mk):
+        terms.setdefault(("cnt", mk), (mk, np.int32(1), np.int32(0),
+                                       partial(jnp.sum, dtype=jnp.int32)))
+        return "cnt", mk
+
+    size = count_term(None)
+    plan = []  # per spec: (op, count term or None, value term or None)
+    for c, op in zip(vn, specs):
+        d, v = tree[c]
+        if op == "size":
+            plan.append((op, size, None))
+            continue
+        mk = None
+        if v is not None or jnp.issubdtype(d.dtype, jnp.floating):
+            mk = c
+            masks.setdefault(c, K.value_ok(d, v, padmask))
+        if op == "count":
+            plan.append((op, count_term(mk), None))
+            continue
+        if op in ("min", "max"):
+            if jnp.issubdtype(d.dtype, jnp.floating):
+                ident = np.inf if op == "min" else -np.inf
+            elif d.dtype == jnp.bool_:
+                ident = op == "min"
+            else:
+                info = jnp.iinfo(d.dtype)
+                ident = info.max if op == "min" else info.min
+            term = (op, c)
+            terms.setdefault(term, (mk, d, jnp.array(ident, d.dtype),
+                                    jnp.min if op == "min" else jnp.max))
+        else:
+            rdt = result_dtype(op, d.dtype)
+            term = ("sum", c, rdt)
+            terms.setdefault(term, (mk, d.astype(rdt), 0, jnp.sum))
+        # pandas: sum over an all-null group is 0, and needs no count
+        plan.append((op, None if op in ("sum", "sum64") else count_term(mk),
+                     term))
+
+    # the slot of every row that takes part, per distinct mask; -1 is
+    # no slot
+    part = {mk: jnp.where(m, slot, np.int32(-1)) for mk, m in masks.items()}
+
+    def one_slot(g):
+        return tuple(red(jnp.where(part[mk] == g, v, ident))
+                     for mk, v, ident, red in terms.values())
+
+    res = dict(zip(terms, jax.vmap(one_slot)(
+        jnp.arange(n_slots, dtype=jnp.int32))))
+    outs = []
+    for op, cnt_term, term in plan:
+        cnt = None if cnt_term is None else res[cnt_term].astype(jnp.int64)
+        if term is None:  # count, size
+            outs.append((cnt, None))
+        elif cnt is None:  # sum, sum64
+            outs.append((res[term], None))
+        elif op == "mean":
+            m = res[term] / jnp.maximum(cnt, 1)
+            outs.append((jnp.where(cnt > 0, m, jnp.nan), None))
+        else:  # SQL: SUM / MIN / MAX over an all-null group is NULL
+            outs.append((res[term], cnt > 0))
+    return res[size] > 0, outs
+
+
 def _groupby_agg_dense(t: Table, keys, aggs, ranges) -> Table:
     """Sort-free dense groupby for small key spaces.
 
     When every key has a host-known range whose exact product K fits the
-    slot budget, rows scatter directly into K dense slots (mixed-radix
-    slot id) and every aggregation is one `segment_*` pass — no lax.sort
-    at all. Group keys are reconstructed from the slot index and compacted
+    slot budget, every row gets one of K dense slots (mixed-radix slot
+    id) and `dense_agg_tail` aggregates per slot — no lax.sort at all:
+    masked reductions over the rows for a handful of slots, `segment_*`
+    scatters for many, the MXU one-hot matmul for float32 columns with
+    Pallas on (`dense_route`; the span carries which). Group keys are
+    reconstructed from the slot index and compacted
     ascending (slot order == lexicographic key order). This is the
     reference's one-pass hash groupby specialized to a perfect hash
     (reference: bodo/libs/groupby/_groupby.cpp hash-table path; SURVEY §7
@@ -1059,8 +1178,10 @@ def _groupby_agg_dense(t: Table, keys, aggs, ranges) -> Table:
                and dense_mxu_ok(t.capacity,
                                 [t.column(c).data.dtype for c in val_names],
                                 specs))
+    route = dense_route(n_slots, specs, use_mxu)
+    tracing.annotate(dense_route=route)
     key = ("gbdense", _sig(tsel), tuple(keys), tuple(zip(val_names, specs)),
-           sizes, los, use_mxu)
+           sizes, los, route)
     fn = _jit_cache.get(key)
     if fn is None:
         kn, vn = list(keys), list(val_names)
@@ -2558,7 +2679,6 @@ def shuffle_by_key(t: Table, key_cols: Sequence[str]) -> Table:
         wait = lockstep.pre_collective("shuffle_by_key")
     from bodo_tpu.parallel import comm
     from bodo_tpu.plan import adaptive
-    from bodo_tpu.utils import tracing
     adaptive.observe_shuffle(t, key_cols)
     with tracing.event("shuffle_by_key", keys=list(key_cols)) as ev, \
             comm.collective_span("shuffle_by_key",

@@ -209,24 +209,50 @@ def _profiler_span(name: str, args: dict):
                                                        **args)
 
 
+# info dicts of the events open on this thread, innermost last
+_open = threading.local()
+
+
 @contextlib.contextmanager
 def event(name: str, **args):
     """Trace one operator/phase: always as a `bodo:<name>` span of any
     listening `jax.profiler` session, and with tracing on (level >= 1)
     also as a ring-buffer event, where the active query id (if any) is
-    attached and keys the per-query aggregate row. Yields None, at the
-    cost of one inactive-check, when neither listens."""
-    with _profiler_span(name, args):
-        if not is_tracing():
+    attached and keys the per-query aggregate row. Yields a dict for
+    arguments only known inside the span (`rows`, and what `annotate`
+    adds from deeper frames): they land beside `args` in both places.
+    Yields None, at the cost of one inactive-check, when neither
+    listens."""
+    with _profiler_span(name, args) as span:
+        if span is None and not is_tracing():
             yield None
             return
-        yield from _ring_event(name, args)
+        info: dict = {}
+        stack = _open.__dict__.setdefault("stack", [])
+        stack.append(info)
+        try:
+            if is_tracing():
+                yield from _ring_event(name, args, info)
+            else:
+                yield info
+        finally:
+            stack.pop()
+            if span is not None and info:
+                span.set_metadata(**info)
 
 
-def _ring_event(name: str, args: dict):
+def annotate(**args) -> None:
+    """Add arguments to the innermost event open on this thread, from a
+    frame that does not hold it (the route a kernel took, decided far
+    below the operator's span). No-op when no event is open."""
+    stack = getattr(_open, "stack", None)
+    if stack:
+        stack[-1].update(args)
+
+
+def _ring_event(name: str, args: dict, info: dict):
     t0 = time.perf_counter()
     qid = current_query_id()
-    info: dict = {}
     try:
         yield info
     finally:

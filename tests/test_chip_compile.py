@@ -1,6 +1,7 @@
 """Ask the v5e's compiler, without a chip: every Pallas kernel in
-ops/pallas_kernels.py at the shapes chip_smoke.py gives it, and the
-multi-key sort, compiled for a described `v5e:2x2` device.
+ops/pallas_kernels.py at the shapes chip_smoke.py gives it, the
+multi-key sort, and the dense aggregate tail at TPC-H Q1's shape,
+compiled for a described `v5e:2x2` device.
 
 Nothing runs, so this says nothing about results or speed; it catches
 what interpret mode cannot (Mosaic refusing an op or a layout, a program
@@ -156,3 +157,46 @@ def test_sort_local_float64_key_compiles_for_v5e(shape):
                  static_argnames=("num_keys", "ascending", "na_last"))
     fn.lower(arrays, shape((), jnp.int64), num_keys=2,
              ascending=(False, True)).compile()
+
+
+def test_dense_reduce_tail_compiles_for_v5e(shape):
+    """The benchmark's Q1-shaped dense tail (3,000,064 rows, six slots,
+    Q1's eight specs over float64) takes the reduce route: the compiler
+    accepts it, no scatter in its optimized HLO works on the rows (the
+    only ones left compact the six slots), and its temporaries stay
+    hundreds of MiB at any slot count: nothing of [rows, n_slots] is
+    materialised."""
+    from bodo_tpu import relational as R
+    n = 3_000_064
+    specs = ("sumnull",) * 4 + ("mean",) * 3 + ("size",)
+    vn = [f"v{i}" for i in range(8)]
+    tree = {"k": (shape((n,), jnp.int32), None),
+            "v7": (shape((n,), jnp.int64), None)}
+    for c in vn[:7]:
+        tree[c] = (shape((n,), jnp.float64), None)
+
+    def compiled(n_slots):
+        assert R.dense_route(n_slots, specs, False) == "reduce"
+
+        def body(tree, live):
+            return R.dense_agg_tail(tree, live, ["k"], vn, specs,
+                                    (n_slots,), (0,), n_slots, False)
+        return jax.jit(body).lower(tree, shape((n,), jnp.bool_)).compile()
+
+    six = compiled(6)
+    text = six.as_text()
+    assert " reduce(" in text
+    for computation in text.split("\n\n"):
+        if " scatter(" in computation:
+            assert str(n) not in computation, computation[:400]
+    # both routes hold the float64 columns' float32 halves and a
+    # slot-or-none column a null mask, 150 MiB at any slot count: more
+    # than rows x 6 x 8, so the bound that shows nothing of
+    # [rows, n_slots] was materialised is read at the widest slot space
+    # the route takes, where that product is 23 GiB
+    temp = six.memory_analysis().temp_size_in_bytes
+    assert temp < (256 << 20), f"{temp >> 20} MiB of temporaries"
+    widest = R.DENSE_REDUCE_MAX_SLOTS
+    temp = compiled(widest).memory_analysis().temp_size_in_bytes
+    assert temp < (512 << 20) < n * widest * 8 // 32, \
+        f"{temp >> 20} MiB of temporaries at {widest} slots"

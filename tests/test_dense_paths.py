@@ -199,3 +199,174 @@ def test_pallas_dense_accumulate_unit():
     np.add.at(exp, np.asarray(codes)[np.asarray(ok)],
               np.asarray(v)[np.asarray(ok)])
     np.testing.assert_allclose(np.asarray(out), exp, rtol=1e-4, atol=1e-4)
+
+
+# ---------------------------------------------------------------------------
+# dense_agg_tail's reduce route against its scatter route, same inputs
+# ---------------------------------------------------------------------------
+
+REDUCE_OPS = ("sum", "sumnull", "sum64", "mean", "count", "size", "min",
+              "max")
+SIZES, LOS = (3, 2), (5, 0)       # six slots, as TPC-H Q1 has
+_tails = {}                       # (route, op, dtype, nullable) -> jitted tail
+
+
+def _tail(route, op, dtype, nullable, monkeypatch):
+    """`dense_agg_tail` jitted with the route steered the way only a
+    test may: by the module constant, while the program traces."""
+    import jax
+    key = (route, op, dtype, nullable)
+    monkeypatch.setattr(R, "DENSE_REDUCE_MAX_SLOTS",
+                        6 if route == "reduce" else 0)
+    assert R.dense_route(6, (op,), False) == route
+    if key not in _tails:
+        def body(tree, live):
+            return R.dense_agg_tail(tree, live, ["a", "b"], ["v"], (op,),
+                                    SIZES, LOS, 6, False)
+        _tails[key] = jax.jit(body)
+    return _tails[key]
+
+
+def _tail_inputs(dtype, case):
+    r = np.random.default_rng(11)
+    n = 4096
+    a = r.integers(0, 3, n).astype(np.int64)
+    b = r.integers(0, 2, n).astype(np.int32)
+    if np.dtype(dtype).kind == "f":
+        v = r.normal(scale=1e3, size=n).astype(dtype)
+    else:
+        v = r.integers(-10_000, 10_000, n).astype(dtype)
+    valid, live = None, np.ones(n, bool)
+    if case != "no_nulls":
+        valid = r.random(n) > 0.2
+        if np.dtype(dtype).kind == "f":
+            v[r.random(n) < 0.05] = np.nan
+    if case == "live_mask":
+        live = r.random(n) < 0.6
+    elif case == "all_null_group":
+        valid &= ~((a == 2) & (b == 0))
+    elif case == "empty_slot":
+        b[a == 1] = 1             # slot (1, 0) has no row
+    return {"a": (a + LOS[0], None), "b": (b, None), "v": (v, valid)}, live
+
+
+@pytest.mark.parametrize("case", ["no_nulls", "nulls", "live_mask",
+                                  "all_null_group", "empty_slot"])
+@pytest.mark.parametrize("dtype", ["float64", "int64", "int32"])
+@pytest.mark.parametrize("op", REDUCE_OPS)
+def test_reduce_route_matches_scatter_route(op, dtype, case, monkeypatch):
+    tree, live = _tail_inputs(dtype, case)
+    nullable = tree["v"][1] is not None
+    got = {}
+    for route in ("reduce", "scatter"):
+        keys, ((data, valid),), ng = _tail(route, op, dtype, nullable,
+                                           monkeypatch)(tree, live)
+        ng = int(ng)
+        got[route] = ([np.asarray(k)[:ng] for k in keys],
+                      np.asarray(data)[:ng],
+                      None if valid is None else np.asarray(valid)[:ng])
+    (rk, rd, rv), (sk, sd, sv) = got["reduce"], got["scatter"]
+    assert len(rd) == (5 if case == "empty_slot" else 6)
+    for x, y in zip(rk, sk):
+        assert x.tolist() == y.tolist()
+    assert rd.dtype == sd.dtype
+    assert (rv is None) == (sv is None)
+    if rv is not None:
+        assert rv.tolist() == sv.tolist()
+        if case == "all_null_group":
+            assert not rv.all()
+    if rd.dtype.kind == "f" and op not in ("min", "max"):
+        # a tree against a chain: same dtype, another order of additions
+        np.testing.assert_allclose(rd, sd, rtol=1e-12, atol=0,
+                                   equal_nan=True)
+        if op == "mean" and case == "all_null_group":
+            assert np.isnan(rd).sum() == 1
+    else:
+        assert rd.tolist() == sd.tolist()
+
+
+def _route_of(t, keys, aggs):
+    """(answer, dense_route as the groupby_agg span carried it)."""
+    import json
+
+    from bodo_tpu.utils import tracing
+    old = config.tracing_level
+    set_config(tracing_level=1)
+    tracing.reset()
+    try:
+        out = R.groupby_agg(t, keys, aggs).to_pandas()
+        routes = [e["args"].get("dense_route")
+                  for e in json.loads(tracing.dump())["traceEvents"]
+                  if e["name"] == "groupby_agg"]
+    finally:
+        set_config(tracing_level=old)
+        tracing.reset()
+    assert len(routes) == 1
+    return out, routes[0]
+
+
+@pytest.mark.parametrize("n_slots,route", [
+    (R.DENSE_REDUCE_MAX_SLOTS, "reduce"),
+    (R.DENSE_REDUCE_MAX_SLOTS + 1, "scatter")])
+def test_route_flips_above_the_constant(one_dev, n_slots, route):
+    r = np.random.default_rng(4)
+    n = 20 * n_slots
+    df = pd.DataFrame({"k": np.arange(n) % n_slots,
+                       "v": r.normal(size=n),
+                       "w": r.integers(-9, 9, n)})
+    got, span_route = _route_of(
+        Table.from_pandas(df), ["k"],
+        [("v", "sum", "s"), ("w", "max", "hi"), ("v", "size", "n")])
+    assert span_route == route
+    exp = df.groupby("k", as_index=False).agg(
+        s=("v", "sum"), hi=("w", "max"), n=("v", "size"))
+    assert got["k"].tolist() == exp["k"].tolist()
+    np.testing.assert_allclose(got["s"], exp["s"], rtol=1e-12)
+    assert got["hi"].tolist() == exp["hi"].tolist()
+    assert got["n"].tolist() == exp["n"].tolist()
+
+
+def test_spec_outside_the_set_keeps_the_scatter_route(one_dev):
+    df = _df(seed=7)
+    got, span_route = _route_of(Table.from_pandas(df), ["flag"],
+                                [("v", "sum", "s"), ("v", "var", "vv")])
+    assert span_route == "scatter"
+    assert R.dense_route(2, ("sum", "var"), False) == "scatter"
+    assert R.dense_route(2, ("sum", "mean"), False) == "reduce"
+    assert R.dense_route(2, ("sum", "mean"), True) == "mxu"
+    exp = df.groupby("flag", as_index=False).agg(s=("v", "sum"),
+                                                 vv=("v", "var"))
+    np.testing.assert_allclose(got["s"], exp["s"], rtol=1e-12)
+    np.testing.assert_allclose(got["vv"], exp["vv"], rtol=1e-12)
+
+
+def test_fused_aggregate_carries_its_route(one_dev):
+    """SQL group-by over a filter: the fused stage takes the dense tail,
+    its span says which route, and `fusion.stats()` counts it."""
+    import json
+
+    import bodo_tpu
+    from bodo_tpu.plan import fusion
+    from bodo_tpu.utils import tracing
+    df = _df(seed=8)
+    ctx = bodo_tpu.sql.BodoSQLContext({"t": df})
+    old = (config.tracing_level, config.result_cache)
+    set_config(tracing_level=1, result_cache=False)
+    tracing.reset()
+    before = fusion.stats()["dense_reduce"]
+    try:
+        got = ctx.sql("select b, sum(v) as s, count(*) as n from t "
+                      "where w > -40 group by b order by b").to_pandas()
+        events = json.loads(tracing.dump())["traceEvents"]
+    finally:
+        set_config(tracing_level=old[0], result_cache=old[1])
+        tracing.reset()
+    routes = [e["args"].get("dense_route") for e in events
+              if e["name"] == "fused_group"]
+    assert routes == ["reduce"]
+    assert fusion.stats()["dense_reduce"] == before + 1
+    exp = df[df.w > -40].groupby("b", as_index=False).agg(
+        s=("v", "sum"), n=("v", "size")).sort_values("b")
+    assert got["b"].tolist() == exp["b"].tolist()
+    np.testing.assert_allclose(got["s"], exp["s"], rtol=1e-12)
+    assert got["n"].tolist() == exp["n"].tolist()

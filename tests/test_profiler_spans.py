@@ -223,3 +223,26 @@ def test_named_jit_names_the_program():
     fn = named_jit("groupby_dense", body)  # shardcheck: ignore[unregistered-jit]
     assert "@jit_groupby_dense" in fn.lower(np.ones(4)).as_text()
     assert fn(np.ones(4)).tolist() == [2.0] * 4
+
+
+def test_dense_route_rides_on_the_spans(tmp_path, mesh8):
+    """What `tracing.annotate` adds below a span arrives as TraceMe
+    metadata of that span, with tracing off: the fused aggregate's and
+    the dense groupby's route."""
+    from bodo_tpu import Table
+    from bodo_tpu import relational as R
+    assert config.tracing_level == 0
+    ctx = bodo_tpu.sql.BodoSQLContext(gen_tpch(n_orders=2000, seed=1))
+    df = pd.DataFrame({"k": np.arange(4000) % 7, "v": np.arange(4000.0)})
+
+    def queries():
+        ctx.sql(QUERIES[1]).to_pandas()
+        R.groupby_agg(Table.from_pandas(df), ["k"],
+                      [("v", "sum", "s"), ("v", "var", "vv")])
+
+    with mesh_mod.use_mesh(bodo_tpu.make_mesh(jax.devices()[:1])):
+        spans, _ = profiled(tmp_path, queries)
+    by_name = {s[1]: s[4] for s in spans}
+    assert by_name["bodo:fused_group"]["dense_route"] == "reduce"
+    assert by_name["bodo:groupby_agg"]["dense_route"] == "scatter"
+    assert by_name["bodo:groupby_agg"]["rows"] == 7

@@ -42,6 +42,21 @@ def _ns(days):
     return pd.Series(days.astype("datetime64[ns]"))
 
 
+def _labelled(codes, labels):
+    """A str column of labels[codes]. Where pandas keeps strings in arrow,
+    the column is built from arrow's dictionary (2.6 s against 5.8 s for
+    60M rows, my CPU run, PR 30); the values are the same either way."""
+    dtype = pd.Series(labels[:1]).dtype
+    if getattr(dtype, "storage", None) != "pyarrow":
+        return pd.Series(np.asarray(labels)[codes])
+    import pyarrow as pa
+    column = pa.DictionaryArray.from_arrays(
+        pa.array(codes.astype(np.int8)),
+        pa.array(labels, pa.large_string())).cast(pa.large_string())
+    return pd.Series(pd.arrays.ArrowStringArray(pa.chunked_array([column]),
+                                                dtype=dtype))
+
+
 def generate(params, seed, data_dir=None):
     """Return {"frames": {table: DataFrame}} for the six tables Q1 and Q5
     read. Nothing is written to disk."""
@@ -74,11 +89,17 @@ def generate(params, seed, data_dir=None):
     orders = pd.DataFrame({
         "o_orderkey": np.arange(n_orders, dtype=np.int64),
         "o_custkey": o_cust,
-        "o_orderdate": _ns(epoch + o_day.astype("timedelta64[D]"))})
+        "o_orderdate": _ns(epoch + o_day.astype("timedelta64[D]"))},
+        copy=False)
 
     r = np.random.default_rng(int(seed))
     l_order = np.repeat(np.arange(n_orders, dtype=np.int64), n_lines)
-    ship_day = o_day[l_order] + l_delay
+    ship_day = np.repeat(o_day, n_lines)
+    ship_day += l_delay
+    # columns are handed to the frame as they are (copy=False): at 60M
+    # rows the copy into one block a dtype cost 11 s of a 48 s generate,
+    # and `choice(labels, n)` draws `integers(0, len(labels), n)` and
+    # indexes, so the flags below are the values the seed always drew
     lineitem = pd.DataFrame({
         "l_orderkey": l_order,
         "l_suppkey": l_supp,
@@ -86,9 +107,10 @@ def generate(params, seed, data_dir=None):
         "l_extendedprice": np.round(r.uniform(900, 100000, n_li), 2),
         "l_discount": np.round(r.uniform(0, 0.10, n_li), 2),
         "l_tax": np.round(r.uniform(0, 0.08, n_li), 2),
-        "l_returnflag": r.choice(["R", "A", "N"], n_li),
-        "l_linestatus": r.choice(["O", "F"], n_li),
-        "l_shipdate": _ns(epoch + ship_day.astype("timedelta64[D]"))})
+        "l_returnflag": _labelled(r.integers(0, 3, n_li), ["R", "A", "N"]),
+        "l_linestatus": _labelled(r.integers(0, 2, n_li), ["O", "F"]),
+        "l_shipdate": _ns(epoch + ship_day.astype("timedelta64[D]"))},
+        copy=False)
 
     frames = {"region": region, "nation": nation, "supplier": supplier,
               "customer": customer, "orders": orders, "lineitem": lineitem}

@@ -26,6 +26,7 @@ import argparse
 import contextlib
 import json
 import os
+import resource
 import shutil
 import sys
 import time
@@ -51,6 +52,12 @@ TRACE_SECONDS = 5.0
 
 def emit(**kw):
     print(json.dumps(kw, default=str), flush=True)
+
+
+def host_peak_bytes():
+    """The process's peak resident set so far (Linux counts it in KiB): a
+    configuration's size rule asks what the host had to hold."""
+    return resource.getrusage(resource.RUSAGE_SELF).ru_maxrss * 1024
 
 
 def scaled(params, fraction):
@@ -117,7 +124,7 @@ def main():
     setup_s = time.perf_counter() - _T0
     emit(phase="setup", seed=args.seed, rows=inputs["rows"],
          generate_s=gen_s, load_s=load_s, warm_query_s=warm,
-         setup_s=setup_s,
+         setup_s=setup_s, host_peak_bytes=host_peak_bytes(),
          compile_cache_dir=jax.config.jax_compilation_cache_dir,
          **at_setup)
 
@@ -178,7 +185,8 @@ def main():
     peak = engine.peak_bytes()
     in_window = {k: at_end[k] - at_setup[k] for k in at_end}
     emit(phase="window", queries_completed=len(done), window_s=window_s,
-         query_seconds=latencies, peak_bytes_in_use=peak, **in_window,
+         query_seconds=latencies, peak_bytes_in_use=peak,
+         host_peak_bytes=host_peak_bytes(), **in_window,
          **engine.report())
 
     # ------------------------------------------------------------ metrics
@@ -196,9 +204,7 @@ def main():
     if not args.trace:
         values = {
             "setup_s": setup_s,
-            "query_s": window_s / len(done) if done else None,
-            "query_p95_s": float(np.percentile(latencies, 95))
-            if latencies else None}
+            "query_s": window_s / len(done) if done else None}
         for m in cell.end_to_end():
             if values.get(m["name"]) is None:
                 sys.exit(f"end-to-end metric {m['name']} has no value")
@@ -243,7 +249,8 @@ def main():
     correct, compared = compare.judge(
         gaps, sum(g is None for _, g in answers), limits)
     emit(phase="compare", answers_compared=len(gaps),
-         reference_and_compare_s=time.perf_counter() - t)
+         reference_and_compare_s=time.perf_counter() - t,
+         host_peak_bytes=host_peak_bytes())
 
     result = {"correct": bool(correct), "attempted": len(answers),
               "failed": len(answers) - len(done), "metrics": metrics,

@@ -18,10 +18,16 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 
 CASES = [
     # fault, cell, fraction of scale, correct
-    ("none", "tpch_q1", "0.01", True),
-    ("answer", "tpch_q1", "0.01", False),
-    ("count", "tpch_q1", "0.01", False),
-    ("half", "tpch_q1", "0.01", False),
+    # tpch_q1 stands at 15,000,000 orders: 7,500 here, as before PR 30
+    ("none", "tpch_q1", "0.0005", True),
+    ("answer", "tpch_q1", "0.0005", False),
+    ("count", "tpch_q1", "0.0005", False),
+    ("half", "tpch_q1", "0.0005", False),
+    # the same query on tpch_750k: 7,500 orders again
+    ("none", "tpch_q1_750k", "0.01", True),
+    ("answer", "tpch_q1_750k", "0.01", False),
+    ("count", "tpch_q1_750k", "0.01", False),
+    ("half", "tpch_q1_750k", "0.01", False),
     ("none", "tpch_q5", "0.01", True),
     ("answer", "tpch_q5", "0.01", False),
     ("half", "tpch_q5", "0.01", False),

@@ -340,8 +340,9 @@ def try_skew_split_join(left, right, left_on, right_on, how, suffixes,
                             null_equal=null_equal)
     if left_cold.nrows == 0:
         return hot_out
-    cold_out = R._join_sharded(left_cold, right, left_on, right_on, how,
-                               suffixes, null_equal=null_equal)
+    with R.join_route("sort", len(left_on), left_cold.nrows, right.nrows):
+        cold_out = R._join_sharded(left_cold, right, left_on, right_on, how,
+                                   suffixes, null_equal=null_equal)
     return _append_splits(hot_out, cold_out)
 
 

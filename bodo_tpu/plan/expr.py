@@ -24,6 +24,7 @@ import numpy as np
 
 from bodo_tpu.ops import datetime as dtops
 from bodo_tpu.table import dtypes as dt
+from bodo_tpu.utils import tracing
 
 
 class Expr:
@@ -1316,23 +1317,26 @@ def eval_expr(e: Expr, tree: Dict[str, Tuple], dicts: Dict[str, np.ndarray],
                 dic = [tr.apply_host(s) for s in dic]
         lut = np.zeros(max(len(dic), 1), dtype=bool)
         pats = [p for p in e.pattern]
-        for i, s in enumerate(dic):
-            if e.kind == "contains":
-                lut[i] = pats[0] in s
-            elif e.kind == "startswith":
-                lut[i] = s.startswith(tuple(pats))
-            elif e.kind == "endswith":
-                lut[i] = s.endswith(tuple(pats))
-            elif e.kind == "match":
-                lut[i] = re.match(pats[0], s) is not None
-            elif e.kind == "fullmatch":
-                lut[i] = re.fullmatch(pats[0], s) is not None
-            elif e.kind == "eq_any":
-                lut[i] = s in pats
-            elif e.kind == "lower_eq":
-                lut[i] = s.lower() == pats[0]
-            else:
-                raise ValueError(f"unknown str predicate {e.kind}")
+        # a host loop as long as the dictionary: it runs while a program
+        # is traced, and the compiled program keeps the LUT as a constant
+        with tracing.event("strpred.lut", kind=e.kind, dict_size=len(dic)):
+            for i, s in enumerate(dic):
+                if e.kind == "contains":
+                    lut[i] = pats[0] in s
+                elif e.kind == "startswith":
+                    lut[i] = s.startswith(tuple(pats))
+                elif e.kind == "endswith":
+                    lut[i] = s.endswith(tuple(pats))
+                elif e.kind == "match":
+                    lut[i] = re.match(pats[0], s) is not None
+                elif e.kind == "fullmatch":
+                    lut[i] = re.fullmatch(pats[0], s) is not None
+                elif e.kind == "eq_any":
+                    lut[i] = s in pats
+                elif e.kind == "lower_eq":
+                    lut[i] = s.lower() == pats[0]
+                else:
+                    raise ValueError(f"unknown str predicate {e.kind}")
         res = jnp.asarray(lut)[jnp.clip(d, 0, len(dic) - 1)]
         return res, v
     if isinstance(e, Where):

@@ -163,6 +163,8 @@ _stats = {"groups_planned": 0, "groups_executed": 0, "stream_chains": 0,
           "budget_spent": 0,
           # fused terminal aggregates by relational.dense_route
           "dense_reduce": 0, "dense_scatter": 0, "dense_mxu": 0,
+          # joins by the realisation they took (`join_route`)
+          "join_dense": 0, "join_hash": 0, "join_sort": 0, "join_fused": 0,
           # scan batches entering fused chains straight off the device
           # decode path (io/device_decode.py) — no host round-trip
           # between ingest and the compiled chain body
@@ -204,6 +206,21 @@ def stats() -> dict:
     out = dict(_stats)
     out.update(_programs.stats())
     return out
+
+
+def join_route(route: str, keys: int, rows_left: int, rows_right: int):
+    """The span a join opens around the realisation it took, once the
+    route is settled (a try that gives up at its build opens none, so a
+    join has one; a hash or fused probe that comes back unresolved, the
+    pathological case, leaves its span behind the sort's): `join.dense`
+    (dense LUT), `join.hash` (hash LUT), `join.sort` (`join_local` and
+    its sorts, replicated, sharded or broadcast) or `join.fused` (the
+    probe inside a fused join group's program). A trace's readers see a
+    span's name only, so the route is in the name; `stats()` counts the
+    same under `join_<route>`."""
+    _stats["join_" + route] += 1
+    return tracing.event("join." + route, keys=keys, rows_left=rows_left,
+                         rows_right=rows_right)
 
 
 def reset_stats() -> None:

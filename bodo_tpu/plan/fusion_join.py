@@ -794,25 +794,24 @@ def _run_join_group(t: Table, b: Table, group: JoinGroup) -> Table:
         bargs = (b.select(build_emit).device_data(), bcodes, owner)
         bspecs = (P(), P(), P())
 
-    if agg_plan is not None:
-        out = _dispatch_agg(t, b, group, body, bargs, bspecs, agg_plan,
-                            out_schema, out_dicts, post_meta,
-                            post_names, post_schema, post_dicts, fp,
-                            fp_sig, multi, build_inprogram)
-    else:
-        chained = _dispatch_chain(t, b, group, body, bargs, bspecs,
+    with F.join_route("fused", nk, t.nrows, b.nrows):
+        if agg_plan is not None:
+            out = _dispatch_agg(t, b, group, body, bargs, bspecs, agg_plan,
+                                out_schema, out_dicts, post_meta,
+                                post_names, post_schema, post_dicts, fp,
+                                fp_sig, multi, build_inprogram)
+        else:
+            out = _dispatch_chain(t, b, group, body, bargs, bspecs,
                                   out_names, out_schema, out_dicts, fp,
                                   fp_sig, multi, build_inprogram)
-        if agg is not None:
-            # partial fusion: the chain+probe fused, the aggregate (REP
-            # input, non-decomposable op, or gate miss) finishes per-op
-            _stats["partial"] += 1
-            out = R.groupby_agg(chained, agg.keys, agg.aggs)
-            for attr in ("_fusion_compiled", "_fusion_compile_s",
-                         "_fusion_donated"):
-                setattr(out, attr, getattr(chained, attr, False))
-        else:
-            out = chained
+    if agg_plan is None and agg is not None:
+        # partial fusion: the chain+probe fused, the aggregate (REP
+        # input, non-decomposable op, or gate miss) finishes per-op
+        _stats["partial"] += 1
+        chained, out = out, R.groupby_agg(out, agg.keys, agg.aggs)
+        for attr in ("_fusion_compiled", "_fusion_compile_s",
+                     "_fusion_donated"):
+            setattr(out, attr, getattr(chained, attr, False))
     if build_inprogram:
         _stats["build_gather_inprogram"] += 1
     return out

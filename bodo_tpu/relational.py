@@ -34,7 +34,7 @@ from bodo_tpu.parallel import mesh as mesh_mod
 from bodo_tpu.parallel.shuffle import (_mesh_key, _MESHES, groupby_sharded,
                                        shuffle_rows)
 from bodo_tpu.plan.expr import Expr, eval_expr, infer_dtype
-from bodo_tpu.plan.fusion import fusion_stage
+from bodo_tpu.plan.fusion import fusion_stage, join_route
 from bodo_tpu.table import dtypes as dt
 from bodo_tpu.table.dict_utils import unify_dictionaries
 from bodo_tpu.table.table import Column, ONED, REP, Table, round_capacity
@@ -1428,13 +1428,15 @@ def join_tables(left: Table, right: Table, left_on: Sequence[str],
                                            how, suffixes, null_equal)
         if out is not None:
             return out
-        return _join_sharded(left, right, left_on, right_on, how, suffixes,
-                             null_equal=null_equal)
-    if left.distribution == ONED and right.distribution == REP:
-        return _join_broadcast(left, right, left_on, right_on, how,
-                               suffixes, null_equal)
-    return _join_rep(left, right, left_on, right_on, how, suffixes,
-                     null_equal)
+    with join_route("sort", len(left_on), left.nrows, right.nrows):
+        if left.distribution == ONED and right.distribution == ONED:
+            return _join_sharded(left, right, left_on, right_on, how,
+                                 suffixes, null_equal=null_equal)
+        if left.distribution == ONED and right.distribution == REP:
+            return _join_broadcast(left, right, left_on, right_on, how,
+                                   suffixes, null_equal)
+        return _join_rep(left, right, left_on, right_on, how, suffixes,
+                         null_equal)
 
 
 def _join_dense_try(left, right, left_on, right_on, how, suffixes,
@@ -1548,11 +1550,12 @@ def _join_dense_try(left, right, left_on, right_on, how, suffixes,
         pfn = named_jit("join_probe_dense", pbody)
         _jit_cache[pkey] = pfn
 
-    out_p, out_b, cnt = pfn(pa, ba, lut, jnp.asarray(left.nrows))
-    nrows = int(jax.device_get(cnt))
-    res = _assemble_join(left, right, left_on, right_on, lorder, rorder,
-                         out_p, out_b, nrows, None, how, suffixes)
-    return rebucket(res)
+    with join_route("dense", nk, left.nrows, right.nrows):
+        out_p, out_b, cnt = pfn(pa, ba, lut, jnp.asarray(left.nrows))
+        nrows = int(jax.device_get(cnt))
+        res = _assemble_join(left, right, left_on, right_on, lorder, rorder,
+                             out_p, out_b, nrows, None, how, suffixes)
+        return rebucket(res)
 
 
 def _join_hash_try(left, right, left_on, right_on, how, suffixes,
@@ -1646,14 +1649,15 @@ def _join_hash_try(left, right, left_on, right_on, how, suffixes,
         pfn = named_jit("join_probe_hash", pbody)
         _jit_cache[pkey] = pfn
 
-    out_p, out_b, cnt, p_unres = pfn(pa, ba, bcodes, owner,
-                                     jnp.asarray(left.nrows))
-    nrows_, unres_ = jax.device_get((cnt, p_unres))
-    if bool(unres_):
-        return None
-    res = _assemble_join(left, right, left_on, right_on, lorder, rorder,
-                         out_p, out_b, int(nrows_), None, how, suffixes)
-    return rebucket(res)
+    with join_route("hash", nk, left.nrows, right.nrows):
+        out_p, out_b, cnt, p_unres = pfn(pa, ba, bcodes, owner,
+                                         jnp.asarray(left.nrows))
+        nrows_, unres_ = jax.device_get((cnt, p_unres))
+        if bool(unres_):
+            return None  # pathological probe chains: the sort join's
+        res = _assemble_join(left, right, left_on, right_on, lorder, rorder,
+                             out_p, out_b, int(nrows_), None, how, suffixes)
+        return rebucket(res)
 
 
 def _probe_build_arrays(left, right, left_on, right_on):

@@ -79,6 +79,7 @@ def reset() -> None:
         _counters.clear()
         _qerr.clear()
         _observed.clear()
+        _stats_mod._priced.update(keyed=0, unkeyed=0)
 
 
 def set_estimate_injector(fn) -> None:
@@ -481,7 +482,10 @@ def _mark_reoptimized(n) -> None:
 
 def stats() -> dict:
     """Decision counters + per-query q-error summary (tracing dump /
-    profile aqe:* rows and the bench JSON `aqe` section)."""
+    profile aqe:* rows and the bench JSON `aqe` section), and how many
+    candidate joins the join order priced with a bound on the keys'
+    distinct values (`join_est_keyed`) and on row counts alone
+    (`join_est_unkeyed`)."""
     with _lock:
         qs = sorted(e["q"] for e in _qerr)
         qe: dict = {"count": len(qs)}
@@ -499,7 +503,9 @@ def stats() -> dict:
         return {"enabled": enabled(),
                 "decisions": {k: int(v)
                               for k, v in sorted(_counters.items())},
-                "q_error": qe}
+                "q_error": qe,
+                "join_est_keyed": _stats_mod._priced["keyed"],
+                "join_est_unkeyed": _stats_mod._priced["unkeyed"]}
 
 
 # install the estimate override once, at import (physical.py imports

@@ -37,9 +37,10 @@ def optimize(node: L.Node) -> L.Node:
 def reorder_joins(node: L.Node) -> L.Node:
     """Greedy stats-driven reordering of left-deep INNER equi-join
     chains — the frame-path analogue of the SQL planner's join-graph
-    ordering (reference: the vendored DuckDB join-order optimizer the
-    frame path gets via bodo/pandas/plan.py get_plan_cardinality).
-    pandas `merge` chains run in user order otherwise.
+    ordering, and the same loop (`stats.greedy_join_order`; reference:
+    the vendored DuckDB join-order optimizer the frame path gets via
+    bodo/pandas/plan.py get_plan_cardinality). pandas `merge` chains
+    run in user order otherwise.
 
     Conservative: only chains of >= 3 relations, all inner, same
     null_equal, where every cross-relation shared column name is a
@@ -100,42 +101,14 @@ def reorder_joins(node: L.Node) -> L.Node:
     # recurse into the chain LEAVES only (they are not part of the chain)
     rels = [reorder_joins(r) for r in rels]
 
-    from bodo_tpu.plan.stats import estimate, join_estimate
-    ests = [estimate(r) for r in rels]
-    start = min(range(len(rels)), key=lambda i: ests[i][0])
-    used = {start}
+    from bodo_tpu.plan.stats import greedy_join_order
+    start, steps = greedy_join_order(rels, edges)
     plan = rels[start]
-    cur_est, cur_raw = ests[start]
-    consumed: set = set()
-    while len(used) < len(rels):
-        best = None
-        for i in range(len(rels)):
-            if i in used:
-                continue
-            kl, kr, ids = [], [], []
-            for eid, (ri, rj, fi, fj) in enumerate(edges):
-                if eid in consumed:
-                    continue
-                if ri in used and rj == i:
-                    kl.append(fi)
-                    kr.append(fj)
-                    ids.append(eid)
-                elif rj in used and ri == i:
-                    kl.append(fj)
-                    kr.append(fi)
-                    ids.append(eid)
-            if kl:
-                out = join_estimate(cur_est, cur_raw, *ests[i])
-                if best is None or out < best[0]:
-                    best = (out, i, kl, kr, ids)
-        if best is None:
+    for i, kl, kr, _ in steps:
+        if not kl:
             return bail()  # disconnected chain: keep user order
-        out, i, kl, kr, ids = best
         plan = L.Join(plan, rels[i], kl, kr, "inner",
                       suffixes=node.suffixes, null_equal=null_eq)
-        cur_est, cur_raw = out, max(cur_raw, ests[i][1])
-        used.add(i)
-        consumed.update(ids)
 
     if set(plan.schema) != set(orig_schema):
         return bail()  # suffix/drop divergence — bail to user order

@@ -750,57 +750,19 @@ class Planner:
                 self._cur_schema = prev_schema
             planned[i] = (L.Filter(p_i, pred), s_i)
 
-        # greedy cost-based join order (replaces the reference's vendored
-        # DuckDB join-order optimizer, bodo/pandas/plan.py
-        # get_plan_cardinality): start from the smallest-estimate relation
-        # with edges, then repeatedly join the connected relation whose
-        # estimated output is smallest
-        from bodo_tpu.plan.stats import estimate, join_estimate
-        ests = [estimate(p) for p, _ in planned]
-        has_edge = {r for e in edges for r in (e[0], e[1])}
-        start = min(range(len(planned)),
-                    key=lambda i: (i not in has_edge, ests[i][0]))
-        used = {start}
+        # greedy cost-based join order: the one loop the frame path and
+        # the run-time re-plan also run (plan/stats.greedy_join_order)
+        from bodo_tpu.plan.stats import greedy_join_order
+        start, steps = greedy_join_order([p for p, _ in planned], edges)
         plan, scope = planned[start]
-        cur_est, cur_raw = ests[start]
         consumed: set = set()
-        while len(used) < len(planned):
-            best = None
-            for i in range(len(planned)):
-                if i in used:
-                    continue
-                keys_l, keys_r, ids = [], [], []
-                for eid, (ri, rj, fi, fj) in enumerate(edges):
-                    if eid in consumed:
-                        continue
-                    if ri in used and rj == i:
-                        keys_l.append(fi)
-                        keys_r.append(fj)
-                        ids.append(eid)
-                    elif rj in used and ri == i:
-                        keys_l.append(fj)
-                        keys_r.append(fi)
-                        ids.append(eid)
-                if keys_l:
-                    out = join_estimate(cur_est, cur_raw, *ests[i])
-                    if best is None or out < best[0]:
-                        best = (out, i, keys_l, keys_r, ids)
-            if best is None:
-                # disconnected — cross join with the smallest remainder
-                i = min((j for j in range(len(planned)) if j not in used),
-                        key=lambda j: ests[j][0])
+        for i, keys_l, keys_r, ids in steps:
+            if keys_l:
+                plan = L.Join(plan, planned[i][0], keys_l, keys_r, "inner",
+                              null_equal=False)
+            else:  # disconnected: cross join with the smallest remainder
                 plan = self._cross_join(plan, planned[i][0])
-                scope = scope.merged(planned[i][1])
-                cur_est *= max(ests[i][0], 1.0)
-                cur_raw = max(cur_raw, ests[i][1])
-                used.add(i)
-                continue
-            out, i, keys_l, keys_r, ids = best
-            plan = L.Join(plan, planned[i][0], keys_l, keys_r, "inner",
-                          null_equal=False)
             scope = scope.merged(planned[i][1])
-            cur_est, cur_raw = out, max(cur_raw, ests[i][1])
-            used.add(i)
             consumed.update(ids)
         # restore FROM-list column order (SELECT * and positional
         # consumers must not see the cost-based join order)

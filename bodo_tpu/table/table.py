@@ -57,6 +57,12 @@ class Column:
     # min/max device reductions (the reference gets the same shortcut
     # from parquet row-group statistics in its planner)
     vrange: Optional[tuple] = None
+    # value span of a RESIDENT column (plan/stats.key_ndv_bound: the join
+    # order's cap on a key's distinct values; 0 = no bound), reduced
+    # once and kept here. Of this column only: no constructor of a
+    # derived column passes it on, and no operator's route reads it
+    ndv_bound: Optional[int] = field(default=None, compare=False,
+                                     repr=False)
 
     @property
     def capacity(self) -> int:

@@ -6,6 +6,7 @@ Replaces the role of the reference's vendored-DuckDB cost model
 
 import numpy as np
 import pandas as pd
+import pytest
 
 from bodo_tpu.plan import logical as L
 from bodo_tpu.plan.stats import estimate, join_estimate, selectivity
@@ -269,3 +270,220 @@ def test_four_table_chain_reorders_as_one_unit(mesh8, tmp_path):
     got = f.to_pandas()
     exp = (fact.merge(d1, on="k1").merge(d2, on="k2").merge(d3, on="k3"))
     assert len(got) == len(exp)
+
+
+# ---------------------------------------------------------------------------
+# join keys' distinct-value bounds (plan/stats.key_ndv_bound) and the one
+# greedy loop (plan/stats.greedy_join_order)
+# ---------------------------------------------------------------------------
+
+def _bench_gen(name):
+    import os
+    import sys
+    bench = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmarks")
+    if bench not in sys.path:
+        sys.path.insert(0, bench)
+    from harness import spec
+    return spec, spec.load_module("gen", name)
+
+
+def _chain(plan):
+    """The left-deep join chain under `plan`: (start relation, [Join...])
+    in execution order."""
+    node = plan
+    while not isinstance(node, L.Join):
+        node = node.children[0]
+    joins = []
+    while isinstance(node, L.Join):
+        joins.append(node)
+        node = node.left
+    return node, joins[::-1]
+
+
+def _table_of(rel):
+    """Which source table a chain relation reads: its columns' prefix
+    (`c_`, `l_`, ...) after the planner's flat `tN__` names."""
+    return next(iter(rel.schema)).split("__")[-1].split("_")[0]
+
+
+def test_q5_chain_never_joins_two_sides_that_both_repeat_their_keys(mesh8):
+    """Suppliers and customers share 25 nation keys: priced by row counts
+    alone the planner met them on `nationkey` (757 x 37,500 -> 1,142,129
+    rows at the cell's scale); with the keys' bounds lineitem is joined
+    on `l_suppkey` first and every join is foreign key to primary key."""
+    from bodo_tpu.plan.physical import execute
+    from bodo_tpu.sql import BodoSQLContext
+    spec, gen = _bench_gen("tpch")
+    frames = gen.generate({"orders": 150000, "structure_seed": 20260926},
+                          1)["frames"]
+    plan = BodoSQLContext(frames).generate_plan(spec.Query("tpch_q5").text)
+    start, joins = _chain(plan)
+    assert len(joins) == 5
+    order = [_table_of(start)] + [_table_of(j.right) for j in joins]
+    assert order.index("l") < order.index("c")
+    assert {k.split("__")[-1] for k in
+            joins[order.index("l") - 1].right_on} <= {"l_suppkey",
+                                                      "l_orderkey"}
+    # every relation as the engine filters it; the joins by pandas
+    acc = execute(start).to_pandas()
+    for j in joins:
+        right = execute(j.right).to_pandas()
+        assert not (acc.duplicated(j.left_on).any()
+                    and right.duplicated(j.right_on).any()), \
+            (j.left_on, j.right_on)
+        acc = acc.merge(right, left_on=j.left_on, right_on=j.right_on)
+        assert len(acc) <= len(frames["lineitem"])
+
+
+def test_q9_keeps_its_order_and_runs_each_join_once(mesh8):
+    """Every join of Q9 is foreign key to primary key, so the bounds
+    change no price: the order is PERF.md section 5's, and the static
+    and the run-time pass agree, so an execution realises five joins
+    (nine when the two passes disagreed about `part` and `partsupp`)."""
+    from bodo_tpu.config import config, set_config
+    from bodo_tpu.plan import fusion
+    from bodo_tpu.sql import BodoSQLContext
+    spec, gen = _bench_gen("tpch_parts")
+    frames = gen.generate({"orders": 30000, "structure_seed": 20260926},
+                          1)["frames"]
+    text = spec.Query("tpch_q9").text
+    ctx = BodoSQLContext(frames)
+    start, joins = _chain(ctx.generate_plan(text))
+    assert [_table_of(start)] + [_table_of(j.right) for j in joins] == \
+        ["n", "s", "l", "p", "ps", "o"]
+    old = config.result_cache
+    set_config(result_cache=False)
+    try:
+        for _ in range(2):  # the first run and a warm one
+            before = fusion.stats()
+            got = ctx.sql(text).to_pandas()
+            after = fusion.stats()
+            assert sum(after[k] - before[k] for k in (
+                "join_dense", "join_hash", "join_sort", "join_fused")) == 5
+    finally:
+        set_config(result_cache=old)
+    assert 0 < len(got) <= 175
+
+
+
+@pytest.mark.parametrize("a,b,key_ndv,want", [
+    # nationkey: 757 suppliers x 37,500 customers over 25 values
+    ((757, 3750), (37500, 37500), 25, 757 * 37500 / 25),
+    # a primary key: the range is the row count, nothing changes
+    ((757, 3750), (1499994, 1499994), 3750, 757 * 1499994 / 3750),
+    # a range wider than the table (sparse keys): the row count stays
+    ((1000, 1000), (50000, 50000), 10**9, 50000),
+    # a pair (partkey, suppkey): parts x suppliers exceeds both tables
+    ((164276, 2999997), (400000, 400000), 100000 * 7500, 164276),
+    # no bound at all
+    ((164276, 2999997), (400000, 400000), None, 164276),
+])
+def test_join_estimate_caps_ndv_by_rows_and_by_key_bound(a, b, key_ndv,
+                                                        want):
+    got = join_estimate(*a, *b, key_ndv)
+    assert got == pytest.approx(want)
+    today = join_estimate(*a, *b)
+    # never divides by more than min(raw rows): never under today's price
+    assert got >= today
+    if key_ndv is None or key_ndv >= min(a[1], b[1]):
+        assert got == today
+
+
+def test_key_without_a_bound_gives_todays_estimate(mesh8):
+    """Float keys and computed keys have no bound: the join is priced by
+    row counts alone, to the digit."""
+    from bodo_tpu.plan.expr import BinOp, ColRef, Lit
+    from bodo_tpu.plan.stats import join_key_ndv, key_ndv_bound
+    r = np.random.default_rng(5)
+    a = L.FromPandas(pd.DataFrame({"k": r.integers(0, 25, 700),
+                                   "f": r.integers(0, 25, 700) * 1.0}))
+    b = L.FromPandas(pd.DataFrame({"k2": r.integers(0, 25, 9000),
+                                   "f2": r.integers(0, 25, 9000) * 1.0}))
+    comp = L.Projection(b, [("k2", BinOp("+", ColRef("k2"), Lit(1)))])
+    assert key_ndv_bound(a, "k") == 25
+    assert key_ndv_bound(L.Filter(a, BinOp("<", ColRef("k"), Lit(3))),
+                         "k") == 25  # of the source, whatever is observed
+    assert key_ndv_bound(L.Projection(b, [("x", ColRef("k2"))]), "x") == 25
+    assert key_ndv_bound(a, "f") is None
+    assert key_ndv_bound(comp, "k2") is None
+    assert join_key_ndv([(a, "k", comp, "k2")]) is None
+    for left_on, right, right_on in ((["f"], b, ["f2"]),
+                                     (["k"], comp, ["k2"])):
+        j = L.Join(a, right, left_on, right_on, "inner")
+        assert estimate(j)[0] == join_estimate(700, 700, 9000, 9000) \
+            == 9000.0
+    # the same join on the bounded keys: 25 values, not 700
+    keyed = L.Join(a, b, ["k"], ["k2"], "inner")
+    assert estimate(keyed)[0] == 700 * 9000 / 25
+    # strings: the dictionary's length
+    s = L.FromPandas(pd.DataFrame({"s": [f"n{i % 7}" for i in range(100)]}))
+    assert key_ndv_bound(s, "s") == 7
+
+
+def test_parquet_key_bound_comes_from_the_footer(mesh8, tmp_path):
+    import pyarrow as pa
+    import pyarrow.parquet as pq
+    from bodo_tpu.plan.stats import key_ndv_bound
+    r = np.random.default_rng(6)
+    df = pd.DataFrame({"k": r.integers(10, 35, 5000),
+                       "v": r.random(5000)})
+    df.loc[0, "k"], df.loc[1, "k"] = 10, 34
+    path = str(tmp_path / "t.pq")
+    pq.write_table(pa.Table.from_pandas(df), path, row_group_size=1000)
+    scan = L.ReadParquet(path)
+    assert key_ndv_bound(scan, "k") == 25
+    assert key_ndv_bound(scan, "v") is None
+
+
+def test_resident_key_range_is_reduced_once(mesh8):
+    """The join order reads a resident key column's range through one
+    min/max reduction in the column's life: the first query on a context
+    reduces each key column once, a second reduces none of them."""
+    import bodo_tpu.relational as R
+    from bodo_tpu.config import config, set_config
+    from bodo_tpu.plan import adaptive
+    from bodo_tpu.sql import BodoSQLContext
+    r = np.random.default_rng(4)
+    n = 20_000
+    fact = pd.DataFrame({"fk": r.integers(0, 2000, n),
+                         "fs": r.integers(0, 200, n), "amt": r.random(n)})
+    cust = pd.DataFrame({"ck": np.arange(2000),
+                         "cn": r.integers(0, 25, 2000)})
+    supp = pd.DataFrame({"sk": np.arange(200),
+                         "sn": r.integers(0, 25, 200)})
+    ctx = BodoSQLContext({"fact": fact, "cust": cust, "supp": supp})
+    keys = {id(ctx._tables[t].table.columns[c].data): c
+            for t, cs in (("fact", ("fk", "fs")), ("cust", ("ck", "cn")),
+                          ("supp", ("sk", "sn"))) for c in cs}
+    reduced = []
+    orig = R.reduce_table
+
+    def spy(t, aggs):
+        reduced.extend(keys[id(c.data)] for c in t.columns.values()
+                       if id(c.data) in keys)
+        return orig(t, aggs)
+    q = ("select cn, sum(amt) as s from fact, cust, supp "
+         "where fk = ck and fs = sk and cn = sn group by cn")
+    old = config.result_cache
+    set_config(result_cache=False)
+    R.reduce_table = spy
+    try:
+        adaptive.reset()
+        first = ctx.sql(q).to_pandas()
+        assert sorted(reduced) == sorted(keys.values())
+        st = adaptive.stats()
+        assert st["join_est_keyed"] > 0 and st["join_est_unkeyed"] == 0
+        del reduced[:]
+        second = ctx.sql(q).to_pandas()
+        assert reduced == []
+    finally:
+        R.reduce_table = orig
+        set_config(result_cache=old)
+    exp = (fact.merge(cust, left_on="fk", right_on="ck")
+           .merge(supp, left_on=["fs", "cn"], right_on=["sk", "sn"])
+           .groupby("cn", as_index=False).agg(s=("amt", "sum")))
+    for got in (first, second):
+        got = got.sort_values("cn").reset_index(drop=True)
+        assert got["cn"].tolist() == exp["cn"].tolist()
+        np.testing.assert_allclose(got["s"], exp["s"], rtol=1e-9)

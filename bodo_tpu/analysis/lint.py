@@ -81,7 +81,7 @@ Rules:
       must stay O(1)-O(log batches), so every deliberate sync site is
       annotated and counted in `stream_stats`; an unannotated sync is
       either an accidental pipeline stall (O(batches) regression) or
-      an uncounted one the bench can't regress on.
+      an uncounted one no test can regress on.
 
 Suppressions: `# shardcheck: ignore[rule]` (or bare
 `# shardcheck: ignore` for all rules) on the finding's line or the

@@ -19,8 +19,7 @@ peers' streams before proceeding:
     wedged process), in seconds instead of the 180s gang timeout.
 
 Single-process runs (or runs without a shared directory) still count
-and fingerprint dispatches — that is what the bench.py overhead suite
-measures — but have no peers to check.
+and fingerprint dispatches but have no peers to check.
 
 The checker is ~free when disabled: one config attribute read per
 dispatch. spawn.py exports BODO_TPU_LOCKSTEP_DIR pointing at each

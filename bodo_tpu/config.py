@@ -67,29 +67,12 @@ class Config:
     fusion_join: bool = field(
         default_factory=lambda: _env_bool("BODO_TPU_FUSION_JOIN", True)
     )
-    # Device-resident build-side hash tables kept per process (LRU):
-    # each entry pins the build table's encoded key codes + slot-owner
-    # LUT on device so repeat probes (streaming batches, reused build
-    # subplans) skip the build entirely.
-    join_build_cache_size: int = field(
-        default_factory=lambda: _env_int("BODO_TPU_JOIN_BUILD_CACHE", 32)
-    )
     # Pad table capacities up to a multiple of this (TPU lane friendliness).
     capacity_round: int = field(
         default_factory=lambda: _env_int("BODO_TPU_CAPACITY_ROUND", 128)
     )
-    # Re-bucket a table's physical capacity when occupancy falls below this.
-    rebucket_threshold: float = field(
-        default_factory=lambda: _env_float("BODO_TPU_REBUCKET_THRESHOLD", 0.45)
-    )
     # Mesh axis used for row sharding.
     data_axis: str = field(default_factory=lambda: _env_str("BODO_TPU_DATA_AXIS", "d"))
-    # Max compiled kernels pinned per kernel cache (LRU eviction beyond
-    # this — unbounded pinning exhausts XLA:CPU JIT code memory and
-    # segfaults the compiler after thousands of distinct compilations).
-    kernel_cache_size: int = field(
-        default_factory=lambda: _env_int("BODO_TPU_KERNEL_CACHE_SIZE", 512)
-    )
     # Skew headroom factor for all_to_all shuffle bucket capacity.
     shuffle_skew_factor: float = field(
         default_factory=lambda: _env_float("BODO_TPU_SHUFFLE_SKEW", 2.0)
@@ -113,12 +96,6 @@ class Config:
     )
     hash_join: bool = field(
         default_factory=lambda: _env_bool("BODO_TPU_HASH_JOIN", True)
-    )
-    # Dense-LUT join: build sides whose key-range product is at most this
-    # many slots (and whose keys are unique) join by perfect-hash gather.
-    dense_join_max_slots: int = field(
-        default_factory=lambda: _env_int("BODO_TPU_DENSE_JOIN_SLOTS",
-                                         1 << 22)
     )
     # Broadcast-join threshold: build side smaller than this many rows is
     # all_gather'd instead of hash-shuffled (analogue of broadcast join,
@@ -167,19 +144,6 @@ class Config:
         default_factory=lambda: _env_int(
             "BODO_TPU_DEVICE_DECODE_MIN_BYTES", 1 << 20)
     )
-    # -- frontend ------------------------------------------------------------
-    # Fall back to real pandas for unsupported args (reference:
-    # bodo/pandas/utils.py:346 check_args_fallback).
-    pandas_fallback: bool = field(
-        default_factory=lambda: _env_bool("BODO_TPU_PANDAS_FALLBACK", True)
-    )
-    warn_fallback: bool = field(
-        default_factory=lambda: _env_bool("BODO_TPU_WARN_FALLBACK", True)
-    )
-    # Dump optimized plans (analogue BODO_DATAFRAME_LIBRARY_DUMP_PLANS).
-    dump_plans: bool = field(
-        default_factory=lambda: _env_bool("BODO_TPU_DUMP_PLANS", False)
-    )
     # -- observability -------------------------------------------------------
     # 0 = silent, 1 = pushdown/fallback notices, 2 = plan dumps, 3 = kernel trace
     # (analogue of bodo.set_verbose_level, bodo/user_logging.py:1-40).
@@ -203,7 +167,7 @@ class Config:
     # Communication observatory (parallel/comm.py): per-collective
     # bytes/wall/peer-wait accounting at every host-level dispatch site.
     # On by default — the accounting is a dict update per DISPATCH (not
-    # per element); bench.py --suite comm pins the overhead < 2%.
+    # per element).
     comm_accounting: bool = field(
         default_factory=lambda: _env_bool("BODO_TPU_COMM_ACCOUNTING",
                                           True)
@@ -241,16 +205,7 @@ class Config:
     flight_dir: str = field(
         default_factory=lambda: _env_str("BODO_TPU_FLIGHT_DIR", "")
     )
-    # Slowest-N EXPLAIN ANALYZE records embedded per bundle.
-    flight_slow_queries: int = field(
-        default_factory=lambda: _env_int("BODO_TPU_FLIGHT_SLOW_QUERIES",
-                                         5)
-    )
     # -- numerics ------------------------------------------------------------
-    # Use bfloat16 accumulation for mean/var where tolerable (perf knob).
-    low_precision_agg: bool = field(
-        default_factory=lambda: _env_bool("BODO_TPU_LOW_PRECISION_AGG", False)
-    )
     # Pack small-range multi-key groupby/sort keys into one int64 (big
     # sort/shuffle win; disable to force the general lexicographic path).
     pack_keys: bool = field(
@@ -312,12 +267,6 @@ class Config:
         default_factory=lambda: _env_int("BODO_TPU_AQE_SKEW_MIN_ROWS",
                                          100_000)
     )
-    # Streaming batches filled below this fraction of the nominal batch
-    # size merge with their successors before the next per-batch kernel.
-    aqe_coalesce_frac: float = field(
-        default_factory=lambda: _env_float("BODO_TPU_AQE_COALESCE_FRAC",
-                                           0.25)
-    )
     # Persistent runtime-stats store directory (runtime/stats_store.py):
     # observed cardinalities keyed by normalized subplan fingerprints, so
     # repeated queries start from observed rather than guessed stats.
@@ -347,11 +296,6 @@ class Config:
     result_cache_host_spill: bool = field(
         default_factory=lambda: _env_bool(
             "BODO_TPU_RESULT_CACHE_HOST_SPILL", True)
-    )
-    # Byte cap of the host spill tier (0 disables the tier outright).
-    result_cache_host_bytes: int = field(
-        default_factory=lambda: _env_int(
-            "BODO_TPU_RESULT_CACHE_HOST_BYTES", 1 << 28)
     )
     # -- query serving (runtime/scheduler.py, bodo_tpu.serve) ----------------
     # Worker threads draining the per-session queues onto the gang. One
@@ -401,13 +345,6 @@ class Config:
         default_factory=lambda: _env_float("BODO_TPU_SERVE_RETRY_AFTER",
                                            0.25)
     )
-    # Latency-bound SLO class: priority aging runs this many times
-    # faster for slo="latency" sessions, so their queued requests
-    # overtake throughput-bound traffic without starving it.
-    serve_latency_boost: float = field(
-        default_factory=lambda: _env_float(
-            "BODO_TPU_SERVE_LATENCY_BOOST", 4.0)
-    )
     # -- fleet serving (runtime/fleet.py, bodo_tpu.fleet) ---------------------
     # Stable identity of THIS gang process within a fleet. Set by the
     # fleet controller in each gang's environment; empty outside fleet
@@ -425,31 +362,17 @@ class Config:
     fleet_gangs: int = field(
         default_factory=lambda: _env_int("BODO_TPU_FLEET_GANGS", 2)
     )
-    # Controller scrape cadence of each gang's /metrics + /healthz.
-    fleet_scrape_s: float = field(
-        default_factory=lambda: _env_float("BODO_TPU_FLEET_SCRAPE_S", 0.5)
-    )
     # Hard cap on a single wire-protocol frame body; an oversized
     # header is a typed ProtocolError, never an attempted allocation.
     fleet_frame_max: int = field(
         default_factory=lambda: _env_int("BODO_TPU_FLEET_FRAME_MAX",
                                          64 << 20)
     )
-    # Cache peering: on a local result-cache miss the owning gang asks
-    # the fingerprint's previous owner before recomputing.
-    fleet_peering: bool = field(
-        default_factory=lambda: _env_bool("BODO_TPU_FLEET_PEERING", True)
-    )
     # Per-session in-flight quota at the controller; overflow is a
     # typed Overloaded(reason="session_quota"), not an unbounded pile.
     fleet_session_quota: int = field(
         default_factory=lambda: _env_int("BODO_TPU_FLEET_SESSION_QUOTA",
                                          64)
-    )
-    # Consecutive failed scrapes before a gang is declared dead and
-    # evicted from the routing ring.
-    fleet_dead_scrapes: int = field(
-        default_factory=lambda: _env_int("BODO_TPU_FLEET_DEAD_SCRAPES", 3)
     )
     # -- materialized views (runtime/views.py) -------------------------------
     # Base signature-watcher poll interval for continuous queries; a
@@ -464,12 +387,6 @@ class Config:
     view_maintenance_weight: float = field(
         default_factory=lambda: _env_float("BODO_TPU_VIEW_MAINT_WEIGHT",
                                            0.5)
-    )
-    # Per-source-file contribution maps (partition-level invalidation)
-    # are built only for datasets of at most this many files — the map
-    # costs one extra pass over the dataset per materialization.
-    view_max_parts: int = field(
-        default_factory=lambda: _env_int("BODO_TPU_VIEW_MAX_PARTS", 64)
     )
     # -- resilience (runtime/resilience.py) ----------------------------------
     # Armed fault-injection spec (see resilience module docstring for the
@@ -648,7 +565,7 @@ def set_config(**kwargs) -> None:
             from bodo_tpu.runtime import io_pool
             io_pool.reset_pool()
         if k in ("result_cache", "result_cache_bytes",
-                 "result_cache_host_spill", "result_cache_host_bytes"):
+                 "result_cache_host_spill"):
             # re-apply budgets to a live cache (lazy: never imports the
             # module just to reconfigure it); disabling drops entries
             import sys as _sys
@@ -669,13 +586,6 @@ def set_config(**kwargs) -> None:
                 os.environ["BODO_TPU_GANG_ID"] = v
             else:
                 os.environ.pop("BODO_TPU_GANG_ID", None)
-        if k.startswith("fleet_"):
-            # re-apply knobs to a live controller (lazy: never imports
-            # the module just to reconfigure it)
-            import sys as _sys
-            fl = _sys.modules.get("bodo_tpu.runtime.fleet")
-            if fl is not None:
-                fl.reconfigure()
         if k.startswith("elastic"):
             # export like faults/lockstep so spawned gang workers
             # inherit the recovery posture and checkpoint budget
@@ -708,13 +618,9 @@ def set_config(**kwargs) -> None:
                 else:
                     os.environ.pop("BODO_TPU_LOCKSTEP_DIR", None)
         if k in ("progcheck", "progcheck_enforce"):
-            # export like lockstep so spawned workers inherit the
-            # verification posture
-            env_name = "BODO_TPU_" + k.upper()
-            if v:
-                os.environ[env_name] = "1"
-            else:
-                os.environ.pop(env_name, None)
+            # export so spawned workers inherit the verification
+            # posture ("0", not unset: progcheck defaults to on)
+            os.environ["BODO_TPU_" + k.upper()] = "1" if v else "0"
         if k == "trace_events_max":
             # rebuild the ring buffer at the new capacity (keeps the
             # newest events)

@@ -25,9 +25,8 @@ Thunks submitted through the fleet execute in a gang process and their
 return value crosses a process boundary — return HOST values (pandas
 DataFrames, scalars, lists), not device-resident Tables.
 
-Knobs: ``BODO_TPU_FLEET_*`` (see config.py) — gang count, scrape
-cadence, frame-size bound, peering toggle, per-session quota, dead
-threshold, optional client-listener port.
+Knobs: ``BODO_TPU_FLEET_*`` (see config.py) — gang count, frame-size
+bound, per-session quota, optional client-listener port.
 """
 
 from __future__ import annotations
@@ -81,7 +80,7 @@ def session(session_id: Optional[str] = None, *, priority: float = 1.0,
             slo: str = "throughput",
             allow_degraded: bool = False) -> FleetSession:
     """Open (or re-open) a logical fleet session. ``slo`` is
-    ``"latency"`` (aged ``serve_latency_boost``× faster on every gang)
+    ``"latency"`` (aged ``SERVE_LATENCY_BOOST``× faster on every gang)
     or ``"throughput"``; ``priority`` is the fair-share weight."""
     ctl = _impl.controller()
     if ctl is None or not ctl._started:

@@ -26,7 +26,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from bodo_tpu.config import config
 from bodo_tpu.ops import kernels as K
 from bodo_tpu.plan.expr import (BinOp, ColRef, Expr, eval_expr,
                                 expr_columns)
@@ -36,9 +35,10 @@ from bodo_tpu.table.table import Column, REP, Table, round_capacity
 # pair-grid budget: tile_rows * build_cap <= this (elements per pred col)
 _GRID_BUDGET = 1 << 22
 
-from bodo_tpu.utils.kernel_cache import KernelCache, named_jit
+from bodo_tpu.utils.kernel_cache import (KERNEL_CACHE_SIZE, KernelCache,
+                                         named_jit)
 
-_jit_cache = KernelCache(maxsize=config.kernel_cache_size,
+_jit_cache = KernelCache(maxsize=KERNEL_CACHE_SIZE,
                          subsystem="nonequi")
 
 

@@ -49,13 +49,13 @@ FORCE_INTERPRET = False
 # dense_accumulate is only called from inside jit-compiled bodies, so
 # this counts compiled-in engagements — interpret-mode traces included,
 # since FORCE_INTERPRET runs the same kernel through the pallas
-# interpreter; bench.py's hardware proof is the separate timed
-# _pallas_proof run). Exported as pallas_traced_into_pipeline.
+# interpreter). Exported as the metrics registry's
+# bodo_tpu_pallas_traced_into_pipeline gauge.
 trace_count = 0
 
 # per-kernel-family trace engagement (same trace-time semantics as
-# trace_count; bench.py surfaces these as pallas:<family> counters so
-# the probe/partition/decode kernels each prove engagement separately)
+# trace_count, so the probe/partition/decode kernels each prove
+# engagement separately)
 trace_counts = {"groupby": 0, "gather": 0, "probe": 0, "partition": 0,
                 "decode": 0, "range": 0}
 

@@ -10,13 +10,13 @@ everyone waits FOR is the straggler, and it is the rank whose own wait
 is smallest).
 
 Rows are keyed ``(op, site)`` where `site` is the first user-level call
-frame (same convention as the lockstep fingerprint), so `doctor` and
-the bench comm suite can name the dominant collective site, not just
-the op. Each span additionally lands in the trace ring as a ``comm:*``
-event (per-rank lanes in the merged gang trace feed the critical-path
-analyzer) and the byte/latency distributions go to the
-``bodo_tpu_comm_*`` histograms push-side; cumulative gauges are synced
-pull-side by ``metrics.sync_engine_metrics``.
+frame (same convention as the lockstep fingerprint), so `doctor` can
+name the dominant collective site, not just the op. Each span
+additionally lands in the trace ring as a ``comm:*`` event (per-rank
+lanes in the merged gang trace feed the critical-path analyzer) and the
+byte/latency distributions go to the ``bodo_tpu_comm_*`` histograms
+push-side; cumulative gauges are synced pull-side by
+``metrics.sync_engine_metrics``.
 
 Stdlib-only on purpose: importable from a /metrics scrape or the
 telemetry sampler without forcing a jax import.
@@ -118,9 +118,9 @@ def record_in_program(group_fp: str, *, bytes_in: int = 0,
     fused dispatcher calls this instead: the group's lockstep manifest
     (``register_fusion_manifest(..., in_program=...)``) declares which
     collective ops the program subsumes, and one accounting row per
-    declared op is recorded at site ``fused[<fp>]`` — so ``doctor`` and
-    the bench comm suite still see an all_to_all row for a shuffle that
-    now lives inside a compiled stage. Group wall/wait is attributed to
+    declared op is recorded at site ``fused[<fp>]`` — so ``doctor``
+    still sees an all_to_all row for a shuffle that now lives inside a
+    compiled stage. Group wall/wait is attributed to
     the FIRST declared op (the program is one dispatch; splitting the
     wall across members would double-count). Returns the number of
     in-program collectives attributed (0 when the manifest declares
@@ -202,8 +202,8 @@ def stats() -> dict:
 
 
 def per_op() -> Dict[str, dict]:
-    """Accounting rows aggregated by op (site collapsed) — what the
-    bench comm suite and tracing.profile's ``comm:*`` rows report."""
+    """Accounting rows aggregated by op (site collapsed) — what
+    tracing.profile's ``comm:*`` rows report."""
     out: Dict[str, dict] = {}
     with _lock:
         items = [(op, dict(r)) for (op, _site), r in _sites.items()]

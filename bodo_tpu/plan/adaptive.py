@@ -6,8 +6,8 @@ closes the loop at stage boundaries, where actual cardinalities are free
 
   * observation — plan/physical._exec and both streaming executors
     report each completed stage's rows/bytes here. The per-stage q-error
-    (max(est/actual, actual/est)) feeds the tracing profile / bench
-    JSON, observed rows override ``stats.estimate()`` for every subplan
+    (max(est/actual, actual/est)) feeds the tracing profile,
+    observed rows override ``stats.estimate()`` for every subplan
     not yet executed, and fingerprint-stable subplans persist to the
     stats store (runtime/stats_store.py) for future processes.
   * broadcast promote/demote — the broadcast-vs-shuffle join decision in
@@ -23,7 +23,7 @@ closes the loop at stage boundaries, where actual cardinalities are free
     and broadcast-join against their (small) build subset so the shuffle
     carries only the cold remainder.
   * batch coalescing — undersized streaming batches (post-filter) merge
-    until they reach aqe_coalesce_frac of the nominal batch size, so
+    until they reach AQE_COALESCE_FRAC of the nominal batch size, so
     per-batch kernels don't run near-empty.
   * mid-plan re-optimization — inner-join chains re-run
     ``optimizer.reorder_joins`` once their leaf relations have observed
@@ -35,7 +35,7 @@ artifacts, not data properties — observation is suspended while one is
 in flight so they cannot poison the stats store.
 
 Default-on via ``set_config(aqe=...)`` / ``BODO_TPU_AQE``; every
-decision lands in an ``aqe:*`` counter (tracing.profile / dump / bench).
+decision lands in an ``aqe:*`` counter (tracing.profile / dump).
 """
 
 from __future__ import annotations
@@ -368,16 +368,20 @@ def _append_splits(a, b):
 # streaming-batch coalescing
 # ---------------------------------------------------------------------------
 
+# Streaming batches filled below this fraction of the nominal batch size
+# merge with their successors before the next per-batch kernel.
+AQE_COALESCE_FRAC = 0.25
+
+
 def coalesce_batches(src, sharded: bool):
     """Merge consecutive undersized streaming batches (post-filter) so
     downstream per-batch kernels see reasonably full batches instead of
     a long tail of near-empty ones. Order-preserving; an unmergeable
     pair (dict drift, schema drift) flushes and starts over."""
-    if not enabled() or config.aqe_coalesce_frac <= 0:
+    if not enabled():
         yield from src
         return
-    target = max(int(config.streaming_batch_size
-                     * min(config.aqe_coalesce_frac, 1.0)), 1)
+    target = max(int(config.streaming_batch_size * AQE_COALESCE_FRAC), 1)
     pend = None
     for b in src:
         if pend is not None:
@@ -482,7 +486,7 @@ def _mark_reoptimized(n) -> None:
 
 def stats() -> dict:
     """Decision counters + per-query q-error summary (tracing dump /
-    profile aqe:* rows and the bench JSON `aqe` section), and how many
+    profile aqe:* rows), and how many
     candidate joins the join order priced with a bound on the keys'
     distinct values (`join_est_keyed`) and on row counts alone
     (`join_est_unkeyed`)."""

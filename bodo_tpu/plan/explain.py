@@ -13,8 +13,8 @@ node as it completes; AQE-replanned join subtrees get paths re-anchored
 under the node they replaced, flagged `replanned`. Observations are
 keyed by query id (tracing.query_span) and kept for the last
 `_MAX_QUERIES` queries, so `explain_analyze()` after a run renders the
-tree of any recent query — `bench.py --explain` and
-`BodoDataFrame.explain_analyze()` are thin wrappers over it.
+tree of any recent query — `BodoDataFrame.explain_analyze()` is a
+thin wrapper over it.
 
 Recording is active only while tracing is on (BODO_TPU_TRACING_LEVEL
 >= 1); with tracing off the executor's hot path skips this module
@@ -198,24 +198,6 @@ def critical_path(query_id: Optional[str] = None) -> List[str]:
         q = _queries.get(qid) if qid else None
         records = dict(q["records"]) if q else {}
     return sorted(_critical_paths(records), key=_pathkey)
-
-
-def node_profiles(query_id: Optional[str] = None) -> List[dict]:
-    """The recorded observations for one query (default: last), in
-    dotted-path order — the JSON-able form bench artifacts embed. Nodes
-    on the wall-dominant chain carry ``critical: True``."""
-    with _lock:
-        qid = query_id or _last_qid
-        q = _queries.get(qid) if qid else None
-        if q is None:
-            return []
-        recs = [dict(r) for r in q["records"].values()]
-    crit = _critical_paths({r["path"]: r for r in recs})
-    for r in recs:
-        if r["path"] in crit:
-            r["critical"] = True
-    recs.sort(key=lambda r: _pathkey(r["path"]))
-    return recs
 
 
 def last_query_id() -> Optional[str]:

@@ -5,7 +5,7 @@ with a host round-trip between plan nodes: a filter compacts its rows,
 syncs the surviving count to the host, re-buckets, and only then does
 the next projection or aggregate trace over the materialized
 intermediate. The per-stage count syncs and intermediate buffers are
-the flat tax the BENCH hot profiles show across the taxi/TPC-H
+the flat tax the hot profiles show across the taxi/TPC-H
 pipelines — the same observation that drives XLA whole-program fusion
 in JAX and HPAT's whole-function parallel compilation: adjacent
 operators should compile together so intermediates never materialize.
@@ -122,7 +122,8 @@ from bodo_tpu.plan import logical as L
 from bodo_tpu.table import dtypes as dt
 from bodo_tpu.table.table import Column, ONED, REP, Table
 from bodo_tpu.runtime import xla_observatory as xobs
-from bodo_tpu.utils.kernel_cache import FusionProgramCache, named_jit
+from bodo_tpu.utils.kernel_cache import (KERNEL_CACHE_SIZE,
+                                         FusionProgramCache, named_jit)
 from bodo_tpu.utils import tracing
 from bodo_tpu.utils.logging import log
 
@@ -154,7 +155,7 @@ def _describe_sig(key):
     return str(base), xobs.facets_from_sig(key)
 
 
-_programs = FusionProgramCache(maxsize=config.kernel_cache_size,
+_programs = FusionProgramCache(maxsize=KERNEL_CACHE_SIZE,
                                subsystem="fusion",
                                describe=_describe_sig)
 

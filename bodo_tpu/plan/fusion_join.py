@@ -4,7 +4,7 @@ shuffle — compiled INTO the whole-stage fusion program.
 
 plan/fusion.py fuses [Filter|Projection]+ chains (+ an optional dense
 aggregate) but stops at every Join and every shuffle: those dispatch
-per-operator, each with its own host count sync, and the BENCH hot
+per-operator, each with its own host count sync, and the hot
 profiles show they are the remaining two-thirds of the flat tax on the
 taxi/TPC-H pipelines. This module extends group formation across both
 boundaries:
@@ -23,7 +23,7 @@ boundaries:
                     kept on device in a process-wide LRU
                     (`build_hash_table`); repeat probes — streaming
                     batches against one build, a build subplan shared
-                    by several joins, bench probe loops — skip the
+                    by several joins, repeated queries — skip the
                     build entirely. The per-node hash join
                     (relational._join_hash_try) draws from the SAME
                     cache, and every cached LUT is tracked in the
@@ -89,7 +89,7 @@ probe donation.
 
 Disable with `BODO_TPU_FUSION_JOIN=0` / `set_config(fusion_join=False)`
 (plain chain fusion keeps working); the build cache is bounded by
-`BODO_TPU_JOIN_BUILD_CACHE` entries.
+`JOIN_BUILD_CACHE_SIZE` entries.
 """
 
 from __future__ import annotations
@@ -320,6 +320,12 @@ def _suffix_maps(lnames, rnames, left_on, right_on, suffixes):
 # device-resident build-side hash tables
 # ---------------------------------------------------------------------------
 
+# Device-resident build-side hash tables kept per process (LRU): each
+# entry pins the build table's encoded key codes + slot-owner LUT on
+# device so repeat probes (streaming batches, reused build subplans)
+# skip the build entirely.
+JOIN_BUILD_CACHE_SIZE = 32
+
 # build-key buffer identity -> {"codes", "owner", "refs", "hits"} entry,
 # or None (negative verdict: duplicate build keys / unresolved claim).
 # Entries hold strong refs to the source key buffers so id() identity
@@ -328,9 +334,10 @@ _build_cache: "OrderedDict[tuple, Optional[dict]]" = OrderedDict()
 
 # build-program cache keyed ("joinbuild", key dtypes, T, layout):
 # registered with the program observatory like every other kernel cache
-from bodo_tpu.utils.kernel_cache import KernelCache, named_jit  # noqa: E402
-_build_jit_cache = KernelCache(maxsize=config.kernel_cache_size,
-                         subsystem="fusion_join")
+from bodo_tpu.utils.kernel_cache import (  # noqa: E402
+    KERNEL_CACHE_SIZE, KernelCache, named_jit)
+_build_jit_cache = KernelCache(maxsize=KERNEL_CACHE_SIZE,
+                               subsystem="fusion_join")
 
 
 def _build_key(right: Table, right_on, null_cols, null_equal) -> tuple:
@@ -345,8 +352,7 @@ def _build_key(right: Table, right_on, null_cols, null_equal) -> tuple:
 def _cache_put(key, ent) -> None:
     _build_cache[key] = ent
     _build_cache.move_to_end(key)
-    limit = max(int(config.join_build_cache_size), 1)
-    while len(_build_cache) > limit:
+    while len(_build_cache) > JOIN_BUILD_CACHE_SIZE:
         _build_cache.popitem(last=False)
         _cstats["evictions"] += 1
 

@@ -27,7 +27,7 @@ from bodo_tpu.utils.logging import log
 # session-level semantic result cache (runtime/result_cache.py): entries
 # key on (plan fingerprint, environment, dataset signatures) so a
 # changed source file never serves a stale result. The old name stays
-# bound for its dict-shaped call sites (.clear() in tests/benches,
+# bound for its dict-shaped call sites (.clear() in tests,
 # .pop(raw_key) after fusion's buffer donation).
 _result_cache = _rcache.cache()
 
@@ -45,8 +45,6 @@ def _execute(node: L.Node, optimize_first: bool) -> Table:
     if optimize_first:
         with tracing.event("plan.optimize"):
             node = optimize(node)
-        if config.dump_plans:
-            _dump(node)
     if config.plan_validate:
         # shardcheck layer 1: reject ill-typed plans (distribution /
         # schema invariant violations) before any kernel traces or
@@ -468,10 +466,3 @@ def _exec_inner(node: L.Node) -> Table:
         out = R.groupby_agg(child, node.subset, aggs)
         return out.select(child.names)
     raise TypeError(f"cannot execute {node!r}")
-
-
-def _dump(node: L.Node, indent: int = 0) -> None:  # pragma: no cover
-    import sys
-    print("  " * indent + repr(node), file=sys.stderr)
-    for c in node.children:
-        _dump(c, indent + 1)

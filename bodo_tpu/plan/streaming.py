@@ -66,7 +66,7 @@ def _bucket_cap(n: int) -> int:
 # the next batch. The accumulators below are written so syncs per stage
 # are O(1)–O(log batches), not O(batches); each legitimate sync site is
 # annotated `# dispatch-boundary` (shardcheck lints unannotated ones) and
-# counted here so the bench can regress on syncs-per-batch.
+# counted here so a test can regress on syncs-per-batch.
 
 stream_stats: Dict[str, int] = {"host_syncs": 0, "batches": 0}
 

@@ -173,8 +173,8 @@ def _shard_batches(src: Iterator[Table], batch_rows: int,
             sh = rep_batch.shard()
             out = shard_recapacity(sh, bcap_s, m)
             # scan provenance survives the scatter: fusion's
-            # device_scan_batches counter and the bench scan suite read
-            # this flag off sharded batches too
+            # device_scan_batches counter reads this flag off sharded
+            # batches too
             if getattr(rep_batch, "_device_decoded", False):
                 out._device_decoded = True
             yield out

@@ -39,11 +39,12 @@ from bodo_tpu.table import dtypes as dt
 from bodo_tpu.table.dict_utils import unify_dictionaries
 from bodo_tpu.table.table import Column, ONED, REP, Table, round_capacity
 
-from bodo_tpu.utils.kernel_cache import KernelCache, named_jit
+from bodo_tpu.utils.kernel_cache import (KERNEL_CACHE_SIZE, KernelCache,
+                                         named_jit)
 
 # relational cache keys are ("kind", schema/dist/mesh/static parts...):
 # the generic facet split in the observatory attributes retraces per kind
-_jit_cache = KernelCache(maxsize=config.kernel_cache_size,
+_jit_cache = KernelCache(maxsize=KERNEL_CACHE_SIZE,
                          subsystem="relational")
 
 
@@ -1439,6 +1440,11 @@ def join_tables(left: Table, right: Table, left_on: Sequence[str],
                          null_equal)
 
 
+# Dense-LUT join: build sides whose key-range product is at most this
+# many slots (and whose keys are unique) join by perfect-hash gather.
+DENSE_JOIN_MAX_SLOTS = 1 << 22
+
+
 def _join_dense_try(left, right, left_on, right_on, how, suffixes,
                     null_equal: bool = True) -> Optional[Table]:
     """Dense-LUT equi-join: when the build (right) side's keys have a
@@ -1449,8 +1455,7 @@ def _join_dense_try(left, right, left_on, right_on, how, suffixes,
     reference's hash join (bodo/libs/_hash_join.cpp build/probe) mapped
     onto gather/scatter. Returns None when not applicable (caller falls
     back to the union-segmentation sort join)."""
-    if how not in ("inner", "left") or right.nrows == 0 or \
-            config.dense_join_max_slots <= 0:
+    if how not in ("inner", "left") or right.nrows == 0:
         return None
     if null_equal and \
             any(left.column(k).valid is not None for k in left_on) and \
@@ -1467,17 +1472,17 @@ def _join_dense_try(left, right, left_on, right_on, how, suffixes,
         n = 1
         for lo, hi in rs:
             n *= int(hi) - int(lo) + 1
-            if n > config.dense_join_max_slots:
+            if n > DENSE_JOIN_MAX_SLOTS:
                 break
         return n
 
     n_slots = _slots(ranges)
-    ok = (n_slots <= config.dense_join_max_slots and
+    ok = (n_slots <= DENSE_JOIN_MAX_SLOTS and
           n_slots <= 16 * right.nrows + 1024)
     if not ok and inexact:
         ranges, inexact = _refine_ranges(right, right_on, ranges, inexact)
         n_slots = _slots(ranges)
-        ok = (n_slots <= config.dense_join_max_slots and
+        ok = (n_slots <= DENSE_JOIN_MAX_SLOTS and
               n_slots <= 16 * right.nrows + 1024)
     if not ok:
         return None  # too large or too sparse: LUT cost would dominate
@@ -2823,12 +2828,16 @@ def head_table(t: Table, n: int) -> Table:
     return Table(dict(g.columns), n, REP, None)
 
 
+# Re-bucket a table's physical capacity when occupancy falls below this.
+REBUCKET_THRESHOLD = 0.45
+
+
 def rebucket(t: Table) -> Table:
     """Shrink physical capacity when occupancy drops below the threshold
     (the re-bucketing step of the padded-capacity design, SURVEY.md §7)."""
     occupancy_cap = (max(t.counts.max(), 1) * t.num_shards
                      if t.distribution == ONED and len(t.counts)
                      else max(t.nrows, 1))
-    if occupancy_cap / t.capacity >= config.rebucket_threshold:
+    if occupancy_cap / t.capacity >= REBUCKET_THRESHOLD:
         return t
     return shrink_to_fit(t)

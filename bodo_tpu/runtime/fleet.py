@@ -30,11 +30,11 @@ repeat-issued query template lands on the same gang every time; callers
 with a real plan fingerprint can pass it explicitly.
 
 ADMISSION: a scrape thread GETs each gang's ``/metrics`` + ``/healthz``
-every ``config.fleet_scrape_s`` and runs the SAME admission decision
+every ``FLEET_SCRAPE_S`` and runs the SAME admission decision
 the gang would make locally (``signals_from_health`` merged with
 ``signals_from_metrics`` — built for exactly this remote-twin use).
 Submits route around shed/degraded/backed-off gangs to the next ring
-successor; a gang failing ``config.fleet_dead_scrapes`` consecutive
+successor; a gang failing ``FLEET_DEAD_SCRAPES`` consecutive
 scrapes (or observed dead at submit time) is evicted from the ring.
 When no gang is serviceable the client gets the healthiest gang's typed
 rejection with its retry hint — never a hang.
@@ -50,7 +50,7 @@ peer ever serves a pre-mutation result.
 
 SLO CLASSES + QUOTAS: sessions carry ``slo="latency"|"throughput"``
 end-to-end (the gang scheduler ages latency-class queues
-``config.serve_latency_boost``× faster) and the controller enforces a
+``scheduler.SERVE_LATENCY_BOOST``× faster) and the controller enforces a
 per-session in-flight quota (``config.fleet_session_quota``) as a typed
 ``Overloaded(reason="session_quota")``.
 
@@ -93,8 +93,8 @@ from bodo_tpu.utils.logging import log
 
 __all__ = [
     "ProtocolError", "FleetController", "FleetSession", "RemoteFleet",
-    "start", "stop", "controller", "controller_stats", "reconfigure",
-    "connect", "gang_main",
+    "start", "stop", "controller", "controller_stats", "connect",
+    "gang_main",
 ]
 
 
@@ -106,6 +106,12 @@ class ProtocolError(RuntimeError):
 # ---------------------------------------------------------------------------
 # framing
 # ---------------------------------------------------------------------------
+
+# Controller scrape cadence of each gang's /metrics + /healthz.
+FLEET_SCRAPE_S = 0.5
+# Consecutive failed scrapes before a gang is declared dead and evicted
+# from the routing ring.
+FLEET_DEAD_SCRAPES = 3
 
 _HDR = struct.Struct(">IB")
 _KIND_JSON = ord("J")
@@ -807,8 +813,8 @@ class FleetController:
                 g.fail_scrapes += 1
                 self._c["scrape_failures"] = \
                     self._c.get("scrape_failures", 0) + 1
-                if g.fail_scrapes >= max(int(config.fleet_dead_scrapes),
-                                         1) and g.state != "dead":
+                if g.fail_scrapes >= FLEET_DEAD_SCRAPES \
+                        and g.state != "dead":
                     self._mark_dead_locked(
                         g, f"{g.fail_scrapes} consecutive scrape "
                            f"failures")
@@ -868,7 +874,7 @@ class FleetController:
                     continue
                 self._scrape_one(g)
             self._push_metrics()
-            self._stop_ev.wait(max(float(config.fleet_scrape_s), 0.05))
+            self._stop_ev.wait(FLEET_SCRAPE_S)
 
     def _push_metrics(self) -> None:
         try:
@@ -988,7 +994,7 @@ class FleetController:
                 f"no serviceable gang: best is {best.gang_id} "
                 f"({best.state}: {best.reason})",
                 retry_after_s=best.retry_after_s
-                or max(float(config.fleet_scrape_s), 0.25) * 2,
+                or FLEET_SCRAPE_S * 2,
                 reason=f"fleet_{best.state}")
 
     def _capacity_frac(self) -> float:
@@ -1049,7 +1055,6 @@ class FleetController:
                 pg = self._gangs.get(peer)
                 if pg is not None and pg.state != "dead":
                     peer_addr = pg.serve_addr
-        peering = bool(config.fleet_peering)
         try:
             sock = _connect(g.serve_addr, timeout=10.0)
         except OSError as e:
@@ -1059,11 +1064,9 @@ class FleetController:
             g2 = self._route(rkey)
             if g2.gang_id == g.gang_id:
                 raise QueryFailed(s.sid, qid, e) from None
-            return self._roundtrip_on(g2, s, fn, rkey, qid, peer_addr
-                                      if peering else None)
+            return self._roundtrip_on(g2, s, fn, rkey, qid, peer_addr)
         with sock:
-            return self._exchange(sock, g, s, fn, qid,
-                                  peer_addr if peering else None)
+            return self._exchange(sock, g, s, fn, qid, peer_addr)
 
     def _roundtrip_on(self, g: _GangState, s: FleetSession,
                       fn: Callable, rkey: str, qid: str,
@@ -1390,14 +1393,6 @@ def controller_stats() -> Optional[dict]:
         return ctl.stats()
     except Exception:  # noqa: BLE001
         return None
-
-
-def reconfigure() -> None:
-    """config.set_config hook for fleet_* knobs: wake the scrape loop
-    so cadence/thresholds re-read config immediately."""
-    # the scrape loop re-reads config.fleet_* each tick and nothing
-    # else is cached, so new values take effect within one cadence
-    _ = _controller
 
 
 def connect(addr: str) -> RemoteFleet:

@@ -25,7 +25,7 @@ Three pieces:
 
 All ``io:*`` observability counters (decode/stall seconds, prefetch
 hits, footer-cache hits, parallel decode units) live here so
-``tracing.profile()``/``dump()`` and the bench JSON read one registry.
+``tracing.profile()`` and ``dump()`` read one registry.
 """
 
 from __future__ import annotations

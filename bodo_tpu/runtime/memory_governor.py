@@ -25,8 +25,7 @@ its spill machinery only when `stream_device_budget_mb` was hand-set
      the plan executor re-runs the failed stage against the shrunken
      grant (plan/physical.py);
   4. exposes OBSERVABILITY: per-operator granted/peak/spilled bytes for
-     the tracing profile, bench JSON, and the chrome-trace `memory`
-     section.
+     the tracing profile and the chrome-trace `memory` section.
 
 The legacy `stream_device_budget_mb` knob still wins when set (tests and
 users that pin an explicit budget keep exact behavior); the governor is

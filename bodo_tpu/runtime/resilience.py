@@ -61,8 +61,8 @@ Three parts:
                             error (missing file, permissions)
 
 3. COUNTERS — every injected fault, retry, degraded stage, and gang
-   retry lands in `stats()`, which the tracing profile, chrome-trace
-   dump, and bench JSON all embed, so a degraded artifact says WHY it
+   retry lands in `stats()`, which the tracing profile and the
+   chrome-trace dump embed, so a degraded artifact says WHY it
    degraded.
 
 IMPORTANT: this module must stay importable WITHOUT the bodo_tpu
@@ -503,7 +503,7 @@ def count_gang_retry() -> None:
 
 def stats() -> dict:
     """JSON-safe snapshot of all resilience counters plus the armed
-    fault specs (embedded in tracing dumps and bench artifacts)."""
+    fault specs (embedded in tracing dumps)."""
     with _lock:
         return {
             "faults_armed": [f.spec() for f in (_armed or [])],

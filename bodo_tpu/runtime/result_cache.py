@@ -60,8 +60,7 @@ the budget (lowest benefit score first); when the inserting session is
 the only one over its share, its own entry is the victim — a tenant
 flooding the cache self-limits to its share and cannot evict another
 tenant's within-share working set. ``stats()["by_session"]`` exposes
-per-session hit/miss/eviction/byte counters (the isolation assertion in
-``bench.py --suite serve`` reads these).
+per-session hit/miss/eviction/byte counters.
 
 OWNERSHIP: the cache is PER-GANG — ownership is the (pid, gang_id)
 pair. Device buffers in entries are only valid on the process that
@@ -103,6 +102,7 @@ _PIN_TIER = 1e9            # score floor per live view dependent (_score)
 _AUTO_FRACTION = 0.125     # auto byte budget: slice of the derived budget
 _AUTO_FLOOR = 64 << 20
 _AUTO_DEFAULT = 256 << 20  # when no governor budget can be derived
+HOST_TIER_BYTES = 1 << 28  # byte cap of the host spill tier
 
 
 # --------------------------------------------------------------------------
@@ -215,8 +215,7 @@ def _env_key() -> tuple:
     """Execution geometry baked into every key: a result computed on one
     mesh/shard policy must not serve a query running under another."""
     from bodo_tpu.parallel import mesh as mesh_mod
-    return (mesh_mod.num_shards(), int(config.shard_min_rows),
-            bool(getattr(config, "low_precision_agg", False)))
+    return (mesh_mod.num_shards(), int(config.shard_min_rows))
 
 
 def _sig_digest(sigs) -> str:
@@ -669,8 +668,7 @@ class ResultCache:
     def _spill_locked(self, e: _Entry) -> None:
         """Device → host pandas tier (query entries only — node-level
         memoization is not worth a host copy)."""
-        if e.kind != "q" or not config.result_cache_host_spill \
-                or int(config.result_cache_host_bytes) <= 0:
+        if e.kind != "q" or not config.result_cache_host_spill:
             self._drop_locked(e)
             return
         try:
@@ -746,8 +744,7 @@ class ResultCache:
             self._c["evictions"] = self._c.get("evictions", 0) + 1
             self._count_sess_locked(victim.session, "evicted")
             self._spill_locked(victim)
-        host_budget = max(int(config.result_cache_host_bytes), 0)
-        while self.host_bytes > host_budget:
+        while self.host_bytes > HOST_TIER_BYTES:
             cands = [e for e in self._entries.values()
                      if e.host is not None]
             if not cands:
@@ -1142,7 +1139,7 @@ class ResultCache:
         A successful import is recorded locally like a fresh result, so
         the NEXT repeat is a plain device hit."""
         fetch = _peer_fetch
-        if fetch is None or not getattr(config, "fleet_peering", True):
+        if fetch is None:
             return None
         try:
             payload = fetch(qi.key)

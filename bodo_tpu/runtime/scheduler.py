@@ -68,6 +68,10 @@ from bodo_tpu.utils.logging import log
 _STORM_SIGS_MAX = 8       # storm signatures remembered per session
 _EWMA_ALPHA = 0.5         # weight of the newest query in session EWMAs
 _SIGNAL_TTL_S = 0.2       # local_signals() snapshot reuse window
+# Latency-bound SLO class: priority aging runs this many times faster
+# for slo="latency" sessions, so their queued requests overtake
+# throughput-bound traffic without starving it.
+SERVE_LATENCY_BOOST = 4.0
 
 
 # --------------------------------------------------------------------------
@@ -689,11 +693,11 @@ class Scheduler:
         """Virtual-time rank with priority aging: every serve_aging_s
         seconds the head request has waited discounts one second of
         accrued virtual time, so starvation is bounded. Latency-class
-        sessions age serve_latency_boost× faster — their head overtakes
+        sessions age SERVE_LATENCY_BOOST× faster — their head overtakes
         queued throughput traffic without zeroing its progress."""
         aging = max(float(config.serve_aging_s), 0.01)
         if s.slo == "latency":
-            aging /= max(float(config.serve_latency_boost), 1.0)
+            aging /= SERVE_LATENCY_BOOST
         waited = now - s.queue[0].enq_ts
         return s.vtime - waited / aging
 
@@ -1022,7 +1026,7 @@ def current_session() -> Optional[str]:
 
 
 class session_scope:
-    """Attribute work on the CALLING thread to a session (tests, bench
+    """Attribute work on the CALLING thread to a session (tests and
     clients that bypass the worker pool)."""
 
     def __init__(self, sid: str):

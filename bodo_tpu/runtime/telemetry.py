@@ -777,13 +777,17 @@ def _write_progcheck(d: str) -> None:
         pass
 
 
+# Slowest-N EXPLAIN ANALYZE records embedded per bundle.
+FLIGHT_SLOW_QUERIES = 5
+
+
 def _write_slow_queries(d: str) -> None:
     ex = _mod("bodo_tpu.plan.explain")
     if ex is None:
         return
     try:
         _write_json(os.path.join(d, "slow_queries.json"),
-                    ex.slow_queries(int(config.flight_slow_queries)))
+                    ex.slow_queries(FLIGHT_SLOW_QUERIES))
     except Exception:
         pass
 

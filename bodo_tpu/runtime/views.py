@@ -47,6 +47,10 @@ from bodo_tpu.utils.logging import log
 MAINTENANCE_SESSION = "__maintenance__"
 
 _STALENESS_SAMPLES = 256   # per-view staleness history for the p99
+# Per-source-file contribution maps (partition-level invalidation) are
+# built only for datasets of at most this many files — the map costs one
+# extra pass over the dataset per materialization.
+VIEW_MAX_PARTS = 64
 
 
 class ViewError(ValueError):
@@ -363,11 +367,11 @@ def _materialize(v: _View):
                     v.refreshes_full += 1
                     v.full_wall_s += wall
                 # contribution map for partition-level invalidation,
-                # rebuilt per generation (bounded by view_max_parts)
+                # rebuilt per generation (bounded by VIEW_MAX_PARTS)
                 try:
                     cache.build_parts(
                         qi.key, physical._exec,
-                        max_parts=int(config.view_max_parts))
+                        max_parts=VIEW_MAX_PARTS)
                 except Exception:  # noqa: BLE001
                     pass
         if changed or v.stale_since is not None:
@@ -586,8 +590,8 @@ def stats() -> dict:
             subscriptions=_n_subs,
             refreshes_incremental=n_inc,
             refreshes_full=n_full,
-            # refresh cost relative to full recompute cost (the bench
-            # bar: <= 0.10); 0.0 until a refresh has happened
+            # refresh cost relative to full recompute cost; 0.0 until a
+            # refresh has happened
             refresh_ratio=round(ref_wall / full_wall, 6)
             if full_wall > 0 and n_ref > 0 else 0.0,
             staleness_p99_s=round(max(lag_p99, 0.0), 6),

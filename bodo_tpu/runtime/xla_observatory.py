@@ -441,8 +441,7 @@ _live: Dict[int, Tuple[int, Optional[str], str]] = {}  # id -> (nbytes, qid, op)
 _ledger = {"created_bytes": 0, "freed_bytes": 0,
            "created_buffers": 0, "freed_buffers": 0,
            # high-water mark of live tracked bytes — what progcheck's
-           # static HBM estimates are judged against (bench.py's
-           # progcheck_hbm_estimate_ratio)
+           # static HBM estimates are judged against
            "peak_live_bytes": 0}
 _by_op: Dict[str, Dict[str, int]] = {}
 _MAX_QUERY_REPORTS = 256
@@ -622,8 +621,7 @@ def query_report(qid: Optional[str] = None) -> dict:
 
 def leak_check(collect: bool = True) -> dict:
     """Force a gc pass (finalizers fire) and report what stayed live,
-    grouped by op — the bench leak assertion and doctor's leak triage
-    both read this."""
+    grouped by op — the flight recorder's leak triage reads this."""
     if collect:
         gc.collect()
     with _lock:

@@ -98,7 +98,7 @@ def session(session_id: Optional[str] = None, *, priority: float = 1.0,
     fair-share weight (2.0 gets twice the gang of 1.0 under
     contention); ``allow_degraded`` opts into service while the gang
     has unhealthy ranks; ``slo`` is the service class — ``"latency"``
-    ages serve_latency_boost× faster under contention,
+    ages SERVE_LATENCY_BOOST× faster under contention,
     ``"throughput"`` (default) takes the plain fair share."""
     return scheduler().session(session_id, priority=priority,
                                allow_degraded=allow_degraded, slo=slo)

@@ -23,6 +23,11 @@ from collections import OrderedDict
 
 from bodo_tpu.runtime import xla_observatory as _obs
 
+# Max compiled kernels pinned per kernel cache (LRU eviction beyond
+# this — unbounded pinning exhausts XLA:CPU JIT code memory and
+# segfaults the compiler after thousands of distinct compilations).
+KERNEL_CACHE_SIZE = 512
+
 
 class KernelCache:
     """Dict-shaped LRU with the two operations the kernel caches use
@@ -230,7 +235,8 @@ def named_jit(name: str, fun, **jit_kwargs):
     return jax.jit(fun, **jit_kwargs)
 
 
-def bounded_jit(fun=None, *, static_argnames=(), maxsize=None):
+def bounded_jit(fun=None, *, static_argnames=(),
+                maxsize=KERNEL_CACHE_SIZE):
     """`jax.jit` whose live compiled executables are BOUNDED.
 
     A module-level `jax.jit` pins one executable per distinct
@@ -253,9 +259,6 @@ def bounded_jit(fun=None, *, static_argnames=(), maxsize=None):
         return functools.partial(bounded_jit,
                                  static_argnames=static_argnames,
                                  maxsize=maxsize)
-    if maxsize is None:
-        from bodo_tpu.config import config
-        maxsize = config.kernel_cache_size
 
     def _describe(key):
         struct, leaf_keys = key

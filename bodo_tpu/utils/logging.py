@@ -6,6 +6,7 @@ Level 1: pushdown/fallback/IO notices; 2: plan dumps; 3: kernel trace.
 from __future__ import annotations
 
 import sys
+import warnings
 
 from bodo_tpu.config import config
 
@@ -18,8 +19,6 @@ def log(level: int, msg: str) -> None:
 def warn_fallback(api: str, reason: str) -> None:
     """Emit the pandas-fallback warning (reference: check_args_fallback
     warning, bodo/pandas/utils.py:346)."""
-    if config.warn_fallback:
-        import warnings
-        warnings.warn(
-            f"{api}: falling back to pandas ({reason}); this materializes "
-            f"the frame on the host", stacklevel=3)
+    warnings.warn(
+        f"{api}: falling back to pandas ({reason}); this materializes "
+        f"the frame on the host", stacklevel=3)

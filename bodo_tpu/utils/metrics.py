@@ -20,7 +20,7 @@ Three metric kinds, all label-aware and thread-safe:
 canonically named metrics (``bodo_tpu_*``); ``expose_text()`` renders
 the whole registry in the Prometheus text exposition format;
 ``snapshot()`` returns the same data as a JSON-safe dict (embedded in
-tracing dumps and bench artifacts). Query-scoped operator counters
+tracing dumps). Query-scoped operator counters
 (labelled ``query=...``/``op=...``) are synthesized from the tracing
 layer's per-query aggregates, so per-query accounting needs no extra
 bookkeeping on the hot event path.

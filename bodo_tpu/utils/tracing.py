@@ -36,8 +36,8 @@ reported in `dump()`.
 
 Counter-valued profile rows (`mem:`/`resil:`/`aqe:`/`io:`/`lint:`/
 `lockstep:`/`cache:`) are read from the unified metrics registry
-(utils/metrics.py `sync_engine_metrics`), which is also what the bench
-JSON and the Prometheus exposition serve.
+(utils/metrics.py `sync_engine_metrics`), which is also what the
+Prometheus exposition serves.
 """
 
 from __future__ import annotations
@@ -776,7 +776,7 @@ def profile(query_id: Optional[str] = None) -> Dict[str, dict]:
 
 def top_ops(query_id: Optional[str] = None, n: int = 5) -> List[dict]:
     """Top-n operators by wall seconds for one query (or overall):
-    the bench artifact's "where did the time go" rows."""
+    the "where did the time go" rows."""
     with _lock:
         rows: Dict[str, dict] = {}
         for (qid, name), v in _agg.items():

@@ -24,7 +24,7 @@ weighted-fair work on the system maintenance session (tenants are not
 billed for shared maintenance).
 
 Knobs: ``BODO_TPU_VIEW_*`` (see config.py) — watcher poll interval,
-maintenance session weight, partition-map size bound.
+maintenance session weight.
 """
 
 from __future__ import annotations

@@ -1,5 +1,5 @@
 """Shared NYC-taxi-shaped workload: data generator, pandas oracle, and the
-bodo_tpu pipeline. Used by the e2e test, bench.py, and __graft_entry__.py.
+bodo_tpu pipeline. Used by the e2e test, chip_smoke.py and __graft_entry__.py.
 
 Mirrors the reference benchmark get_monthly_travels_weather
 (reference: benchmarks/nyc_taxi/bodo/nyc_taxi_precipitation.py): csv+parquet

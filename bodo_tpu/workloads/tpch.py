@@ -141,8 +141,8 @@ def gen_tpch(n_orders: int = 1500, seed: int = 0):
             "orders": orders, "lineitem": lineitem}
 
 
-# Queries the engine cannot yet plan (kept beside QUERIES so the bench
-# and the test suite share one source of truth). Currently empty — Q21's
+# Queries the engine cannot yet plan (kept beside QUERIES so every
+# test file shares one source of truth). Currently empty — Q21's
 # non-equality correlated EXISTS is handled by the row-id decorrelation.
 UNSUPPORTED = {}
 
@@ -450,9 +450,9 @@ order by cntrycode
 
 
 # ---------------------------------------------------------------------------
-# sqlite oracle helpers (shared by tests/test_tpch.py and bench.py --suite
-# tpch): translate the standard query texts into sqlite's dialect so the
-# stdlib engine can serve as a differential baseline (the reference's
+# sqlite oracle helpers (shared by tests/test_tpch.py and chip_smoke.py):
+# translate the standard query texts into sqlite's dialect so the stdlib
+# engine can serve as a differential baseline (the reference's
 # differential-oracle strategy, SURVEY.md §4).
 # ---------------------------------------------------------------------------
 

@@ -144,26 +144,6 @@ def _run_progcheck() -> int:
     return r.returncode
 
 
-def _run_benchwatch() -> int:
-    """Bench-trajectory regression gate: validates every BENCH_r*.json
-    against the stable schema and fails on a direction-aware regression
-    of any tracked metric (bodo_tpu/benchwatch.py)."""
-    if not glob.glob(os.path.join(_REPO, "BENCH_r*.json")):
-        return 0  # no trajectory yet: nothing to regress against
-    print("[benchwatch] python -m bodo_tpu.benchwatch --check ... ",
-          end="", flush=True)
-    t1 = time.time()
-    r = subprocess.run([sys.executable, "-m", "bodo_tpu.benchwatch",
-                        "--check"],
-                       cwd=_REPO, capture_output=True, text=True,
-                       timeout=120)
-    tail = (r.stdout.strip().splitlines() or [""])[-1]
-    print(f"{tail}  ({time.time() - t1:.0f}s)")
-    if r.returncode != 0:
-        sys.stdout.write(r.stdout[-4000:] + r.stderr[-2000:] + "\n")
-    return r.returncode
-
-
 def main(argv: list[str]) -> int:
     want_lint = "lint" in argv
     argv = [a for a in argv if a != "lint"]
@@ -188,8 +168,6 @@ def main(argv: list[str]) -> int:
     if full_suite:
         if _run_progcheck() != 0:
             failed.append("progcheck")
-        if _run_benchwatch() != 0:
-            failed.append("benchwatch")
     for i, group in enumerate(groups):
         names = " ".join(os.path.relpath(m, _REPO) for m in group)
         label = names if len(group) == 1 else \

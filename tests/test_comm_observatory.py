@@ -2,9 +2,9 @@
 known shuffle sizes, arrival-skew straggler attribution (in-process and
 across a real spawned gang with an injected latency fault), rank-aware
 critical-path analysis over a synthetic merged trace, the EXPLAIN
-ANALYZE comm-vs-compute split, doctor comm triage, the benchwatch
-regression watcher, the swallowed-collective lint rule, and live
-/metrics exposure of the ``bodo_tpu_comm_*`` family.
+ANALYZE comm-vs-compute split, doctor comm triage, the
+swallowed-collective lint rule, and live /metrics exposure of the
+``bodo_tpu_comm_*`` family.
 
 NOTE: the tier-1 runner executes modules in shared processes (this one
 is isolated in runtests.py), and every test here restores the global
@@ -401,185 +401,6 @@ class TestDoctorComm:
         rep = doctor.render(t)
         assert "critical path:" in rep
         assert "trace straggler: rank 0" in rep
-
-
-# ----------------------------------------------------- benchwatch
-
-def _bench_rec(n, value, *, unit="x", metric="speedup", rc=0):
-    return {"n": n, "cmd": "python bench.py", "rc": rc,
-            "tail": "...",
-            "parsed": {"metric": metric, "value": value, "unit": unit,
-                       "vs_baseline": 1.0, "detail": {}}}
-
-
-def _write_traj(d, values, **kw):
-    os.makedirs(d, exist_ok=True)
-    for i, v in enumerate(values, 1):
-        with open(os.path.join(d, f"BENCH_r{i:02d}.json"), "w") as f:
-            json.dump(_bench_rec(i, v, **kw), f)
-
-
-class TestBenchwatch:
-    def test_higher_better_regression(self, tmp_path):
-        from bodo_tpu import benchwatch
-        d = str(tmp_path / "t1")
-        _write_traj(d, [2.0, 2.5, 1.9])  # -24% vs best 2.5
-        out = benchwatch.watch(d, threshold=0.15)
-        assert out["regressions"] == ["speedup"]
-        assert not out["ok"]
-        v = out["metrics"]["speedup"]
-        assert v["status"] == "regression"
-        assert v["reference"] == 2.5
-        assert "REGRESSION" in benchwatch.render(out)
-
-    def test_lower_better_direction(self, tmp_path):
-        from bodo_tpu import benchwatch
-        d = str(tmp_path / "t2")
-        # a frac metric RISING is the regression
-        _write_traj(d, [0.010, 0.011, 0.030], unit="frac",
-                    metric="comm_overhead_frac")
-        out = benchwatch.watch(d, threshold=0.15)
-        assert out["regressions"] == ["comm_overhead_frac"]
-        # and falling is an improvement, not a regression
-        d2 = str(tmp_path / "t3")
-        _write_traj(d2, [0.030, 0.011], unit="frac",
-                    metric="comm_overhead_frac")
-        out2 = benchwatch.watch(d2, threshold=0.15)
-        assert out2["ok"]
-        assert out2["metrics"]["comm_overhead_frac"][
-            "status"] == "improvement"
-
-    def test_within_threshold_is_stable(self, tmp_path):
-        from bodo_tpu import benchwatch
-        d = str(tmp_path / "t4")
-        _write_traj(d, [2.0, 2.5, 2.4])
-        out = benchwatch.watch(d, threshold=0.15)
-        assert out["ok"]
-        assert out["metrics"]["speedup"]["status"] == "stable"
-
-    def test_against_prev_and_median(self, tmp_path):
-        from bodo_tpu import benchwatch
-        d = str(tmp_path / "t5")
-        _write_traj(d, [1.0, 3.0, 2.9])
-        best = benchwatch.watch(d)  # vs best 3.0: stable
-        assert best["metrics"]["speedup"]["reference"] == 3.0
-        prev = benchwatch.watch(d, against="prev")
-        assert prev["metrics"]["speedup"]["reference"] == 3.0
-        med = benchwatch.watch(d, against="median")
-        assert med["metrics"]["speedup"]["reference"] == 3.0
-
-    def test_waiver_downgrades_regression_for_that_round(self,
-                                                         tmp_path):
-        from bodo_tpu import benchwatch
-        d = str(tmp_path / "tw")
-        _write_traj(d, [2.0, 2.5, 1.9])
-        # waive the regressing round with a documented reason
-        p = os.path.join(d, "BENCH_r03.json")
-        with open(p) as f:
-            rec = json.load(f)
-        rec["waiver"] = "degraded box: pristine HEAD control also slow"
-        with open(p, "w") as f:
-            json.dump(rec, f)
-        out = benchwatch.watch(d, threshold=0.15)
-        assert out["ok"]
-        assert out["regressions"] == []
-        v = out["metrics"]["speedup"]
-        assert v["status"] == "waived"
-        rendered = benchwatch.render(out)
-        assert "WAIVED" in rendered
-        assert "degraded box" in rendered
-        # the waiver covers ONLY its round: a later unwaived round
-        # still regresses against the pre-waiver high-water mark
-        with open(os.path.join(d, "BENCH_r04.json"), "w") as f:
-            json.dump(_bench_rec(4, 1.8), f)
-        out2 = benchwatch.watch(d, threshold=0.15)
-        assert out2["regressions"] == ["speedup"]
-        assert not out2["ok"]
-
-    def test_embedded_suite_metrics_are_tracked(self, tmp_path):
-        from bodo_tpu import benchwatch
-        d = str(tmp_path / "ts")
-        _write_traj(d, [2.0, 2.1])
-        # round 3 embeds per-suite summaries under parsed.detail.suites;
-        # each becomes its own tracked series alongside the headline
-        rec = _bench_rec(3, 2.2)
-        rec["parsed"]["detail"]["suites"] = {
-            "join": {"metric": "join_mrows_per_s", "value": 1.1,
-                     "unit": "Mrows/s", "detail": {}},
-            "fusion": {"metric": "fusion_speedup_ratio", "value": 0.7,
-                       "unit": "frac"},
-            "broken": {"no": "summary keys"},  # skipped, not fatal
-        }
-        with open(os.path.join(d, "BENCH_r03.json"), "w") as f:
-            json.dump(rec, f)
-        out = benchwatch.watch(d, threshold=0.15)
-        assert out["ok"]
-        assert out["metrics"]["join_mrows_per_s"]["status"] == "new"
-        assert out["metrics"]["fusion_speedup_ratio"]["status"] == "new"
-        assert all("broken" not in m for m in out["metrics"])
-        # a later round regressing an embedded metric fails the watch
-        # (Mrows/s is higher-better: 0.5 vs best 1.1 regresses) ...
-        rec4 = _bench_rec(4, 2.2)
-        rec4["parsed"]["detail"]["suites"] = {
-            "join": {"metric": "join_mrows_per_s", "value": 0.5,
-                     "unit": "Mrows/s"}}
-        with open(os.path.join(d, "BENCH_r04.json"), "w") as f:
-            json.dump(rec4, f)
-        out2 = benchwatch.watch(d, threshold=0.15)
-        assert out2["regressions"] == ["join_mrows_per_s"]
-        assert not out2["ok"]
-        # ... and the round's waiver covers its embedded metrics too
-        rec4["waiver"] = "degraded box: control run also slow"
-        with open(os.path.join(d, "BENCH_r04.json"), "w") as f:
-            json.dump(rec4, f)
-        out3 = benchwatch.watch(d, threshold=0.15)
-        assert out3["ok"]
-        assert out3["metrics"]["join_mrows_per_s"]["status"] == "waived"
-
-    def test_schema_violations_fail_loudly(self, tmp_path):
-        from bodo_tpu import benchwatch
-        d = str(tmp_path / "t6")
-        os.makedirs(d)
-        with open(os.path.join(d, "BENCH_r01.json"), "w") as f:
-            f.write("{not json")
-        with open(os.path.join(d, "BENCH_r02.json"), "w") as f:
-            json.dump({"n": 2, "cmd": "x", "rc": 0,
-                       "parsed": {"metric": "m"}}, f)  # missing keys
-        out = benchwatch.watch(d)
-        assert not out["ok"]
-        assert len(out["errors"]) >= 2
-        assert any("unreadable" in e for e in out["errors"])
-        assert any("missing" in e for e in out["errors"])
-
-    def test_empty_dir_fails_check(self, tmp_path):
-        from bodo_tpu import benchwatch
-        d = str(tmp_path / "t7")
-        os.makedirs(d)
-        assert benchwatch.main(["--dir", d, "--check"]) == 1
-        assert benchwatch.main(["--dir", d]) == 0  # report-only
-
-    def test_cli_check_and_json(self, tmp_path, capsys):
-        from bodo_tpu import benchwatch
-        d = str(tmp_path / "t8")
-        _write_traj(d, [2.0, 2.5, 1.0])
-        assert benchwatch.main(["--dir", d, "--check",
-                                "--json"]) == 1
-        out = json.loads(capsys.readouterr().out)
-        assert out["regressions"] == ["speedup"]
-        d2 = str(tmp_path / "t9")
-        _write_traj(d2, [2.0, 2.1])
-        assert benchwatch.main(["--dir", d2, "--check"]) == 0
-
-    def test_repo_trajectory_is_valid(self):
-        """Whatever BENCH_r*.json artifacts the repo holds parse clean —
-        the runtests gate depends on it. (The records made through the
-        old device path were deleted; the next ones come from a
-        `benchmark` PR.)"""
-        from bodo_tpu import benchwatch
-        repo = os.path.dirname(os.path.dirname(
-            os.path.abspath(__file__)))
-        traj = benchwatch.load_trajectory(repo)
-        assert traj["errors"] == []
 
 
 # ------------------------------------------------- lint: swallowed

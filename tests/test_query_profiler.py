@@ -247,8 +247,8 @@ def test_prometheus_exposition():
 
 
 def test_engine_metrics_sync(mesh8):
-    """The unified registry carries the engine gauges the bench JSON
-    reads (compile seconds, pallas count) and per-query operator
+    """The unified registry carries the engine gauges (compile
+    seconds, pallas count) and per-query operator
     counters synthesized from the tracing aggregates."""
     import bodo_tpu.pandas_api as bd
     from bodo_tpu.utils import metrics

@@ -806,8 +806,7 @@ class TestLockstep:
     def test_single_process_records_and_profiles(self, mesh8,
                                                  monkeypatch,
                                                  lockstep_reset):
-        """Single-process mode (what the bench overhead suite measures):
-        dispatches are fingerprinted and counted with no peers to poll,
+        """Single-process mode: dispatches are fingerprinted and counted with no peers to poll,
         through the REAL relational dispatch path, and surface as the
         profile's lockstep:check row."""
         from bodo_tpu import relational

@@ -221,10 +221,12 @@ def test_cast_string_in_where_and_rounding(ctx):
 def test_json_quoted_numeric_key(mesh8):
     t = pd.DataFrame({"j": ['{"2": "x", "a.b": "y"}', "not json"]})
     c = BodoSQLContext({"t": t})
+    # a row that is not JSON gives SQL NULL (sqlite's json_extract under
+    # json_valid says the same), which pandas' `str` dtype carries as NaN
     got = _col(c, "select json_extract_path_text(j, '\"2\"') from t")
-    assert got.where(got.notna(), None).tolist() == ["x", None]
+    assert [None if pd.isna(v) else v for v in got] == ["x", None]
     got2 = _col(c, "select json_extract_path_text(j, '\"a.b\"') from t")
-    assert got2.where(got2.notna(), None).tolist() == ["y", None]
+    assert [None if pd.isna(v) else v for v in got2] == ["y", None]
 
 
 def test_regexp_position_validation(ctx):

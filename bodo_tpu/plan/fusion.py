@@ -27,7 +27,9 @@ This module implements the plan-level version of that inversion:
                     evaluate element-wise on the uncompacted tree (dead
                     rows compute garbage harmlessly, exactly like the
                     eval-then-mask order of relational.filter_table).
-                    One `K.compact` runs at group exit — or ZERO when a
+                    One `K.compact` runs at group exit (the surviving
+                    rows' source index found once, every column
+                    gathered at it) — or ZERO when a
                     terminal dense Aggregate consumes the mask directly
                     via `relational.dense_agg_tail`, which aggregates
                     per slot by one of three routes
@@ -166,6 +168,9 @@ _stats = {"groups_planned": 0, "groups_executed": 0, "stream_chains": 0,
           "dense_reduce": 0, "dense_scatter": 0, "dense_mxu": 0,
           # joins by the realisation they took (`join_route`)
           "join_dense": 0, "join_hash": 0, "join_sort": 0, "join_fused": 0,
+          # inner LUT joins by how their result was emitted
+          # (`join_emitted`): compacted at its own size, or not at all
+          "join_emit": 0, "join_emit_skipped": 0,
           # scan batches entering fused chains straight off the device
           # decode path (io/device_decode.py) — no host round-trip
           # between ingest and the compiled chain body
@@ -222,6 +227,15 @@ def join_route(route: str, keys: int, rows_left: int, rows_right: int):
     _stats["join_" + route] += 1
     return tracing.event("join." + route, keys=keys, rows_left=rows_left,
                          rows_right=rows_right)
+
+
+def join_emitted(rows_out: int, skipped: bool) -> None:
+    """An inner LUT join (`join.dense`, `join.hash`) says, inside its
+    span, how many rows it found and whether emitting them had to
+    compact (`join_emit`) or every live probe row had its match and the
+    compaction was skipped (`join_emit_skipped`)."""
+    _stats["join_emit_skipped" if skipped else "join_emit"] += 1
+    tracing.annotate(rows_out=rows_out)
 
 
 def reset_stats() -> None:

@@ -34,7 +34,7 @@ from bodo_tpu.parallel import mesh as mesh_mod
 from bodo_tpu.parallel.shuffle import (_mesh_key, _MESHES, groupby_sharded,
                                        shuffle_rows)
 from bodo_tpu.plan.expr import Expr, eval_expr, infer_dtype
-from bodo_tpu.plan.fusion import fusion_stage, join_route
+from bodo_tpu.plan.fusion import fusion_stage, join_emitted, join_route
 from bodo_tpu.table import dtypes as dt
 from bodo_tpu.table.dict_utils import unify_dictionaries
 from bodo_tpu.table.table import Column, ONED, REP, Table, round_capacity
@@ -1013,18 +1013,21 @@ def dense_agg_tail(tree, live, kn, vn, specs, sizes, los, n_slots: int,
         outs = [_segment_agg(op, tree[c][0], tree[c][1], slot,
                              padmask, n_slots)
                 for c, op in zip(vn, specs)]
-    # reconstruct keys from the slot index (mixed-radix decode)
-    rem = jnp.arange(n_slots, dtype=jnp.int32)
-    key_cols = [None] * len(kn)
+    # the present slots ascending: their indices ARE the keys (mixed-
+    # radix decode of `src`, neither scattered nor gathered); only the
+    # aggregates are gathered
+    src, n_groups = K.compact_index(present)
+    keep = K.row_mask(n_groups, n_slots)
+    rem = src
+    out_keys = [None] * len(kn)
     for i in range(len(kn) - 1, -1, -1):
         code = rem % np.int32(sizes[i])
         rem = rem // np.int32(sizes[i])
-        key_cols[i] = code.astype(jnp.int64) + np.int64(los[i])
+        out_keys[i] = jnp.where(
+            keep, code.astype(jnp.int64) + np.int64(los[i]), 0)
     vflat, slots_v = _flatten_with_valids(outs)
-    packed, n_groups = K.compact(present,
-                                 tuple(key_cols) + tuple(vflat))
-    out_keys = packed[:len(kn)]
-    out_vals = _rebuild_from_flat(packed[len(kn):], slots_v)
+    out_vals = _rebuild_from_flat(
+        K.take_compacted(src, n_groups, tuple(vflat)), slots_v)
     return tuple(out_keys), tuple(out_vals), n_groups
 
 
@@ -1450,8 +1453,12 @@ def _join_dense_try(left, right, left_on, right_on, how, suffixes,
     """Dense-LUT equi-join: when the build (right) side's keys have a
     small host-known range and are unique, the join is a perfect-hash
     lookup — build scatters row indices into a dense LUT, probe gathers.
-    No sort, no shuffle; output capacity == probe capacity (unique build
-    keys ⇒ ≤1 match per probe row). The dimension-table fast path of the
+    No sort, no shuffle; unique build keys ⇒ ≤1 match per probe row, so
+    a left join's output is the probe table with the build columns
+    gathered beside it (one program), and an inner join's probe returns
+    only its `hit` mask, the build row index and the count: the host
+    reads the count and `_join_emit` gathers the columns at the size of
+    the result. The dimension-table fast path of the
     reference's hash join (bodo/libs/_hash_join.cpp build/probe) mapped
     onto gather/scatter. Returns None when not applicable (caller falls
     back to the union-segmentation sort join)."""
@@ -1523,8 +1530,12 @@ def _join_dense_try(left, right, left_on, right_on, how, suffixes,
     use_gather = ((PK.use_pallas() or PK.FORCE_INTERPRET)
                   and n_slots <= PK.MAX_MATMUL_SLOTS
                   and right.capacity < PK.MAX_GATHER_VALUE)
-    pkey = ("densejoin_probe", _sig(left.select(lorder)),
-            _sig(right.select(rorder)), sizes, los, nk, how, use_gather)
+    inner = how == "inner"
+    # an inner probe is handed the key columns alone, and no build array
+    pkey = ("densejoin_probe",
+            _sig(left.select(lorder[:nk] if inner else lorder)),
+            None if inner else _sig(right.select(rorder)),
+            sizes, los, nk, how, use_gather)
     pfn = _jit_cache.get(pkey)
     if pfn is None:
         def pbody(p_arrays, b_arrays, lut, pcount):
@@ -1536,27 +1547,24 @@ def _join_dense_try(left, right, left_on, right_on, how, suffixes,
             idx = jnp.where(live, g, -1)
             hit = idx >= 0
             safe = jnp.maximum(idx, 0)
-            out_b = []
-            for d, v in b_arrays:
-                od = d[safe]
-                ov = hit if v is None else (hit & v[safe])
-                out_b.append((od, ov))
-            if how == "inner":
-                flat, slots = _flatten_with_valids(
-                    tuple(p_arrays) + tuple(out_b))
-                packed, cnt = K.compact(hit, tuple(flat))
-                rebuilt = _rebuild_from_flat(packed, slots)
-                np_ = len(p_arrays)
-                return (tuple(rebuilt[:np_]), tuple(rebuilt[np_:]), cnt)
+            if inner:
+                # touches no column: `_join_emit` gathers them at the
+                # size of the result once the host has read `cnt`
+                return hit, safe, jnp.sum(hit)
             # left join: keep every probe row; unmatched build cols invalid
-            out_p2 = tuple((d, v) for d, v in p_arrays)
-            return out_p2, tuple(out_b), pcount
+            return tuple(p_arrays), _gather_build(b_arrays, hit, safe), pcount
 
         pfn = named_jit("join_probe_dense", pbody)
         _jit_cache[pkey] = pfn
 
     with join_route("dense", nk, left.nrows, right.nrows):
-        out_p, out_b, cnt = pfn(pa, ba, lut, jnp.asarray(left.nrows))
+        pc = jnp.asarray(left.nrows)
+        if inner:
+            hit, safe, cnt = pfn(pa[:nk], (), lut, pc)
+            return _join_emit(left, right, left_on, right_on, suffixes,
+                              (lorder, rorder, pa, ba), hit, safe,
+                              int(jax.device_get(cnt)))
+        out_p, out_b, cnt = pfn(pa, ba, lut, pc)
         nrows = int(jax.device_get(cnt))
         res = _assemble_join(left, right, left_on, right_on, lorder, rorder,
                              out_p, out_b, nrows, None, how, suffixes)
@@ -1569,8 +1577,10 @@ def _join_hash_try(left, right, left_on, right_on, how, suffixes,
     key-range gate. The build side claims slots in a scatter-claim hash
     table (ops/hashtable.py) — owner IS the LUT — and probe rows follow
     the same double-hash sequence to a match or an empty slot. Unique
-    build keys ⇒ ≤1 match per probe row ⇒ static probe-side output
-    capacity, no sort, no shuffle. Arbitrary key dtypes/ranges
+    build keys ⇒ ≤1 match per probe row, no sort, no shuffle; the output
+    is emitted as the dense LUT's is (a left join in the probe program,
+    an inner join by `_join_emit` at the size of its result). Arbitrary
+    key dtypes/ranges
     (reference: bodo/libs/_hash_join.cpp build/probe). Returns None on
     duplicate build keys or probe-round exhaustion (caller falls back
     to the sort join)."""
@@ -1621,8 +1631,11 @@ def _join_hash_try(left, right, left_on, right_on, how, suffixes,
         if bool(jax.device_get(bad)):
             return None  # duplicate build keys (or pathological probing)
 
-    pkey = ("hashjoin_probe", _sig(left.select(lorder)),
-            _sig(right.select(rorder)), nk, null_equal, T, how, null_cols)
+    inner = how == "inner"
+    pkey = ("hashjoin_probe",
+            _sig(left.select(lorder[:nk] if inner else lorder)),
+            None if inner else _sig(right.select(rorder)),
+            nk, null_equal, T, how, null_cols)
     pfn = _jit_cache.get(pkey)
     if pfn is None:
         def pbody(p_arrays, b_arrays, bcodes, owner, pcount):
@@ -1635,34 +1648,87 @@ def _join_hash_try(left, right, left_on, right_on, how, suffixes,
             idx, p_unres = HT.probe_slots(bcodes, owner, codes, live, T)
             hit = idx >= 0
             safe = jnp.maximum(idx, 0)
-            out_b = []
-            for d, v in b_arrays:
-                od = d[safe]
-                ov = hit if v is None else (hit & v[safe])
-                out_b.append((od, ov))
-            if how == "inner":
-                flat, slots = _flatten_with_valids(
-                    tuple(p_arrays) + tuple(out_b))
-                packed, cnt = K.compact(hit, tuple(flat))
-                rebuilt = _rebuild_from_flat(packed, slots)
-                np_ = len(p_arrays)
-                return (tuple(rebuilt[:np_]), tuple(rebuilt[np_:]), cnt,
-                        p_unres)
-            out_p2 = tuple((d, v) for d, v in p_arrays)
-            return out_p2, tuple(out_b), pcount, p_unres
+            if inner:
+                # as the dense probe: no column, `_join_emit` has them
+                return hit, safe, jnp.sum(hit), p_unres
+            return (tuple(p_arrays), _gather_build(b_arrays, hit, safe),
+                    pcount, p_unres)
 
         pfn = named_jit("join_probe_hash", pbody)
         _jit_cache[pkey] = pfn
 
     with join_route("hash", nk, left.nrows, right.nrows):
-        out_p, out_b, cnt, p_unres = pfn(pa, ba, bcodes, owner,
-                                         jnp.asarray(left.nrows))
+        pc = jnp.asarray(left.nrows)
+        if inner:
+            hit, safe, cnt, p_unres = pfn(pa[:nk], (), bcodes, owner, pc)
+        else:
+            out_p, out_b, cnt, p_unres = pfn(pa, ba, bcodes, owner, pc)
         nrows_, unres_ = jax.device_get((cnt, p_unres))
         if bool(unres_):
             return None  # pathological probe chains: the sort join's
+        if inner:
+            return _join_emit(left, right, left_on, right_on, suffixes,
+                              (lorder, rorder, pa, ba), hit, safe,
+                              int(nrows_))
         res = _assemble_join(left, right, left_on, right_on, lorder, rorder,
                              out_p, out_b, int(nrows_), None, how, suffixes)
         return rebucket(res)
+
+
+def _gather_build(b_arrays, hit, safe):
+    """A LUT probe's build columns at every probe row: row `safe` of
+    each, valid where the probe row `hit` (and the build value is)."""
+    return tuple((d[safe], hit if v is None else (hit & v[safe]))
+                 for d, v in b_arrays)
+
+
+def _join_emit(left, right, left_on, right_on, suffixes, arrays, hit, safe,
+               nrows: int) -> Table:
+    """An inner LUT join's result from its probe's `hit` mask and build
+    row index `safe`, at the size of the result (`nrows`, which the
+    host has just read; `arrays` is `_probe_build_arrays`'). Every live
+    probe row hit: they are already a prefix, nothing is compacted, and
+    the build columns are gathered at `safe`. Otherwise one program at
+    the capacity `rebucket` would leave the result (`rebucket_capacity`:
+    the probe table's while the hits fill `REBUCKET_THRESHOLD` of it, so
+    that a join keeping most of its rows has one shape whatever the
+    count; `round_capacity(nrows)` below it): the hits' source index
+    once (`K.compact_index`), probe columns gathered at it, build
+    columns at `safe` of it, so no column is scattered and none is
+    touched at the probe table's capacity when the result is small."""
+    lorder, rorder, pa, ba = arrays
+    skip = nrows == left.nrows
+    join_emitted(nrows, skip)
+    out_cap = None if skip else rebucket_capacity(nrows, left.capacity)
+    ekey = ("join_probe_emit", _sig(left.select(lorder)),
+            _sig(right.select(rorder)), out_cap)
+    efn = _jit_cache.get(ekey)
+    if efn is None:
+        def ebody(p_arrays, b_arrays, hit, safe):
+            if out_cap is None:
+                return (), _gather_build(b_arrays, hit, safe)
+            src, n = K.compact_index(hit, out_cap)
+
+            def take(idx, pairs):
+                # `None` valids pass through `take_compacted`
+                return tuple(zip(
+                    K.take_compacted(idx, n, [d for d, _ in pairs]),
+                    K.take_compacted(idx, n, [v for _, v in pairs])))
+            keep = K.row_mask(n, out_cap)
+            return take(src, p_arrays), tuple(
+                (d, keep if v is None else v)
+                for d, v in take(safe[src], b_arrays))
+
+        efn = named_jit("join_probe_emit", ebody)
+        _jit_cache[ekey] = efn
+
+    out_p, out_b = efn(() if skip else pa, ba, hit, safe)
+    res = _assemble_join(left, right, left_on, right_on, lorder, rorder,
+                         pa if skip else out_p, out_b, nrows, None, "inner",
+                         suffixes)
+    # a compacted result is born at its capacity (a no-op here); an
+    # all-hit one keeps the probe table's, which may be far above its rows
+    return rebucket(res)
 
 
 def _probe_build_arrays(left, right, left_on, right_on):
@@ -2830,6 +2896,14 @@ def head_table(t: Table, n: int) -> Table:
 
 # Re-bucket a table's physical capacity when occupancy falls below this.
 REBUCKET_THRESHOLD = 0.45
+
+
+def rebucket_capacity(nrows: int, capacity: int) -> int:
+    """The capacity `rebucket` leaves a replicated table of `nrows` rows
+    in `capacity` slots, for a producer that can be born there."""
+    if max(nrows, 1) / capacity >= REBUCKET_THRESHOLD:
+        return capacity
+    return min(capacity, round_capacity(max(nrows, 1)))
 
 
 def rebucket(t: Table) -> Table:

@@ -370,3 +370,88 @@ def test_fused_aggregate_carries_its_route(one_dev):
     assert got["b"].tolist() == exp["b"].tolist()
     np.testing.assert_allclose(got["s"], exp["s"], rtol=1e-12)
     assert got["n"].tolist() == exp["n"].tolist()
+
+
+# ---------------------------------------------------------------------------
+# the LUT probes emit at the size of their result (PR 34): an inner
+# join's probe touches no column; `_join_emit` gathers them once the
+# host knows the count, or skips the compaction when every row hit
+# ---------------------------------------------------------------------------
+
+def _emit_tables(route, case):
+    """(left, right, how) whose join takes `route`: the hash LUT's keys
+    are the dense LUT's spread too thin for a dense slot space."""
+    r = np.random.default_rng(11)
+    n, nb = 4000, 300
+    spread = 1 if route == "dense" else 1_000_003
+    right = pd.DataFrame({"k": np.arange(nb) * spread,
+                          "name": [f"n{i}" for i in range(nb)],
+                          "z": np.arange(nb) * 1.5})
+    how = "inner"
+    if case == "all_hit":
+        k = r.integers(0, nb, n)
+    elif case == "none_hit":
+        k = r.integers(nb, 2 * nb, n)
+    elif case in ("five_pct", "most_hit"):
+        share = 0.05 if case == "five_pct" else 0.7
+        k = np.where(r.random(n) < share, r.integers(0, nb, n),
+                     r.integers(nb, 2 * nb, n))
+    else:                       # null_keys, left
+        k = r.integers(0, 2 * nb, n)
+        how = "left" if case == "left" else "inner"
+    left = pd.DataFrame({"k": k * spread, "v": r.normal(size=n),
+                         "w": r.integers(-9, 9, n).astype(np.int32)})
+    if case == "null_keys":
+        left["k"] = left["k"].astype("Int64").mask(r.random(n) < 0.2)
+    return left, right, how
+
+
+@pytest.mark.parametrize("case", ["all_hit", "none_hit", "five_pct",
+                                  "most_hit", "null_keys", "left"])
+@pytest.mark.parametrize("route", ["dense", "hash"])
+def test_lut_join_emits_at_result_size(one_dev, route, case):
+    from bodo_tpu.plan import fusion
+    from bodo_tpu.table.table import round_capacity
+    left, right, how = _emit_tables(route, case)
+    tl, tr = Table.from_pandas(left), Table.from_pandas(right)
+    before = fusion.stats()
+    out = R.join_tables(tl, tr, ["k"], ["k"], how, null_equal=False)
+    after = fusion.stats()
+    delta = {k: after[k] - before[k] for k in
+             ("join_dense", "join_hash", "join_sort", "join_emit",
+              "join_emit_skipped")}
+    want_route = {"join_dense": 0, "join_hash": 0, "join_sort": 0,
+                  "join_" + route: 1}
+    assert {k: delta[k] for k in want_route} == want_route
+    exp = left.merge(right, on="k", how=how)
+    if case == "all_hit":
+        # every probe row found its key: nothing is compacted
+        assert (delta["join_emit"], delta["join_emit_skipped"]) == (0, 1)
+        assert out.capacity == tl.capacity
+    elif case == "left":
+        # a left join keeps every probe row: no emit either way
+        assert (delta["join_emit"], delta["join_emit_skipped"]) == (0, 0)
+        assert out.capacity == tl.capacity
+    else:
+        # the result is born at the capacity `rebucket` would leave it:
+        # the probe table's while most rows hit (one program shape
+        # whatever the count), that of its rows below the threshold
+        assert (delta["join_emit"], delta["join_emit_skipped"]) == (1, 0)
+        assert out.capacity == R.rebucket_capacity(len(exp), tl.capacity)
+        assert out.capacity == (
+            tl.capacity if case == "most_hit"
+            else round_capacity(max(len(exp), 1)))
+    assert out.nrows == len(exp)
+    if case == "none_hit":
+        assert out.nrows == 0
+    got = out.to_pandas()
+    assert list(got.columns) == list(exp.columns)
+    # row for row: the compaction is stable, as pandas' merge is
+    assert got["k"].astype("float64").tolist() == \
+        exp["k"].astype("float64").tolist()
+    np.testing.assert_array_equal(got["v"].to_numpy(), exp["v"].to_numpy())
+    assert got["w"].tolist() == exp["w"].tolist()
+    assert got["name"].fillna("<NA>").tolist() == \
+        exp["name"].fillna("<NA>").tolist()
+    np.testing.assert_array_equal(got["z"].to_numpy(dtype="float64"),
+                                  exp["z"].to_numpy(dtype="float64"))

@@ -575,7 +575,8 @@ def _groupby_hashed_agg(arrays, seg, group_row, ok,
     gkeys = tuple(data[grs][:ng_cap] for data, valid in keys)
 
     # MXU route: f32 sums/counts/means via one fused one-hot matmul
-    mxu = ((PK.use_pallas() or PK.FORCE_INTERPRET)
+    # (a key-only group-by, a DISTINCT, has nothing to accumulate)
+    mxu = ((PK.use_pallas() or PK.FORCE_INTERPRET) and bool(specs)
            and ng_cap <= PK.MAX_MATMUL_SLOTS and cap <= (1 << 24)
            and all(op in ("sum", "count", "size", "mean")
                    for op in specs)

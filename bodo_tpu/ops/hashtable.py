@@ -61,7 +61,11 @@ def encode_columns(key_arrays: Sequence[Tuple], null_equal: bool = True):
     get a dedicated extra 0/1 code column (null == null, and no real
     value can collide with the null group). When not `null_equal`,
     null-keyed rows are excluded via `ok` (pandas groupby dropna /
-    SQL join semantics)."""
+    SQL join semantics).
+
+    A float64 column's code is its bits, which the v5e's compiler
+    refuses (`sort_encoding.encode_value`): `relational.groupby_agg`
+    sends a key list that holds one to the sort route instead."""
     codes = []
     ok = None
     for data, valid in key_arrays:

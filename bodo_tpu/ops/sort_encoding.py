@@ -35,7 +35,16 @@ _SIGN64 = np.uint64(0x8000000000000000)
 
 def encode_value(data, ascending: bool = True):
     """uint64 encoding of values; unsigned order == logical order.
-    Exact (bijective) — no range clamping."""
+    Exact (bijective) — no range clamping.
+
+    Not for a float64 on the v5e: the code is the double's IEEE bits,
+    and the TPU compiler cannot bitcast a float64 (it holds one as two
+    floats: `UNIMPLEMENTED ... X64 element types ... bitcast-convert`).
+    Sort keys go through `encode_field` instead, and
+    `relational.groupby_agg` keeps a float64 group key off the hashed
+    route, whose codes these are (`hashtable.encode_columns`); float64
+    join keys and median / nunique / mode over float64 still come
+    here and do not compile there."""
     dt = data.dtype
     if jnp.issubdtype(dt, jnp.floating):
         data = data + jnp.zeros((), dt)  # -0.0 -> +0.0 (equal keys, one code)

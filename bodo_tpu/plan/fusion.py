@@ -41,6 +41,14 @@ This module implements the plan-level version of that inversion:
                     otherwise. The `fused_group` span carries the
                     route as `dense_route`; `stats()` counts by it.
 
+  route spans       a join and a group-by each open one span around
+                    the realisation they took, named for it
+                    (`join_route`: `join.dense|hash|sort|fused`;
+                    `groupby_route`: `groupby.dense|packed|hashed|
+                    sort|fused`), and `stats()` counts the same under
+                    `join_<route>` / `groupby_<route>`. A trace's
+                    readers see names only, so the route is the name.
+
   sharding          derived from the shardcheck REP/DIST lattice
                     (`analysis/plan_validator.check_fusion_boundary`
                     cross-checks the runtime input against it): REP
@@ -171,6 +179,9 @@ _stats = {"groups_planned": 0, "groups_executed": 0, "stream_chains": 0,
           # inner LUT joins by how their result was emitted
           # (`join_emitted`): compacted at its own size, or not at all
           "join_emit": 0, "join_emit_skipped": 0,
+          # group-bys by the realisation they took (`groupby_route`)
+          "groupby_dense": 0, "groupby_packed": 0, "groupby_hashed": 0,
+          "groupby_sort": 0, "groupby_fused": 0,
           # scan batches entering fused chains straight off the device
           # decode path (io/device_decode.py) — no host round-trip
           # between ingest and the compiled chain body
@@ -227,6 +238,26 @@ def join_route(route: str, keys: int, rows_left: int, rows_right: int):
     _stats["join_" + route] += 1
     return tracing.event("join." + route, keys=keys, rows_left=rows_left,
                          rows_right=rows_right)
+
+
+def groupby_route(route: str, keys: int, rows_in: int, slots: int = 0,
+                  **args):
+    """The span a group-by opens around the realisation it took, once
+    the route is settled: `groupby.dense` (one slot a key combination,
+    `relational._groupby_agg_dense`), `groupby.packed` (the keys packed
+    into one int64; the group-by over that key opens a span of its own
+    inside), `groupby.hashed` (the scatter-claim table; a claim that
+    comes back unresolved, the pathological case, leaves its span
+    behind the sort's), `groupby.sort` (`groupby_local`: every row
+    sorted by the keys) on a replicated table, or `groupby.fused` (the
+    terminal aggregate of a fused group, `_run_fused_agg`). `slots` is
+    the slot space of a dense realisation, 0 elsewhere; `args` ride
+    along (the `dense_route` of a dense or fused one). As with
+    `join_route` the route is in the name, and `stats()` counts the
+    same under `groupby_<route>`."""
+    _stats["groupby_" + route] += 1
+    return tracing.event("groupby." + route, keys=keys, rows_in=rows_in,
+                         slots=slots, **args)
 
 
 def join_emitted(rows_out: int, skipped: bool) -> None:
@@ -913,10 +944,12 @@ def _run_fused_agg(t: Table, group: FusionGroup, donate: bool):
     from bodo_tpu.runtime import memory_governor as _mg
     t0 = _time.perf_counter()
     try:
-        with _mg.preadmission_charge(f"fused:{fp}"):
+        with groupby_route("fused", len(kn), t.nrows, n_slots,
+                           dense_route=route), \
+                _mg.preadmission_charge(f"fused:{fp}"):
             out_keys, out_vals, ng = fn(t.device_data(),
                                         jnp.asarray(t.nrows))
-        nrows = int(jax.device_get(ng))
+            nrows = int(jax.device_get(ng))
     except Exception as e:  # noqa: BLE001 - classified below
         from bodo_tpu.runtime import resilience
         from bodo_tpu.runtime.memory_governor import governor

@@ -34,7 +34,8 @@ from bodo_tpu.parallel import mesh as mesh_mod
 from bodo_tpu.parallel.shuffle import (_mesh_key, _MESHES, groupby_sharded,
                                        shuffle_rows)
 from bodo_tpu.plan.expr import Expr, eval_expr, infer_dtype
-from bodo_tpu.plan.fusion import fusion_stage, join_emitted, join_route
+from bodo_tpu.plan.fusion import (fusion_stage, groupby_route, join_emitted,
+                                  join_route)
 from bodo_tpu.table import dtypes as dt
 from bodo_tpu.table.dict_utils import unify_dictionaries
 from bodo_tpu.table.table import Column, ONED, REP, Table, round_capacity
@@ -704,11 +705,28 @@ def groupby_agg(t: Table, keys: Sequence[str],
     """Group by `keys`; aggs = [(value_col, op, out_name)].
     Output sorted by keys ascending (pandas sort=True).
 
-    When every key has a small host-known range (ints/bools/dict codes),
-    the keys pack into one int64 — a single-operand sort replaces the
-    multi-operand lexicographic sort and the shuffle moves one key
-    column (the reference gets a similar effect from its categorical/
-    sorted-key exscan strategies, bodo/libs/groupby/)."""
+    Which keys take which realisation on a replicated table, first
+    that fits (each opens its `fusion.groupby_route` span):
+      dense   every key has a host-known range (ints, bools, dict
+              codes) and the product of the ranges fits the slot
+              budget: one slot a key combination, no sort
+      packed  such keys whose product is too wide for slots but fits
+              62 bits pack into one int64, and the group-by runs again
+              on that one key — a single-operand sort replaces the
+              multi-operand lexicographic sort and the shuffle moves
+              one key column (the reference gets a similar effect from
+              its categorical/sorted-key exscan strategies,
+              bodo/libs/groupby/)
+      hashed  any other key list without a float64 key, with HASH_OPS
+              aggregates: the scatter-claim table, no row sort
+      sort    the rest, and every key list that holds a float64: the
+              hashed route's codes are the key's IEEE bits
+              (`hashtable.encode_columns`), a bitcast the TPU compiler
+              refuses for float64 (it holds a double as two floats),
+              while the sort compares a float64 natively
+              (`sort_encoding.encode_field`). The gate is the same on
+              every platform, so the CPU tests run the path the chip
+              runs. NaN keys are dropped and -0.0 == 0.0 either way."""
     _inject_collective(t, op="groupby_agg")
     keys = list(keys)
     # normalize op aliases: median/quantile_<q> → the "q:<q>" kernel op
@@ -771,20 +789,29 @@ def groupby_agg(t: Table, keys: Sequence[str],
     pack = _pack_plan(t, keys, 62,
                       ranges=None if inexact else ranges)
     if pack is not None:
-        return _groupby_agg_packed(t, keys, list(aggs), pack)
+        with groupby_route("packed", len(keys), t.nrows):
+            return _groupby_agg_packed(t, keys, list(aggs), pack)
     specs = tuple(op for _, op, _ in aggs)
     val_names = [c for c, _, _ in aggs]
-    arrays = tuple((t.column(k).data, t.column(k).valid) for k in keys) + \
-        tuple((t.column(c).data, t.column(c).valid) for c in val_names)
+
+    def _arrays(t: Table):
+        return tuple((t.column(n).data, t.column(n).valid)
+                     for n in keys + val_names)
+    arrays = _arrays(t)
 
     # arbitrary-cardinality hash path (scatter-claim table): no row
     # sort; only the group table is sorted. Falls back to the sort
     # kernel on probe-round exhaustion (pathological keys).
+    # Not for a float64 key: its code would be the double's bits, which
+    # the TPU compiler cannot take (the docstring says who sorts it).
     from bodo_tpu.ops.groupby import HASH_OPS, groupby_local_hashed
     if (t.distribution == REP and keys and config.hash_groupby
-            and all(op in HASH_OPS for op in specs)):
-        out_keys, out_vals, ng, unresolved = groupby_local_hashed(
-            arrays, jnp.asarray(t.nrows), specs, t.capacity, len(keys))
+            and all(op in HASH_OPS for op in specs)
+            and not any(t.column(k).data.dtype == np.float64
+                        for k in keys)):
+        with groupby_route("hashed", len(keys), t.nrows):
+            out_keys, out_vals, ng, unresolved = groupby_local_hashed(
+                arrays, jnp.asarray(t.nrows), specs, t.capacity, len(keys))
         if not unresolved:
             cols: Dict[str, Column] = {}
             for kname, (kd, kv) in zip(keys, out_keys):
@@ -795,10 +822,11 @@ def groupby_agg(t: Table, keys: Sequence[str],
                 cols[oname] = _agg_out_col(t.column(cname), op, vd, vv)
             return shrink_to_fit(Table(cols, ng, REP, None))
 
+    # the row sort is sized to the rows, not to the capacity a join or a
+    # filter left behind (Q18: 490 rows in lineitem's six million slots)
+    t = shrink_to_fit(t.select(list(dict.fromkeys(keys + val_names))))
+    arrays = _arrays(t)
     if t.distribution == ONED:
-        t = shrink_to_fit(t)
-        arrays = tuple((t.column(k).data, t.column(k).valid) for k in keys) + \
-            tuple((t.column(c).data, t.column(c).valid) for c in val_names)
         # bucket/final capacities are sized by the host from stage-1
         # partial counts (with overflow retry) inside groupby_sharded
         (out_keys, out_vals), ngs, ovf = groupby_sharded(
@@ -806,10 +834,11 @@ def groupby_agg(t: Table, keys: Sequence[str],
         counts = np.asarray(jax.device_get(ngs)).reshape(-1).astype(np.int64)
         nrows, dist = int(counts.sum()), ONED
     else:
-        out_keys, out_vals, ng = groupby_local(
-            arrays, jnp.asarray(t.nrows), specs, t.capacity, len(keys))
+        with groupby_route("sort", len(keys), t.nrows):
+            out_keys, out_vals, ng = groupby_local(
+                arrays, jnp.asarray(t.nrows), specs, t.capacity, len(keys))
+            nrows = int(ng)
         counts, dist = None, REP
-        nrows = int(ng)
 
     cols: Dict[str, Column] = {}
     for kname, (kd, kv) in zip(keys, out_keys):
@@ -1198,8 +1227,11 @@ def _groupby_agg_dense(t: Table, keys, aggs, ranges) -> Table:
         fn = named_jit("groupby_dense", body)
         _jit_cache[key] = fn
 
-    out_keys, out_vals, ng = fn(tsel.device_data(), jnp.asarray(t.nrows))
-    nrows = int(jax.device_get(ng))
+    with groupby_route("dense", len(keys), t.nrows, n_slots,
+                       dense_route=route):
+        out_keys, out_vals, ng = fn(tsel.device_data(),
+                                    jnp.asarray(t.nrows))
+        nrows = int(jax.device_get(ng))
     cols: Dict[str, Column] = {}
     for kname, kd in zip(keys, out_keys):
         src = t.column(kname)

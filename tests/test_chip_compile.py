@@ -1,7 +1,8 @@
 """Ask the v5e's compiler, without a chip: every Pallas kernel in
 ops/pallas_kernels.py at the shapes chip_smoke.py gives it, the
-multi-key sort, and the dense aggregate tail at TPC-H Q1's shape,
-compiled for a described `v5e:2x2` device.
+multi-key sort, the dense aggregate tail at TPC-H Q1's shape, and the
+group-by of TPC-H Q18's float64 key, compiled for a described `v5e:2x2`
+device.
 
 Nothing runs, so this says nothing about results or speed; it catches
 what interpret mode cannot (Mosaic refusing an op or a layout, a program
@@ -200,3 +201,43 @@ def test_dense_reduce_tail_compiles_for_v5e(shape):
     temp = compiled(widest).memory_analysis().temp_size_in_bytes
     assert temp < (512 << 20) < n * widest * 8 // 32, \
         f"{temp >> 20} MiB of temporaries at {widest} slots"
+
+
+def test_float64_group_key_compiles_for_v5e(shape):
+    """TPC-H Q18's last group-by: a float64 key (`o_totalprice`) beside
+    int64 ones and a string's codes. The hashed route's codes are the
+    key's bits, a bitcast the TPU compiler refuses for float64, so
+    `relational.groupby_agg` sends such a key list to the sort route,
+    whose program compiles. Both halves are asked here: the route the
+    gate takes (on this CPU, the same on every platform), and the two
+    programs against the described chip."""
+    import numpy as np
+    import pandas as pd
+
+    from bodo_tpu import Table
+    from bodo_tpu import relational as R
+    from bodo_tpu.ops import groupby as G
+    from bodo_tpu.plan import fusion
+
+    df = pd.DataFrame({"k": np.arange(40, dtype=np.int64) % 5,
+                       "p": (np.arange(40) % 3) * 0.25,
+                       "v": np.arange(40.0)})
+    before = fusion.stats()
+    R.groupby_agg(Table.from_pandas(df), ["k", "p"], [("v", "sum", "s")])
+    after = fusion.stats()
+    assert after["groupby_sort"] == before["groupby_sort"] + 1
+    assert after["groupby_hashed"] == before["groupby_hashed"]
+
+    n = 512     # 57 large orders of seven lines, rounded to 128
+    keys = ((shape((n,), jnp.int32), None), (shape((n,), jnp.int64), None),
+            (shape((n,), jnp.int64), None), (shape((n,), jnp.int64), None),
+            (shape((n,), jnp.float64), None))
+    arrays = keys + ((shape((n,), jnp.float64), None),)
+    fn = jax.jit(G.groupby_local.__wrapped__,
+                 static_argnames=("specs", "out_capacity", "num_keys"))
+    fn.lower(arrays, shape((), jnp.int64), specs=("sumnull",),
+             out_capacity=n, num_keys=len(keys)).compile()
+    # what the gate keeps a float64 key away from
+    claim = jax.jit(G._groupby_hashed_claim.__wrapped__)
+    with pytest.raises(Exception, match="X64 element types"):
+        claim.lower(keys, shape((), jnp.int64)).compile()

@@ -48,6 +48,10 @@ This module implements the plan-level version of that inversion:
                     sort|fused`), and `stats()` counts the same under
                     `join_<route>` / `groupby_<route>`. A trace's
                     readers see names only, so the route is the name.
+                    A LUT join built on its left side says so in the
+                    span's argument `build` (`join_build_left`), and
+                    a build side refused unbuilt because its keys must
+                    repeat is counted (`join_build_skipped`).
 
   sharding          derived from the shardcheck REP/DIST lattice
                     (`analysis/plan_validator.check_fusion_boundary`
@@ -176,6 +180,10 @@ _stats = {"groups_planned": 0, "groups_executed": 0, "stream_chains": 0,
           "dense_reduce": 0, "dense_scatter": 0, "dense_mxu": 0,
           # joins by the realisation they took (`join_route`)
           "join_dense": 0, "join_hash": 0, "join_sort": 0, "join_fused": 0,
+          # inner joins realised with the sides exchanged (the LUT built
+          # on the left), and builds `relational.keys_must_repeat`
+          # refused before any program ran
+          "join_build_left": 0, "join_build_skipped": 0,
           # inner LUT joins by how their result was emitted
           # (`join_emitted`): compacted at its own size, or not at all
           "join_emit": 0, "join_emit_skipped": 0,
@@ -225,7 +233,8 @@ def stats() -> dict:
     return out
 
 
-def join_route(route: str, keys: int, rows_left: int, rows_right: int):
+def join_route(route: str, keys: int, rows_left: int, rows_right: int,
+               build_left: bool = False):
     """The span a join opens around the realisation it took, once the
     route is settled (a try that gives up at its build opens none, so a
     join has one; a hash or fused probe that comes back unresolved, the
@@ -234,10 +243,17 @@ def join_route(route: str, keys: int, rows_left: int, rows_right: int):
     its sorts, replicated, sharded or broadcast) or `join.fused` (the
     probe inside a fused join group's program). A trace's readers see a
     span's name only, so the route is in the name; `stats()` counts the
-    same under `join_<route>`."""
+    same under `join_<route>`. `rows_left` and `rows_right` are the
+    join's own sides whichever was built on. A LUT route builds on the
+    right and probes with the left; `build_left` says that
+    `relational.join_tables` exchanged them for an inner join (LUT on
+    the left, probed by the right): the span then carries the argument
+    `build="left"` and `stats()` counts it under `join_build_left`."""
     _stats["join_" + route] += 1
+    _stats["join_build_left"] += build_left
     return tracing.event("join." + route, keys=keys, rows_left=rows_left,
-                         rows_right=rows_right)
+                         rows_right=rows_right,
+                         **({"build": "left"} if build_left else {}))
 
 
 def groupby_route(route: str, keys: int, rows_in: int, slots: int = 0,
@@ -267,6 +283,13 @@ def join_emitted(rows_out: int, skipped: bool) -> None:
     compaction was skipped (`join_emit_skipped`)."""
     _stats["join_emit_skipped" if skipped else "join_emit"] += 1
     tracing.annotate(rows_out=rows_out)
+
+
+def join_build_skipped() -> None:
+    """A build side that `relational.keys_must_repeat` refused: more
+    rows than its keys can take values, so neither LUT was built on it
+    and no program ran to find the duplicates."""
+    _stats["join_build_skipped"] += 1
 
 
 def reset_stats() -> None:

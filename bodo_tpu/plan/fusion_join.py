@@ -24,7 +24,12 @@ boundaries:
                     (`build_hash_table`); repeat probes — streaming
                     batches against one build, a build subplan shared
                     by several joins, repeated queries — skip the
-                    build entirely. The per-node hash join
+                    build entirely. A build side with more rows than
+                    its keys' sources have values
+                    (`relational.keys_must_repeat` on the planner's
+                    `stats.key_ndv_bound`) is not built at all: the
+                    group falls back as it would after finding the
+                    duplicates. The per-node hash join
                     (relational._join_hash_try) draws from the SAME
                     cache, and every cached LUT is tracked in the
                     device-buffer ledger (xla_observatory) under op
@@ -114,6 +119,7 @@ from bodo_tpu.parallel import mesh as mesh_mod
 from bodo_tpu.plan import expr as E
 from bodo_tpu.plan import fusion as F
 from bodo_tpu.plan import logical as L
+from bodo_tpu.plan import stats as plan_stats
 from bodo_tpu.table import dtypes as dt
 from bodo_tpu.table.table import (Column, ONED, REP, Table,
                                   round_capacity)
@@ -679,6 +685,13 @@ def _run_join_group(t: Table, b: Table, group: JoinGroup) -> Table:
     nk = len(left_on)
     how, null_equal, suffixes = join.how, join.null_equal, join.suffixes
     agg = group.agg
+    if not build_inprogram and R.keys_must_repeat(b, right_on, [
+            plan_stats.key_ndv_bound(join.right, k) for k in right_on]):
+        # more rows than the keys' sources have values: the build would
+        # run, sync and find a key twice. No reduction a query: the
+        # planner keeps a resident column's span on the column (PR 32)
+        F.join_build_skipped()
+        raise F.FusionFallback("duplicate build keys")
 
     fp_sig = ("fusedjoin", F._struct_sig(t), F._struct_sig(b),
               F._steps_sig(group.below), F._steps_sig(group.above),

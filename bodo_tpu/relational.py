@@ -34,7 +34,8 @@ from bodo_tpu.parallel import mesh as mesh_mod
 from bodo_tpu.parallel.shuffle import (_mesh_key, _MESHES, groupby_sharded,
                                        shuffle_rows)
 from bodo_tpu.plan.expr import Expr, eval_expr, infer_dtype
-from bodo_tpu.plan.fusion import (fusion_stage, groupby_route, join_emitted,
+from bodo_tpu.plan.fusion import (fusion_stage, groupby_route,
+                                  join_build_skipped, join_emitted,
                                   join_route)
 from bodo_tpu.table import dtypes as dt
 from bodo_tpu.table.dict_utils import unify_dictionaries
@@ -1354,12 +1355,26 @@ def _suffix_columns(left: Table, right: Table, left_on, right_on,
 def join_tables(left: Table, right: Table, left_on: Sequence[str],
                 right_on: Sequence[str], how: str = "inner",
                 suffixes=("_x", "_y"), null_equal: bool = True) -> Table:
-    """Join (pandas merge analogue). Build side = right.
+    """Join (pandas merge analogue).
     how: inner / left / right / outer / cross (reference join matrix:
     bodo/libs/_hash_join.cpp build_table_outer/probe_table_outer,
     _nested_loop_join_impl.cpp for cross). null_equal=True gives pandas
     merge semantics (NaN keys match each other); SQL passes False (null
-    keys never match, the reference's is_na_equal=false join mode)."""
+    keys never match, the reference's is_na_equal=false join mode).
+
+    Build side: the right, probed by the left, on every route but one.
+    Two replicated tables first try the LUT routes on the right
+    (`_join_lut_try`: dense, then hash; both need unique build keys). A
+    right side that is no LUT, because `keys_must_repeat` says so before
+    anything is built or because its builds found a key twice, sends an
+    inner join with a left side of no more rows to the same two routes
+    with the sides exchanged: the LUT is built on the left and probed by
+    the right (`build="left"` on the route span, `join_build_left` in
+    `fusion.stats()`), and the columns come back left-then-right under
+    the suffixes asked for. Only when the left repeats too
+    (many-to-many) does the join sort (`_join_rep`). The rows of an
+    inner join come in the probe side's order, whichever side that was:
+    no order is promised (`reorder_joins` permutes it as well)."""
     _inject_collective(left, right, op="join_tables")
     left_on, right_on = list(left_on), list(right_on)
     assert how in ("inner", "left", "right", "outer", "cross"), \
@@ -1418,15 +1433,19 @@ def join_tables(left: Table, right: Table, left_on: Sequence[str],
     if left.distribution == REP and right.distribution == ONED:
         left = left.shard()
     if left.distribution == REP and right.distribution == REP:
-        out = _join_dense_try(left, right, left_on, right_on, how, suffixes,
-                              null_equal)
+        out = _join_lut_try(left, right, left_on, right_on, how, suffixes,
+                            null_equal)
         if out is not None:
             return out
-        if left_on:
-            out = _join_hash_try(left, right, left_on, right_on, how,
-                                 suffixes, null_equal)
+        if how == "inner" and left_on and left.nrows <= right.nrows:
+            # the right side is no LUT; an inner join is symmetric, so
+            # the smaller left may be one, probed by the right
+            out = _join_lut_try(right, left, right_on, left_on, how,
+                                (suffixes[1], suffixes[0]), null_equal,
+                                build_left=True)
             if out is not None:
-                return out
+                return _left_then_right(out, left, right, left_on,
+                                        right_on, suffixes)
     from bodo_tpu.plan import adaptive
     if how == "outer" and left.distribution == ONED and \
             right.distribution == REP:
@@ -1454,11 +1473,8 @@ def join_tables(left: Table, right: Table, left_on: Sequence[str],
         # broadcast it, and restore the left-then-right column order
         out = join_tables(right, left, right_on, left_on, "inner",
                           (suffixes[1], suffixes[0]), null_equal)
-        lmap, rmap = _suffix_columns(left, right, left_on, right_on,
-                                     suffixes)
-        names = [lmap[n] for n in left.names] + \
-            [rmap[n] for n in right.names if n in rmap]
-        return out.select([n for n in names if n in out.columns])
+        return _left_then_right(out, left, right, left_on, right_on,
+                                suffixes)
     if left.distribution == ONED and right.distribution == ONED:
         out = adaptive.try_skew_split_join(left, right, left_on, right_on,
                                            how, suffixes, null_equal)
@@ -1475,13 +1491,102 @@ def join_tables(left: Table, right: Table, left_on: Sequence[str],
                          null_equal)
 
 
+def _left_then_right(out: Table, left, right, left_on, right_on,
+                     suffixes) -> Table:
+    """The result of an inner join run with its sides exchanged (and its
+    suffixes with them), in the column order of the join asked for:
+    left's columns, then right's."""
+    lmap, rmap = _suffix_columns(left, right, left_on, right_on, suffixes)
+    names = [lmap[n] for n in left.names] + \
+        [rmap[n] for n in right.names if n in rmap]
+    return out.select([n for n in names if n in out.columns])
+
+
+def _host_span(c: Column) -> Optional[int]:
+    """How many values a column can take by what the host knows of it
+    without touching the device, as `_key_ranges` reads it: a string's
+    dictionary size, a bool's two, the span of an integer's or a date's
+    `vrange`. None without one."""
+    if c.dtype is dt.STRING:
+        return None if c.dictionary is None else max(len(c.dictionary), 1)
+    if c.dtype.kind == "b":
+        return 2
+    if c.vrange is not None and (c.dtype.kind in ("i", "u")
+                                 or c.dtype is dt.DATE):
+        return int(c.vrange[1]) - int(c.vrange[0]) + 1
+    return None
+
+
+def keys_must_repeat(t: Table, keys: Sequence[str],
+                     bounds: Optional[Sequence[Optional[int]]] = None
+                     ) -> bool:
+    """Whether `t` must hold some combination of `keys` twice, decided
+    on the host: every key column has a bound on its distinct values,
+    none carries a `valid` mask (null keys never claim a slot, so they
+    prove nothing), and `t` has more rows than the product of the
+    bounds (the pigeonhole principle). `bounds[i]`, where given, is the
+    caller's bound on `keys[i]` (a range it has just reduced,
+    `plan/stats.key_ndv_bound`); `_host_span` serves the rest. Such a
+    side is no LUT: the dense build and the hash build would each run,
+    sync and refuse, so `_join_dense_try` and
+    `fusion_join._run_join_group` ask here first. A bound need not be
+    tight and a filter below only lowers `nrows`, so an answer of True
+    sends a join only where its builds would have sent it anyway."""
+    if not keys:
+        return False
+    combos = 1
+    for i, k in enumerate(keys):
+        c = t.column(k)
+        if c.valid is not None:
+            return False
+        b = bounds[i] if bounds is not None else None
+        if b is None:
+            b = _host_span(c)
+        if b is None:
+            return False
+        combos *= max(int(b), 1)
+        if combos >= t.nrows:
+            return False
+    return True
+
+
+# `_join_dense_try`'s answer for a build side whose keys must repeat:
+# not a dense LUT and not a hash LUT either (`_join_lut_try`)
+_KEYS_REPEAT = object()
+
+
+def _join_lut_try(left, right, left_on, right_on, how, suffixes,
+                  null_equal: bool = True,
+                  build_left: bool = False) -> Optional[Table]:
+    """The two LUT routes of a replicated equi-join with the LUT built
+    on `right` and probed by `left`: dense, then hash. None when `right`
+    is no LUT (its keys repeat, or neither route applies).
+    `build_left` says the caller exchanged the join's sides (`right`
+    here is the join's left); it rides to the route span."""
+    out = _join_dense_try(left, right, left_on, right_on, how, suffixes,
+                          null_equal, build_left)
+    if out is _KEYS_REPEAT:
+        return None  # the hash build would find the same duplicates
+    if out is None and left_on:
+        out = _join_hash_try(left, right, left_on, right_on, how, suffixes,
+                             null_equal, build_left)
+    return out
+
+
+def _lut_route(route: str, nk: int, probe: Table, build: Table,
+               build_left: bool):
+    """`join_route` for a LUT join, in the join's own left and right."""
+    left, right = (build, probe) if build_left else (probe, build)
+    return join_route(route, nk, left.nrows, right.nrows, build_left)
+
+
 # Dense-LUT join: build sides whose key-range product is at most this
 # many slots (and whose keys are unique) join by perfect-hash gather.
 DENSE_JOIN_MAX_SLOTS = 1 << 22
 
 
 def _join_dense_try(left, right, left_on, right_on, how, suffixes,
-                    null_equal: bool = True) -> Optional[Table]:
+                    null_equal: bool = True, build_left: bool = False):
     """Dense-LUT equi-join: when the build (right) side's keys have a
     small host-known range and are unique, the join is a perfect-hash
     lookup — build scatters row indices into a dense LUT, probe gathers.
@@ -1492,8 +1597,11 @@ def _join_dense_try(left, right, left_on, right_on, how, suffixes,
     reads the count and `_join_emit` gathers the columns at the size of
     the result. The dimension-table fast path of the
     reference's hash join (bodo/libs/_hash_join.cpp build/probe) mapped
-    onto gather/scatter. Returns None when not applicable (caller falls
-    back to the union-segmentation sort join)."""
+    onto gather/scatter. Returns None when not applicable (the caller
+    tries the hash LUT, then the union-segmentation sort join), and
+    `_KEYS_REPEAT` when the ranges reduced for the gate show, before
+    anything is built, that the build side has more rows than its keys
+    have values (`keys_must_repeat`): no LUT of either kind."""
     if how not in ("inner", "left") or right.nrows == 0:
         return None
     if null_equal and \
@@ -1506,6 +1614,10 @@ def _join_dense_try(left, right, left_on, right_on, how, suffixes,
     ranges, inexact = _key_ranges(right, right_on)
     if any(r is None for r in ranges):
         return None
+    if keys_must_repeat(right, right_on,
+                        [int(hi) - int(lo) + 1 for lo, hi in ranges]):
+        join_build_skipped()
+        return _KEYS_REPEAT
 
     def _slots(rs) -> int:
         n = 1
@@ -1589,7 +1701,7 @@ def _join_dense_try(left, right, left_on, right_on, how, suffixes,
         pfn = named_jit("join_probe_dense", pbody)
         _jit_cache[pkey] = pfn
 
-    with join_route("dense", nk, left.nrows, right.nrows):
+    with _lut_route("dense", nk, left, right, build_left):
         pc = jnp.asarray(left.nrows)
         if inner:
             hit, safe, cnt = pfn(pa[:nk], (), lut, pc)
@@ -1604,7 +1716,8 @@ def _join_dense_try(left, right, left_on, right_on, how, suffixes,
 
 
 def _join_hash_try(left, right, left_on, right_on, how, suffixes,
-                   null_equal: bool = True) -> Optional[Table]:
+                   null_equal: bool = True,
+                   build_left: bool = False) -> Optional[Table]:
     """Hash-LUT equi-join: the dense-LUT fast path freed from its
     key-range gate. The build side claims slots in a scatter-claim hash
     table (ops/hashtable.py) — owner IS the LUT — and probe rows follow
@@ -1689,7 +1802,7 @@ def _join_hash_try(left, right, left_on, right_on, how, suffixes,
         pfn = named_jit("join_probe_hash", pbody)
         _jit_cache[pkey] = pfn
 
-    with join_route("hash", nk, left.nrows, right.nrows):
+    with _lut_route("hash", nk, left, right, build_left):
         pc = jnp.asarray(left.nrows)
         if inner:
             hit, safe, cnt, p_unres = pfn(pa[:nk], (), bcodes, owner, pc)

@@ -60,7 +60,9 @@ class Column:
     # value span of a RESIDENT column (plan/stats.key_ndv_bound: the join
     # order's cap on a key's distinct values; 0 = no bound), reduced
     # once and kept here. Of this column only: no constructor of a
-    # derived column passes it on, and no operator's route reads it
+    # derived column passes it on, and no operator's gate reads it (a
+    # fused join group asks `key_ndv_bound` whether its build side's
+    # keys must repeat, which only spares a build that would refuse)
     ndv_bound: Optional[int] = field(default=None, compare=False,
                                      repr=False)
 

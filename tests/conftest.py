@@ -34,6 +34,18 @@ def mesh8():
 
 
 @pytest.fixture
+def one_dev(mesh8):
+    """A mesh of one device for the test, so tables stay replicated."""
+    import jax
+
+    import bodo_tpu
+    old = bodo_tpu.parallel.mesh.get_mesh()
+    bodo_tpu.set_mesh(bodo_tpu.make_mesh(jax.devices()[:1]))
+    yield
+    bodo_tpu.set_mesh(old)
+
+
+@pytest.fixture
 def rng():
     return np.random.default_rng(42)
 

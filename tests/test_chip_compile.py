@@ -97,13 +97,16 @@ def _range(shape, n, s):
 # (id, builder, args): the shapes the smoke's phases hand each kernel —
 # Q1's grouping over SF1 lineitem, one parquet row group of dictionary
 # codes, the taxi merge's probe at 2M rows (larger probes close the
-# gate), one shard's rows of the 20M-row four-chip shuffle — and each
-# kernel's widest slot space
+# gate), one shard's rows of the 20M-row four-chip shuffle — each
+# kernel's widest slot space, and the gather a benchmark cell hands it
 KERNELS = [
     ("groupby_sf1_lineitem", _groupby, (6_001_664, 6, 2)),
     ("groupby_max_slots", _groupby, (1 << 20, 4096, 8)),
     ("gather_rowgroup_dict", _gather, (1 << 20, 4)),
     ("gather_max_slots", _gather, (1 << 20, 4096)),
+    # tpch_q5's lineitem probing the LUT built on its 757 suppliers, a
+    # range of 3,750: the probe side is the fact table since PR 36
+    ("gather_q5_lineitem_probe", _gather, (1_500_032, 3750)),
     ("probe_taxi_2m", _probe, (2_000_000, 512, 4)),
     ("probe_max_slots", _probe, (1 << 20, 4096, 8)),
     ("partition_4_shards", _partition, (5_000_064, 4)),

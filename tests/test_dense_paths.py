@@ -14,17 +14,6 @@ from bodo_tpu import Table
 from bodo_tpu.config import config, set_config
 
 
-@pytest.fixture
-def one_dev(mesh8):
-    import jax
-
-    import bodo_tpu
-    old = bodo_tpu.parallel.mesh.get_mesh()
-    bodo_tpu.set_mesh(bodo_tpu.make_mesh(jax.devices()[:1]))
-    yield
-    bodo_tpu.set_mesh(old)
-
-
 def _df(n=5000, seed=0):
     r = np.random.default_rng(seed)
     df = pd.DataFrame({

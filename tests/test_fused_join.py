@@ -256,7 +256,9 @@ def test_duplicate_build_keys_negative_cached(mesh8):
     import bodo_tpu.pandas_api as bd
     from bodo_tpu.plan import fusion_join, physical
 
-    dup = pd.DataFrame({"k": [1, 1, 2], "dim": [0.1, 0.2, 0.3]})
+    # three rows in a key range of seven: nothing says they repeat before
+    # the build has run (`relational.keys_must_repeat` is false)
+    dup = pd.DataFrame({"k": [1, 1, 7], "dim": [0.1, 0.2, 0.3]})
     bl = bd.from_pandas(_probe_df(nkeys=3))
     br = bd.from_pandas(dup)
 

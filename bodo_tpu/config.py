@@ -97,9 +97,14 @@ class Config:
     hash_join: bool = field(
         default_factory=lambda: _env_bool("BODO_TPU_HASH_JOIN", True)
     )
-    # Broadcast-join threshold: build side smaller than this many rows is
-    # all_gather'd instead of hash-shuffled (analogue of broadcast join,
-    # reference bodo/libs/_shuffle.h:153-210).
+    # Broadcast-join threshold: a sharded build side of at most this many
+    # rows, under a probe of more than four times as many, is replicated
+    # instead of hash-shuffled (analogue of broadcast join, reference
+    # bodo/libs/_shuffle.h:153-210). Replicated means `Table.gather`
+    # today: every column copied device to host, repacked with numpy,
+    # and put back on the default device (no `all_gather`; only a fused
+    # join group gathers a build inside its program,
+    # plan/fusion_join.py). `bodo:exchange.broadcast` times it.
     bcast_join_threshold: int = field(
         default_factory=lambda: _env_int("BODO_TPU_BCAST_JOIN_THRESHOLD", 1 << 20)
     )

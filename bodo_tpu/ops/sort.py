@@ -63,6 +63,12 @@ def _partition_key(keys: Sequence[Tuple], ascending: Sequence[bool],
     concatenation (every row on shard i sorts <= every row on shard i+1).
     """
     data, valid = keys[0]
+    if data.dtype == jnp.float64:
+        # the v5e's compiler cannot bitcast a float64 (`encode_value`),
+        # and a partition key need only never invert an order: rounding
+        # to float32 keeps it, values that round alike go to one shard
+        # together, and the final local sort compares the float64 itself
+        data = data.astype(jnp.float32)
     enc = SE.encode_value(data, ascending[0])
     null = SE.null_flag(data, valid)
     # layout: [2 bits rank][62 bits value] — rank orders nulls/padding

@@ -52,6 +52,12 @@ This module implements the plan-level version of that inversion:
                     span's argument `build` (`join_build_left`), and
                     a build side refused unbuilt because its keys must
                     repeat is counted (`join_build_skipped`).
+                    On a mesh a join also says how its rows crossed
+                    chips (`exchange`): `exchange.shuffle` around the
+                    two `shuffle_by_key` of a join of sharded sides,
+                    `exchange.broadcast` around the replication of a
+                    build side, and the counter `exchange_inprogram`
+                    for a fused group's in-program `all_gather`.
 
   sharding          derived from the shardcheck REP/DIST lattice
                     (`analysis/plan_validator.check_fusion_boundary`
@@ -187,6 +193,11 @@ _stats = {"groups_planned": 0, "groups_executed": 0, "stream_chains": 0,
           # inner LUT joins by how their result was emitted
           # (`join_emitted`): compacted at its own size, or not at all
           "join_emit": 0, "join_emit_skipped": 0,
+          # how a join's rows crossed chips (`exchange`): both sides
+          # hash-shuffled, a build side replicated, or a fused group's
+          # build gathered inside its program (`exchange_inprogram`)
+          "exchange_shuffle": 0, "exchange_broadcast": 0,
+          "exchange_inprogram": 0,
           # group-bys by the realisation they took (`groupby_route`)
           "groupby_dense": 0, "groupby_packed": 0, "groupby_hashed": 0,
           "groupby_sort": 0, "groupby_fused": 0,
@@ -283,6 +294,31 @@ def join_emitted(rows_out: int, skipped: bool) -> None:
     compaction was skipped (`join_emit_skipped`)."""
     _stats["join_emit_skipped" if skipped else "join_emit"] += 1
     tracing.annotate(rows_out=rows_out)
+
+
+def exchange(kind: str, **args):
+    """The span a join on a mesh opens around what moves its rows
+    between chips, counted under `exchange_<kind>`:
+    `exchange.shuffle` (`keys`, `rows_left`, `rows_right`) around the
+    pair of `shuffle_by_key` calls that co-locate equal keys of two
+    sharded sides, `exchange.broadcast` (`rows`) around the replication
+    of a build side (`Table.gather` of a sharded one: device to host, a
+    numpy repack, host to device; nothing to do for a side that is
+    replicated already, whose span says only that it is). A fused join
+    group that gathers its build inside the program (`lax.all_gather`
+    under `shard_map`) has no host span of its own: it counts
+    `exchange_inprogram` (`exchange_inprogram()`) and says so on the
+    group's span."""
+    _stats["exchange_" + kind] += 1
+    return tracing.event("exchange." + kind, **args)
+
+
+def exchange_inprogram(rows: int) -> None:
+    """A fused join group whose build side crosses chips by
+    `lax.all_gather` inside its program: counted, and written as the
+    arguments `exchange` and `build_rows` of the group's span."""
+    _stats["exchange_inprogram"] += 1
+    tracing.annotate(exchange="inprogram", build_rows=rows)
 
 
 def join_build_skipped() -> None:

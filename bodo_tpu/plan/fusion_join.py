@@ -673,7 +673,8 @@ def _run_join_group(t: Table, b: Table, group: JoinGroup) -> Table:
         # builds the claim table as replicated compute
         from bodo_tpu.plan import adaptive
         if adaptive.join_broadcast_decision(b, t):
-            b = b.gather()
+            with F.exchange("broadcast", rows=b.nrows):
+                b = b.gather()
         elif t.distribution == ONED and t.num_shards > 1:
             build_inprogram = True
     if b.distribution != REP and not build_inprogram:
@@ -833,6 +834,7 @@ def _run_join_group(t: Table, b: Table, group: JoinGroup) -> Table:
             setattr(out, attr, getattr(chained, attr, False))
     if build_inprogram:
         _stats["build_gather_inprogram"] += 1
+        F.exchange_inprogram(b.nrows)
     return out
 
 

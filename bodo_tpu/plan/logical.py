@@ -116,7 +116,7 @@ class FromPandas(Node):
     """In-memory source (bd.from_pandas analogue, reference base.py:74)."""
     _counter = [0]
 
-    def __init__(self, df):
+    def __init__(self, df, source: Optional["FromPandas"] = None):
         from bodo_tpu.table.table import Table
         self.children = []
         if isinstance(df, Table):
@@ -126,6 +126,13 @@ class FromPandas(Node):
         self.schema = {n: c.dtype for n, c in self.table.columns.items()}
         FromPandas._counter[0] += 1
         self._id = FromPandas._counter[0]
+        # a copy cut to a query's columns (`optimizer.prune_columns`,
+        # a new node every query) names the node it was cut from, which
+        # keeps the columns the executor has sharded for a mesh
+        # (`physical._place_source`): a registered table is scattered
+        # once, not once a query
+        self.source = source.source if source is not None else self
+        self.placed: dict = {}
 
     def key(self):
         return ("from_pandas", self._id)

@@ -239,8 +239,7 @@ def prune_columns(node: L.Node, required: Optional[Set[str]]) -> L.Node:
             cols = [n for n in node.schema if n in required]
             if not cols:
                 cols = [next(iter(node.schema))]
-            pruned = L.FromPandas(node.table.select(cols))
-            return pruned
+            return L.FromPandas(node.table.select(cols), source=node)
         return node
     if isinstance(node, L.Projection):
         exprs = node.exprs if required is None else \

@@ -98,13 +98,40 @@ def _maybe_shard(t: Table) -> Table:
     """Scan distribution policy: shard large sources over the mesh; keep
     small ones replicated so joins against them broadcast instead of
     shuffling (the reference's broadcast-join size heuristic)."""
-    if t.distribution == ONED:
+    return t.shard() if _shards_source(t) else t
+
+
+def _shards_source(t: Table) -> bool:
+    return t.distribution != ONED and \
+        not getattr(_degrade_tls, "force_rep", False) and \
+        t.nrows >= config.shard_min_rows and mesh_mod.num_shards() > 1
+
+
+def _place_source(node: L.FromPandas) -> Table:
+    """`_maybe_shard` for a registered table, scattered once: the
+    columns sharded for a mesh are kept on the node the table was
+    registered under (`FromPandas.source`), beside the replicated
+    column each came from, and a copy cut to a query's columns reads
+    them there and shards only what no query has read yet. The
+    replicated original stays where registration put it (one copy, on
+    the default device): the planner's statistics, a degraded re-run
+    and another mesh read it."""
+    t = node.table
+    if not _shards_source(t):
         return t
-    if getattr(_degrade_tls, "force_rep", False):
-        return t
-    if t.nrows >= config.shard_min_rows and mesh_mod.num_shards() > 1:
-        return t.shard()
-    return t
+    held = node.source.placed
+    m = mesh_mod.get_mesh()
+    if held.get("mesh") != m:
+        held.update(mesh=m, columns={}, counts=None)
+    cols = held["columns"]
+    missing = [n for n, c in t.columns.items()
+               if n not in cols or cols[n][0] is not c]
+    if missing:
+        s = t.select(missing).shard()
+        cols.update({n: (t.columns[n], s.columns[n]) for n in missing})
+        held["counts"] = s.counts
+    return Table({n: cols[n][1] for n in t.names}, t.nrows, ONED,
+                 held["counts"])
 
 
 def _exec(node: L.Node) -> Table:
@@ -384,7 +411,7 @@ def _exec_inner(node: L.Node) -> Table:
             node.path, columns=node.columns,
             parse_dates=list(node.parse_dates) or None))
     if isinstance(node, L.FromPandas):
-        return _maybe_shard(node.table)
+        return _place_source(node)
     if isinstance(node, L.ViewScan):
         from bodo_tpu.runtime import views as _views
         return _maybe_shard(_views.materialized_table(node.name))

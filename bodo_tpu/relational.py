@@ -34,7 +34,7 @@ from bodo_tpu.parallel import mesh as mesh_mod
 from bodo_tpu.parallel.shuffle import (_mesh_key, _MESHES, groupby_sharded,
                                        shuffle_rows)
 from bodo_tpu.plan.expr import Expr, eval_expr, infer_dtype
-from bodo_tpu.plan.fusion import (fusion_stage, groupby_route,
+from bodo_tpu.plan.fusion import (exchange, fusion_stage, groupby_route,
                                   join_build_skipped, join_emitted,
                                   join_route)
 from bodo_tpu.table import dtypes as dt
@@ -1430,7 +1430,17 @@ def join_tables(left: Table, right: Table, left_on: Sequence[str],
         left = ll
     if rl is not None:
         right = rl
+    from bodo_tpu.plan import adaptive
     if left.distribution == REP and right.distribution == ONED:
+        if how == "inner" and \
+                adaptive.join_broadcast_decision(left, right):
+            # the mirror case below with its answer known: a small
+            # replicated left under a sharded right is the build side as
+            # it stands, so it is not scattered to be gathered again
+            out = join_tables(right, left, right_on, left_on, "inner",
+                              (suffixes[1], suffixes[0]), null_equal)
+            return _left_then_right(out, left, right, left_on, right_on,
+                                    suffixes)
         left = left.shard()
     if left.distribution == REP and right.distribution == REP:
         out = _join_lut_try(left, right, left_on, right_on, how, suffixes,
@@ -1446,17 +1456,21 @@ def join_tables(left: Table, right: Table, left_on: Sequence[str],
             if out is not None:
                 return _left_then_right(out, left, right, left_on,
                                         right_on, suffixes)
-    from bodo_tpu.plan import adaptive
     if how == "outer" and left.distribution == ONED and \
             right.distribution == REP:
         # a replicated build side would emit its unmatched rows once PER
         # SHARD; shard it so every build row is owned by exactly one shard
         right = right.shard()
-    if left.distribution == ONED and right.distribution == REP and \
-            adaptive.should_demote_broadcast(right):
-        # AQE demotion: the planned broadcast's observed build side
-        # blows the governor budget — shard it and shuffle instead
-        right = right.shard()
+    if left.distribution == ONED and right.distribution == REP:
+        if adaptive.should_demote_broadcast(right):
+            # AQE demotion: the planned broadcast's observed build side
+            # blows the governor budget — shard it and shuffle instead
+            right = right.shard()
+        else:
+            # a build side that is replicated as it came: nothing to
+            # move on the host, the span only says which way
+            with exchange("broadcast", rows=right.nrows):
+                pass
     if how != "outer" and \
             left.distribution == ONED and right.distribution == ONED and \
             adaptive.join_broadcast_decision(right, left):
@@ -1465,7 +1479,8 @@ def join_tables(left: Table, right: Table, left_on: Sequence[str],
         # big probe side entirely (reference: broadcast join sizing,
         # bodo/libs/_shuffle.h:153); with AQE on the gate is the build's
         # observed bytes against the governor's derived budget
-        right = right.gather()
+        with exchange("broadcast", rows=right.nrows):
+            right = right.gather()
     elif how == "inner" and left.distribution == ONED and \
             right.distribution == ONED and \
             adaptive.join_broadcast_decision(left, right):
@@ -2015,6 +2030,33 @@ def _build_join_sharded_fn(mesh_key, nk, how, out_cap, broadcast: bool,
     return fn
 
 
+def _build_join_count_sharded_fn(mesh_key, nk, how, broadcast: bool,
+                                 sig_key, null_equal: bool = True,
+                                 method: str = "sort"):
+    """Every shard's exact join output count (`join_count` over the key
+    columns alone), for the retry of `_join_sharded` after an output
+    bucket overflowed."""
+    key = ("join_count", mesh_key, nk, how, sig_key, null_equal, method,
+           broadcast)
+    fn = _jit_cache.get(key)
+    if fn is not None:
+        return fn
+    ax = config.data_axis
+
+    def body(p_arrays, b_arrays, pcounts, bcounts):
+        return join_count(p_arrays[:nk], b_arrays[:nk], pcounts[0],
+                          bcounts[0], nk, how, null_equal,
+                          method)[0][None]
+
+    fn = named_jit("join_count_sharded", C.smap(
+        body,
+        in_specs=(P(ax), P() if broadcast else P(ax), P(ax),
+                  P() if broadcast else P(ax)),
+        out_specs=P(ax), mesh=_MESHES[mesh_key]))
+    _jit_cache[key] = fn
+    return fn
+
+
 def _join_sharded(left, right, left_on, right_on, how, suffixes,
                   broadcast: bool = False,
                   null_equal: bool = True,
@@ -2022,8 +2064,10 @@ def _join_sharded(left, right, left_on, right_on, how, suffixes,
     m = mesh_mod.get_mesh()
     if not broadcast and not pre_shuffled:
         # co-locate equal keys, then join at tight static shapes
-        left = shuffle_by_key(left, left_on)
-        right = shuffle_by_key(right, right_on)
+        with exchange("shuffle", keys=len(left_on), rows_left=left.nrows,
+                      rows_right=right.nrows):
+            left = shuffle_by_key(left, left_on)
+            right = shuffle_by_key(right, right_on)
     left = shrink_to_fit(left)
     lorder, rorder, pa, ba = _probe_build_arrays(left, right, left_on,
                                                  right_on)
@@ -2051,22 +2095,9 @@ def _join_sharded(left, right, left_on, right_on, how, suffixes,
         if not np.asarray(jax.device_get(ovf)).any():
             break
         # exact per-shard counts, then one final right-sized run
-        cfn_key = ("join_count", _mesh_key(m), nk, how, sig_key,
-                   null_equal, method, broadcast)
-        cfn = _jit_cache.get(cfn_key)
-        if cfn is None:
-            ax = config.data_axis
-
-            def cbody(p_arrays, b_arrays, pcounts, bcounts_):
-                return join_count(p_arrays[:nk], b_arrays[:nk], pcounts[0],
-                                  bcounts_[0], nk, how, null_equal,
-                                  method)[0][None]
-            cfn = named_jit("join_count_sharded", C.smap(
-                cbody,
-                in_specs=(P(ax), P() if broadcast else P(ax), P(ax),
-                          P() if broadcast else P(ax)),
-                out_specs=P(ax), mesh=m))
-            _jit_cache[cfn_key] = cfn
+        cfn = _build_join_count_sharded_fn(_mesh_key(m), nk, how,
+                                           broadcast, sig_key, null_equal,
+                                           method)
         exact = np.asarray(jax.device_get(
             cfn(pa, ba, left.counts_device(), bcounts)))
         out_cap = round_capacity(int(exact.max()))
@@ -2878,6 +2909,32 @@ def shrink_to_fit(t: Table) -> Table:
     return t.with_device_data(tree, nrows=t.nrows)
 
 
+def _build_shuffle_fn(mesh_key, nk: int, cap: int, sig_key, has_valid):
+    """The shard_map program of `shuffle_by_key`: the first `nk` arrays
+    are the keys, `cap` a shard's capacity and every bucket's (a shard
+    can receive at most what all hold, so no bucket overflows), and
+    `has_valid` says which arrays carry a null mask."""
+    key = ("shuffle", mesh_key, sig_key, nk, cap)
+    fn = _jit_cache.get(key)
+    if fn is not None:
+        return fn
+    mesh = _MESHES[mesh_key]
+    S = mesh_mod.num_shards(mesh)
+    ax = config.data_axis
+
+    def body(arrs, counts):
+        dest = dest_shard(hash_columns(arrs[:nk]), S)
+        flat, _ = _flatten_with_valids(arrs)
+        out, cnt2, _ = shuffle_rows(dest, flat, counts[0], S, cap, ax)
+        return _rebuild_from_flat(out, has_valid), cnt2[None]
+
+    fn = named_jit("shuffle_by_key", C.smap(
+        body, in_specs=(P(ax), P(ax)), out_specs=(P(ax), P(ax)),
+        mesh=mesh))
+    _jit_cache[key] = fn
+    return fn
+
+
 def shuffle_by_key(t: Table, key_cols: Sequence[str]) -> Table:
     """Hash-partition rows over the mesh by key columns (the standalone
     shuffle_table analogue, reference bodo/libs/_shuffle.h:41). Rows with
@@ -2907,26 +2964,12 @@ def shuffle_by_key(t: Table, key_cols: Sequence[str]) -> Table:
         if ev is not None:
             ev["rows"] = t.nrows
         m = mesh_mod.get_mesh()
-        S = mesh_mod.num_shards(m)
-        ax = config.data_axis
         names = t.names
-        cap = t.shard_capacity
         nk = len(key_cols)
         korder = list(key_cols) + [n for n in names if n not in key_cols]
-        key = ("shuffle", _mesh_key(m), _sig(t.select(korder)), nk, cap)
-        fn = _jit_cache.get(key)
-        if fn is None:
-            def body(arrs, counts):
-                cnt = counts[0]
-                dest = dest_shard(hash_columns(arrs[:nk]), S)
-                flat, _ = _flatten_with_valids(arrs)
-                out, cnt2, _ = shuffle_rows(dest, flat, cnt, S, cap, ax)
-                return _rebuild_from_flat(out, tuple(slots2)), cnt2[None]
-            slots2 = [t.column(n).valid is not None for n in korder]
-            fn = named_jit("shuffle_by_key", C.smap(
-                body, in_specs=(P(ax), P(ax)), out_specs=(P(ax), P(ax)),
-                mesh=m))
-            _jit_cache[key] = fn
+        fn = _build_shuffle_fn(
+            _mesh_key(m), nk, t.shard_capacity, _sig(t.select(korder)),
+            tuple(t.column(n).valid is not None for n in korder))
         karrays = tuple((t.column(n).data, t.column(n).valid)
                         for n in korder)
         out, cnts = fn(karrays, t.counts_device())

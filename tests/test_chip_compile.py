@@ -1,8 +1,10 @@
 """Ask the v5e's compiler, without a chip: every Pallas kernel in
 ops/pallas_kernels.py at the shapes chip_smoke.py gives it, the
-multi-key sort, the dense aggregate tail at TPC-H Q1's shape, and the
-group-by of TPC-H Q18's float64 key, compiled for a described `v5e:2x2`
-device.
+multi-key sort, the dense aggregate tail at TPC-H Q1's shape, the
+group-by of TPC-H Q18's float64 key, and the programs that carry a join
+across the four chips of cell `tpch_q5_4chip` (`shuffle_by_key`,
+`join_sharded` shuffled and broadcast, `join_count_sharded`), compiled
+for a described `v5e:2x2`.
 
 Nothing runs, so this says nothing about results or speed; it catches
 what interpret mode cannot (Mosaic refusing an op or a layout, a program
@@ -244,3 +246,153 @@ def test_float64_group_key_compiles_for_v5e(shape):
     claim = jax.jit(G._groupby_hashed_claim.__wrapped__)
     with pytest.raises(Exception, match="X64 element types"):
         claim.lower(keys, shape((), jnp.int64)).compile()
+
+
+# ------------------------------------------- a join across the four chips
+# `relational.py`'s shard_map programs at a shard's shapes of the two
+# top steps of `tpch_4w`'s ladder (6,000,000 and 3,000,000 orders over
+# four chips), int64 keys. Seconds are this sandbox's with the file run
+# alone (PR 37); a ceiling is about three times its reading.
+ORDERS_TOP, ORDERS_NEXT = 6_000_000, 3_000_000
+
+
+# shuffle_by_key: 50.7 s on the pair, 13.0 s on one key; join_sharded
+# after a shuffle: 120.2 s on the pair, 18.3 s on one key (its count 9.5
+# and 5.7 s); with a replicated build at 6.0M and 3.0M probe rows a
+# shard: 31.8 and 30.3 s (its count 9.2 s). A second 64-bit key costs
+# four to six times the compile of one.
+SHUFFLE_CEILING_S = 150.0
+JOIN_SHUFFLED_CEILING_S = 360.0
+JOIN_BROADCAST_CEILING_S = 100.0
+
+
+def _cap(rows):
+    from bodo_tpu.table.table import round_capacity
+    return round_capacity(-(-rows // 4))
+
+
+@pytest.fixture(scope="module")
+def four_chips(topo):
+    """(mesh key, shapes of row-sharded and replicated arrays) on the
+    four described devices; Pallas gates open as on the chip, since the
+    described device is not `jax.devices()`."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from bodo_tpu import relational as R
+    from bodo_tpu.config import config
+    mesh = Mesh(np.array(topo.devices), (config.data_axis,))
+    row = NamedSharding(mesh, P(config.data_axis))
+    rep = NamedSharding(mesh, P())
+
+    def sharded(n, dtype):
+        return jax.ShapeDtypeStruct((n,), dtype, sharding=row)
+
+    def replicated(n, dtype):
+        return jax.ShapeDtypeStruct((n,), dtype, sharding=rep)
+    old = PK.use_pallas
+    PK.use_pallas = lambda: True
+    yield R._mesh_key(mesh), sharded, replicated
+    PK.use_pallas = old
+
+
+def _timed(lowered, ceiling):
+    t0 = time.perf_counter()
+    compiled = lowered.compile()
+    took = time.perf_counter() - t0
+    assert took < ceiling, f"{took:.0f}s to compile"
+    return compiled
+
+
+def _keyed(make, rows, keys, payload):
+    """`keys` int64 key columns first, then float64 payload columns."""
+    return tuple((make(rows, jnp.int64), None) for _ in range(keys)) + \
+        tuple((make(rows, jnp.float64), None) for _ in range(payload))
+
+
+# a shard of the sharded side of Q5's last join (0.123 rows an order)
+# and of the side it meets (0.10): the pair at the top step, one key at
+# the next
+LAST_JOIN = [("pair_top", 2, _cap(int(0.123 * ORDERS_TOP)),
+              _cap(int(0.10 * ORDERS_TOP))),
+             ("one_key_next", 1, _cap(int(0.123 * ORDERS_NEXT)),
+              _cap(int(0.10 * ORDERS_NEXT)))]
+
+
+@pytest.mark.parametrize("keys,cap", [c[1:3] for c in LAST_JOIN],
+                         ids=[c[0] for c in LAST_JOIN])
+def test_shuffle_by_key_compiles_for_four_v5e(four_chips, keys, cap):
+    """Hash of the 64-bit keys, `partition_rank`, the `all_to_all` of
+    four buckets of a shard's capacity, the compaction of what came."""
+    from bodo_tpu import relational as R
+    mesh_key, sharded, _ = four_chips
+    fn = R._build_shuffle_fn(mesh_key, keys, cap, ("chip_compile", cap),
+                             (False,) * 4)
+    compiled = _timed(fn.lower(_keyed(sharded, 4 * cap, keys, 4 - keys),
+                               sharded(4, jnp.int64)), SHUFFLE_CEILING_S)
+    text = compiled.as_text()
+    assert "all-to-all" in text and "tpu_custom_call" in text
+    # four buckets of `cap` rows a column, sent and received
+    assert compiled.memory_analysis().temp_size_in_bytes < (1 << 30)
+
+
+@pytest.mark.parametrize("keys,cap,bcap", [c[1:] for c in LAST_JOIN],
+                         ids=[c[0] for c in LAST_JOIN])
+def test_join_sharded_compiles_for_four_v5e(four_chips, keys, cap, bcap):
+    """Both sides co-located by the shuffle: `join_local` under
+    `shard_map`, and the exact count its overflow retry asks for."""
+    from bodo_tpu import relational as R
+    mesh_key, sharded, _ = four_chips
+    args = (_keyed(sharded, 4 * cap, keys, 2),
+            _keyed(sharded, 4 * bcap, keys, 1),
+            sharded(4, jnp.int64), sharded(4, jnp.int64))
+    sig = ("chip_compile", cap, bcap)
+    fn = R._build_join_sharded_fn(mesh_key, keys, "inner", 2 * cap, False,
+                                  sig, True, "hash")
+    compiled = _timed(fn.lower(*args), JOIN_SHUFFLED_CEILING_S)
+    assert compiled.memory_analysis().temp_size_in_bytes < (1 << 30)
+    cfn = R._build_join_count_sharded_fn(mesh_key, keys, "inner", False,
+                                         sig, True, "hash")
+    _timed(cfn.lower(*args), JOIN_SHUFFLED_CEILING_S)
+
+
+# a shard of `lineitem` (four lines an order) under the region's
+# suppliers (a fifth of `orders // 100`), replicated: Q5's large join
+BROADCAST = [("lineitem_top", _cap(4 * ORDERS_TOP),
+              _cap(4 * (ORDERS_TOP // 500))),
+             ("lineitem_next", _cap(4 * ORDERS_NEXT),
+              _cap(4 * (ORDERS_NEXT // 500)))]
+
+
+@pytest.mark.parametrize("cap,brows", [c[1:] for c in BROADCAST],
+                         ids=[c[0] for c in BROADCAST])
+def test_join_broadcast_compiles_for_four_v5e(four_chips, cap, brows):
+    from bodo_tpu import relational as R
+    mesh_key, sharded, replicated = four_chips
+    args = (_keyed(sharded, 4 * cap, 1, 2), _keyed(replicated, brows, 1, 1),
+            sharded(4, jnp.int64), replicated(1, jnp.int64))
+    sig = ("chip_compile", cap, brows)
+    fn = R._build_join_sharded_fn(mesh_key, 1, "inner", 2 * cap, True, sig,
+                                  True, "hash")
+    compiled = _timed(fn.lower(*args), JOIN_BROADCAST_CEILING_S)
+    # 6M probe rows a shard: the output at twice the probe's capacity is
+    # the largest thing the program holds
+    assert compiled.memory_analysis().temp_size_in_bytes < (2 << 30)
+    cfn = R._build_join_count_sharded_fn(mesh_key, 1, "inner", True, sig,
+                                         True, "hash")
+    _timed(cfn.lower(*args), JOIN_BROADCAST_CEILING_S)
+
+
+def test_sort_sharded_float64_key_compiles_for_four_v5e(four_chips):
+    """Q5 ends in `order by revenue desc` over the aggregate's five
+    rows, which are sharded on four chips: the sample sort's partition
+    key was the float64's bits, a bitcast the TPU compiler refuses (the
+    first four-chip run of PR 37 failed there, in its first query); it
+    is the key rounded to float32 now."""
+    from bodo_tpu.ops import sort as S4
+    mesh_key, sharded, _ = four_chips
+    cap = 128
+    arrays = ((sharded(4 * cap, jnp.float64), None),
+              (sharded(4 * cap, jnp.int32), None))
+    fn = S4._build_sort_sharded(mesh_key, 2, 1, (False,), True, cap)
+    fn.lower(arrays, sharded(4, jnp.int64)).compile()

@@ -190,6 +190,10 @@ _stats = {"groups_planned": 0, "groups_executed": 0, "stream_chains": 0,
           # on the left), and builds `relational.keys_must_repeat`
           # refused before any program ran
           "join_build_left": 0, "join_build_skipped": 0,
+          # LUT joins (dense or hash) probed by a sharded side, once a
+          # shard under `shard_map`, against a LUT built on a replicated
+          # side
+          "join_lut_sharded": 0,
           # inner LUT joins by how their result was emitted
           # (`join_emitted`): compacted at its own size, or not at all
           "join_emit": 0, "join_emit_skipped": 0,
@@ -245,7 +249,7 @@ def stats() -> dict:
 
 
 def join_route(route: str, keys: int, rows_left: int, rows_right: int,
-               build_left: bool = False):
+               build_left: bool = False, sharded_probe: bool = False):
     """The span a join opens around the realisation it took, once the
     route is settled (a try that gives up at its build opens none, so a
     join has one; a hash or fused probe that comes back unresolved, the
@@ -259,12 +263,21 @@ def join_route(route: str, keys: int, rows_left: int, rows_right: int,
     right and probes with the left; `build_left` says that
     `relational.join_tables` exchanged them for an inner join (LUT on
     the left, probed by the right): the span then carries the argument
-    `build="left"` and `stats()` counts it under `join_build_left`."""
+    `build="left"` and `stats()` counts it under `join_build_left`.
+    `sharded_probe` says that a LUT route's probe side is row-sharded
+    and probes the LUT of a replicated build once a shard under
+    `shard_map`: the argument `probe="sharded"`, counted under
+    `join_lut_sharded`."""
     _stats["join_" + route] += 1
     _stats["join_build_left"] += build_left
+    _stats["join_lut_sharded"] += sharded_probe
+    args = {}
+    if build_left:
+        args["build"] = "left"
+    if sharded_probe:
+        args["probe"] = "sharded"
     return tracing.event("join." + route, keys=keys, rows_left=rows_left,
-                         rows_right=rows_right,
-                         **({"build": "left"} if build_left else {}))
+                         rows_right=rows_right, **args)
 
 
 def groupby_route(route: str, keys: int, rows_in: int, slots: int = 0,

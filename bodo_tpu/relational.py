@@ -1372,9 +1372,15 @@ def join_tables(left: Table, right: Table, left_on: Sequence[str],
     the right (`build="left"` on the route span, `join_build_left` in
     `fusion.stats()`), and the columns come back left-then-right under
     the suffixes asked for. Only when the left repeats too
-    (many-to-many) does the join sort (`_join_rep`). The rows of an
-    inner join come in the probe side's order, whichever side that was:
-    no order is promised (`reorder_joins` permutes it as well)."""
+    (many-to-many) does the join sort (`_join_rep`). A row-sharded left
+    over a replicated right (as it came, or gathered by
+    `exchange.broadcast`) tries the same two LUT routes on the right: the
+    LUT is built once, as for two replicated tables, and probed once a
+    shard under `shard_map` (`probe="sharded"` on the route span,
+    `join_lut_sharded` in `fusion.stats()`); a right side that is no LUT
+    goes down `_join_broadcast`. The rows of an inner join come in the
+    probe side's order, whichever side that was: no order is promised
+    (`reorder_joins` permutes it as well)."""
     _inject_collective(left, right, op="join_tables")
     left_on, right_on = list(left_on), list(right_on)
     assert how in ("inner", "left", "right", "outer", "cross"), \
@@ -1495,6 +1501,11 @@ def join_tables(left: Table, right: Table, left_on: Sequence[str],
                                            how, suffixes, null_equal)
         if out is not None:
             return out
+    if left.distribution == ONED and right.distribution == REP:
+        out = _join_lut_try(left, right, left_on, right_on, how, suffixes,
+                            null_equal)
+        if out is not None:
+            return out
     with join_route("sort", len(left_on), left.nrows, right.nrows):
         if left.distribution == ONED and right.distribution == ONED:
             return _join_sharded(left, right, left_on, right_on, how,
@@ -1573,9 +1584,10 @@ _KEYS_REPEAT = object()
 def _join_lut_try(left, right, left_on, right_on, how, suffixes,
                   null_equal: bool = True,
                   build_left: bool = False) -> Optional[Table]:
-    """The two LUT routes of a replicated equi-join with the LUT built
-    on `right` and probed by `left`: dense, then hash. None when `right`
-    is no LUT (its keys repeat, or neither route applies).
+    """The two LUT routes of an equi-join with the LUT built on `right`
+    (replicated) and probed by `left` (replicated, or row-sharded and
+    probed once a shard): dense, then hash. None when `right` is no LUT
+    (its keys repeat, or neither route applies).
     `build_left` says the caller exchanged the join's sides (`right`
     here is the join's left); it rides to the route span."""
     out = _join_dense_try(left, right, left_on, right_on, how, suffixes,
@@ -1590,9 +1602,41 @@ def _join_lut_try(left, right, left_on, right_on, how, suffixes,
 
 def _lut_route(route: str, nk: int, probe: Table, build: Table,
                build_left: bool):
-    """`join_route` for a LUT join, in the join's own left and right."""
+    """`join_route` for a LUT join, in the join's own left and right;
+    a row-sharded probe side says so (`probe="sharded"`)."""
     left, right = (build, probe) if build_left else (probe, build)
-    return join_route(route, nk, left.nrows, right.nrows, build_left)
+    return join_route(route, nk, left.nrows, right.nrows, build_left,
+                      probe.distribution == ONED)
+
+
+def _probe_mesh_key(probe: Table):
+    """The mesh a LUT join's probe programs run over: None for a
+    replicated probe side, the active mesh's key for a row-sharded one."""
+    if probe.distribution != ONED:
+        return None
+    return _mesh_key(mesh_mod.get_mesh())
+
+
+def _cached_program(key, name: str, body, mesh_key, in_specs):
+    """`body` jitted under its program name and kept in the kernel cache
+    under `key`: as it stands (`mesh_key` None), or once a shard under
+    `shard_map` over the mesh `mesh_key`, its arguments split as
+    `in_specs` says and every output split by rows (a shard's count or
+    flag as its element of a vector of the shards')."""
+    if mesh_key is not None:
+        body = C.smap(body, in_specs=in_specs, out_specs=P(config.data_axis),
+                      mesh=_MESHES[mesh_key])
+    fn = named_jit(name, body)
+    _jit_cache[key] = fn
+    return fn
+
+
+def _result_rows(cnt, sharded: bool):
+    """`(nrows, counts)` of a LUT join's result from its probe's counts
+    as the host read them, one a shard (one in all on a replicated
+    probe, whose `counts` is None)."""
+    counts = np.asarray(cnt).reshape(-1).astype(np.int64)
+    return int(counts.sum()), (counts if sharded else None)
 
 
 # Dense-LUT join: build sides whose key-range product is at most this
@@ -1684,50 +1728,72 @@ def _join_dense_try(left, right, left_on, right_on, how, suffixes,
 
     # the LUT gather is the probe's hot lookup: small LUTs route through
     # the Pallas one-hot MXU gather (values are row indices, bounded by
-    # MAX_GATHER_VALUE so the f32 contraction is exact)
+    # MAX_GATHER_VALUE so the f32 contraction is exact); a row-sharded
+    # probe keeps XLA's gather: the kernel has neither compiled nor run
+    # under `shard_map`, and its padded temporaries grow with a shard's
+    # rows
     from bodo_tpu.ops import pallas_kernels as PK
+    sharded = left.distribution == ONED
     use_gather = ((PK.use_pallas() or PK.FORCE_INTERPRET)
+                  and not sharded
                   and n_slots <= PK.MAX_MATMUL_SLOTS
                   and right.capacity < PK.MAX_GATHER_VALUE)
     inner = how == "inner"
     # an inner probe is handed the key columns alone, and no build array
-    pkey = ("densejoin_probe",
-            _sig(left.select(lorder[:nk] if inner else lorder)),
-            None if inner else _sig(right.select(rorder)),
-            sizes, los, nk, how, use_gather)
-    pfn = _jit_cache.get(pkey)
-    if pfn is None:
-        def pbody(p_arrays, b_arrays, lut, pcount):
-            cap = p_arrays[0][0].shape[0]
-            slot, live = _dense_slots(p_arrays[:nk], los, sizes,
-                                      K.row_mask(pcount, cap),
-                                      strict_range=True)
-            g = PK.matmul_gather(slot, lut) if use_gather else lut[slot]
-            idx = jnp.where(live, g, -1)
-            hit = idx >= 0
-            safe = jnp.maximum(idx, 0)
-            if inner:
-                # touches no column: `_join_emit` gathers them at the
-                # size of the result once the host has read `cnt`
-                return hit, safe, jnp.sum(hit)
-            # left join: keep every probe row; unmatched build cols invalid
-            return tuple(p_arrays), _gather_build(b_arrays, hit, safe), pcount
-
-        pfn = named_jit("join_probe_dense", pbody)
-        _jit_cache[pkey] = pfn
+    pfn = _dense_probe_fn(
+        _probe_mesh_key(left), nk, sizes, los, how, use_gather,
+        (_sig(left.select(lorder[:nk] if inner else lorder)),
+         None if inner else _sig(right.select(rorder))))
 
     with _lut_route("dense", nk, left, right, build_left):
-        pc = jnp.asarray(left.nrows)
+        pc = left.counts_device() if sharded else jnp.asarray([left.nrows])
         if inner:
             hit, safe, cnt = pfn(pa[:nk], (), lut, pc)
             return _join_emit(left, right, left_on, right_on, suffixes,
                               (lorder, rorder, pa, ba), hit, safe,
-                              int(jax.device_get(cnt)))
+                              *_result_rows(jax.device_get(cnt), sharded))
         out_p, out_b, cnt = pfn(pa, ba, lut, pc)
-        nrows = int(jax.device_get(cnt))
+        nrows, counts = _result_rows(jax.device_get(cnt), sharded)
         res = _assemble_join(left, right, left_on, right_on, lorder, rorder,
-                             out_p, out_b, nrows, None, how, suffixes)
+                             out_p, out_b, nrows, counts, how, suffixes)
         return rebucket(res)
+
+
+def _dense_probe_fn(mesh_key, nk, sizes, los, how, use_gather, sig_key):
+    """The dense LUT's probe program, `join_probe_dense`: each live
+    probe row's slot, the LUT's build row there. An inner probe returns
+    its `hit` mask, the build row index `safe` and its count; a left one
+    every probe row with the build columns gathered beside it, and its
+    count. The probe's count comes in, and the result's goes out, as a
+    one-element vector. One body for a replicated probe side and, once a
+    shard under `shard_map` (`mesh_key`), for a row-sharded one."""
+    key = ("densejoin_probe", mesh_key, sig_key, sizes, los, nk, how,
+           use_gather)
+    fn = _jit_cache.get(key)
+    if fn is not None:
+        return fn
+    from bodo_tpu.ops import pallas_kernels as PK
+    inner = how == "inner"
+
+    def pbody(p_arrays, b_arrays, lut, pcount):
+        cap = p_arrays[0][0].shape[0]
+        slot, live = _dense_slots(p_arrays[:nk], los, sizes,
+                                  K.row_mask(pcount[0], cap),
+                                  strict_range=True)
+        g = PK.matmul_gather(slot, lut) if use_gather else lut[slot]
+        idx = jnp.where(live, g, -1)
+        hit = idx >= 0
+        safe = jnp.maximum(idx, 0)
+        if inner:
+            # touches no column: `_join_emit` gathers them at the
+            # size of the result once the host has read `cnt`
+            return hit, safe, jnp.sum(hit)[None]
+        # left join: keep every probe row; unmatched build cols invalid
+        return tuple(p_arrays), _gather_build(b_arrays, hit, safe), pcount
+
+    ax = config.data_axis
+    return _cached_program(key, "join_probe_dense", pbody, mesh_key,
+                           (P(ax), P(), P(), P(ax)))
 
 
 def _join_hash_try(left, right, left_on, right_on, how, suffixes,
@@ -1792,47 +1858,64 @@ def _join_hash_try(left, right, left_on, right_on, how, suffixes,
             return None  # duplicate build keys (or pathological probing)
 
     inner = how == "inner"
-    pkey = ("hashjoin_probe",
-            _sig(left.select(lorder[:nk] if inner else lorder)),
-            None if inner else _sig(right.select(rorder)),
-            nk, null_equal, T, how, null_cols)
-    pfn = _jit_cache.get(pkey)
-    if pfn is None:
-        def pbody(p_arrays, b_arrays, bcodes, owner, pcount):
-            cap = p_arrays[0][0].shape[0]
-            codes, null_ok = HT.encode_columns_aligned(
-                p_arrays[:nk], null_cols, null_equal)
-            live = K.row_mask(pcount, cap)
-            if null_ok is not None:
-                live = live & null_ok
-            idx, p_unres = HT.probe_slots(bcodes, owner, codes, live, T)
-            hit = idx >= 0
-            safe = jnp.maximum(idx, 0)
-            if inner:
-                # as the dense probe: no column, `_join_emit` has them
-                return hit, safe, jnp.sum(hit), p_unres
-            return (tuple(p_arrays), _gather_build(b_arrays, hit, safe),
-                    pcount, p_unres)
-
-        pfn = named_jit("join_probe_hash", pbody)
-        _jit_cache[pkey] = pfn
+    sharded = left.distribution == ONED
+    pfn = _hash_probe_fn(
+        _probe_mesh_key(left), nk, null_equal, T, how, null_cols,
+        (_sig(left.select(lorder[:nk] if inner else lorder)),
+         None if inner else _sig(right.select(rorder))))
 
     with _lut_route("hash", nk, left, right, build_left):
-        pc = jnp.asarray(left.nrows)
+        pc = left.counts_device() if sharded else jnp.asarray([left.nrows])
         if inner:
             hit, safe, cnt, p_unres = pfn(pa[:nk], (), bcodes, owner, pc)
         else:
             out_p, out_b, cnt, p_unres = pfn(pa, ba, bcodes, owner, pc)
-        nrows_, unres_ = jax.device_get((cnt, p_unres))
-        if bool(unres_):
-            return None  # pathological probe chains: the sort join's
+        cnt, unres = jax.device_get((cnt, p_unres))
+        if np.any(unres):
+            return None  # pathological probe chains (on any shard): sort
+        nrows, counts = _result_rows(cnt, sharded)
         if inner:
             return _join_emit(left, right, left_on, right_on, suffixes,
-                              (lorder, rorder, pa, ba), hit, safe,
-                              int(nrows_))
+                              (lorder, rorder, pa, ba), hit, safe, nrows,
+                              counts)
         res = _assemble_join(left, right, left_on, right_on, lorder, rorder,
-                             out_p, out_b, int(nrows_), None, how, suffixes)
+                             out_p, out_b, nrows, counts, how, suffixes)
         return rebucket(res)
+
+
+def _hash_probe_fn(mesh_key, nk, null_equal, T, how, null_cols, sig_key):
+    """The hash LUT's probe program, `join_probe_hash`: each live probe
+    row follows the build's double-hash sequence to its build row or an
+    empty slot. It returns what the dense probe does
+    (`_dense_probe_fn`), and `p_unres`, set where a probe chain ran out
+    of rounds (a shard's own on a row-sharded probe)."""
+    from bodo_tpu.ops import hashtable as HT
+    key = ("hashjoin_probe", mesh_key, sig_key, nk, null_equal, T, how,
+           null_cols)
+    fn = _jit_cache.get(key)
+    if fn is not None:
+        return fn
+    inner = how == "inner"
+
+    def pbody(p_arrays, b_arrays, bcodes, owner, pcount):
+        cap = p_arrays[0][0].shape[0]
+        codes, null_ok = HT.encode_columns_aligned(
+            p_arrays[:nk], null_cols, null_equal)
+        live = K.row_mask(pcount[0], cap)
+        if null_ok is not None:
+            live = live & null_ok
+        idx, p_unres = HT.probe_slots(bcodes, owner, codes, live, T)
+        hit = idx >= 0
+        safe = jnp.maximum(idx, 0)
+        if inner:
+            # as the dense probe: no column, `_join_emit` has them
+            return hit, safe, jnp.sum(hit)[None], p_unres[None]
+        return (tuple(p_arrays), _gather_build(b_arrays, hit, safe),
+                pcount, p_unres[None])
+
+    ax = config.data_axis
+    return _cached_program(key, "join_probe_hash", pbody, mesh_key,
+                           (P(ax), P(), P(), P(), P(ax)))
 
 
 def _gather_build(b_arrays, hit, safe):
@@ -1843,7 +1926,7 @@ def _gather_build(b_arrays, hit, safe):
 
 
 def _join_emit(left, right, left_on, right_on, suffixes, arrays, hit, safe,
-               nrows: int) -> Table:
+               nrows: int, counts: Optional[np.ndarray] = None) -> Table:
     """An inner LUT join's result from its probe's `hit` mask and build
     row index `safe`, at the size of the result (`nrows`, which the
     host has just read; `arrays` is `_probe_build_arrays`'). Every live
@@ -1855,40 +1938,64 @@ def _join_emit(left, right, left_on, right_on, suffixes, arrays, hit, safe,
     count; `round_capacity(nrows)` below it): the hits' source index
     once (`K.compact_index`), probe columns gathered at it, build
     columns at `safe` of it, so no column is scattered and none is
-    touched at the probe table's capacity when the result is small."""
+    touched at the probe table's capacity when the result is small.
+
+    A row-sharded probe passes `counts`, its hits a shard, read with
+    `nrows` in the probe's one host read: the same program runs once a
+    shard under `shard_map`, each shard compacting its own hits at
+    `rebucket_capacity` of the largest shard's count against a shard's
+    capacity and gathering the replicated build's columns at `safe`;
+    nothing is compacted when every shard kept all its rows, and the
+    result is row-sharded with those counts."""
     lorder, rorder, pa, ba = arrays
-    skip = nrows == left.nrows
+    if counts is None:
+        skip = nrows == left.nrows
+        out_cap = None if skip else rebucket_capacity(nrows, left.capacity)
+    else:
+        skip = bool((counts == left.counts).all())
+        out_cap = None if skip else rebucket_capacity(
+            int(counts.max()), left.shard_capacity)
     join_emitted(nrows, skip)
-    out_cap = None if skip else rebucket_capacity(nrows, left.capacity)
-    ekey = ("join_probe_emit", _sig(left.select(lorder)),
-            _sig(right.select(rorder)), out_cap)
-    efn = _jit_cache.get(ekey)
-    if efn is None:
-        def ebody(p_arrays, b_arrays, hit, safe):
-            if out_cap is None:
-                return (), _gather_build(b_arrays, hit, safe)
-            src, n = K.compact_index(hit, out_cap)
-
-            def take(idx, pairs):
-                # `None` valids pass through `take_compacted`
-                return tuple(zip(
-                    K.take_compacted(idx, n, [d for d, _ in pairs]),
-                    K.take_compacted(idx, n, [v for _, v in pairs])))
-            keep = K.row_mask(n, out_cap)
-            return take(src, p_arrays), tuple(
-                (d, keep if v is None else v)
-                for d, v in take(safe[src], b_arrays))
-
-        efn = named_jit("join_probe_emit", ebody)
-        _jit_cache[ekey] = efn
-
+    efn = _emit_fn(_probe_mesh_key(left), out_cap,
+                   (_sig(left.select(lorder)), _sig(right.select(rorder))))
     out_p, out_b = efn(() if skip else pa, ba, hit, safe)
     res = _assemble_join(left, right, left_on, right_on, lorder, rorder,
-                         pa if skip else out_p, out_b, nrows, None, "inner",
-                         suffixes)
+                         pa if skip else out_p, out_b, nrows, counts,
+                         "inner", suffixes)
     # a compacted result is born at its capacity (a no-op here); an
     # all-hit one keeps the probe table's, which may be far above its rows
     return rebucket(res)
+
+
+def _emit_fn(mesh_key, out_cap, sig_key):
+    """`_join_emit`'s program, `join_probe_emit`: with `out_cap` None the
+    build columns at every probe row's `safe`; else the hits' source
+    index at `out_cap` rows, the probe columns gathered there and the
+    build columns at `safe` of it. Once a shard under `shard_map` on a
+    row-sharded probe (`mesh_key`)."""
+    key = ("join_probe_emit", mesh_key, sig_key, out_cap)
+    fn = _jit_cache.get(key)
+    if fn is not None:
+        return fn
+
+    def ebody(p_arrays, b_arrays, hit, safe):
+        if out_cap is None:
+            return (), _gather_build(b_arrays, hit, safe)
+        src, n = K.compact_index(hit, out_cap)
+
+        def take(idx, pairs):
+            # `None` valids pass through `take_compacted`
+            return tuple(zip(
+                K.take_compacted(idx, n, [d for d, _ in pairs]),
+                K.take_compacted(idx, n, [v for _, v in pairs])))
+        keep = K.row_mask(n, out_cap)
+        return take(src, p_arrays), tuple(
+            (d, keep if v is None else v)
+            for d, v in take(safe[src], b_arrays))
+
+    ax = config.data_axis
+    return _cached_program(key, "join_probe_emit", ebody, mesh_key,
+                           (P(ax), P(), P(ax), P(ax)))
 
 
 def _probe_build_arrays(left, right, left_on, right_on):
@@ -2011,7 +2118,6 @@ def _build_join_sharded_fn(mesh_key, nk, how, out_cap, broadcast: bool,
     fn = _jit_cache.get(key)
     if fn is not None:
         return fn
-    mesh = _MESHES[mesh_key]
     ax = config.data_axis
 
     def body(p_arrays, b_arrays, pcounts, bcounts):
@@ -2020,14 +2126,10 @@ def _build_join_sharded_fn(mesh_key, nk, how, out_cap, broadcast: bool,
             null_equal, method)
         return out_p, out_b, cnt[None], ovf[None], unres[None]
 
-    fn = named_jit("join_sharded", C.smap(
-        body,
-        in_specs=(P(ax), P() if broadcast else P(ax),
-                  P(ax), P() if broadcast else P(ax)),
-        out_specs=(P(ax), P(ax), P(ax), P(ax), P(ax)),
-        mesh=mesh))
-    _jit_cache[key] = fn
-    return fn
+    return _cached_program(
+        key, "join_sharded", body, mesh_key,
+        (P(ax), P() if broadcast else P(ax), P(ax),
+         P() if broadcast else P(ax)))
 
 
 def _build_join_count_sharded_fn(mesh_key, nk, how, broadcast: bool,
@@ -2048,13 +2150,10 @@ def _build_join_count_sharded_fn(mesh_key, nk, how, broadcast: bool,
                           bcounts[0], nk, how, null_equal,
                           method)[0][None]
 
-    fn = named_jit("join_count_sharded", C.smap(
-        body,
-        in_specs=(P(ax), P() if broadcast else P(ax), P(ax),
-                  P() if broadcast else P(ax)),
-        out_specs=P(ax), mesh=_MESHES[mesh_key]))
-    _jit_cache[key] = fn
-    return fn
+    return _cached_program(
+        key, "join_count_sharded", body, mesh_key,
+        (P(ax), P() if broadcast else P(ax), P(ax),
+         P() if broadcast else P(ax)))
 
 
 def _join_sharded(left, right, left_on, right_on, how, suffixes,

@@ -1,10 +1,12 @@
 """Ask the v5e's compiler, without a chip: every Pallas kernel in
 ops/pallas_kernels.py at the shapes chip_smoke.py gives it, the
 multi-key sort, the dense aggregate tail at TPC-H Q1's shape, the
-group-by of TPC-H Q18's float64 key, and the programs that carry a join
-across the four chips of cell `tpch_q5_4chip` (`shuffle_by_key`,
-`join_sharded` shuffled and broadcast, `join_count_sharded`), compiled
-for a described `v5e:2x2`.
+group-by of TPC-H Q18's float64 key and its hash probe, and the programs
+that carry a join across the four chips of cell `tpch_q5_4chip`
+(`shuffle_by_key`, `join_sharded` shuffled and broadcast,
+`join_count_sharded`, and the LUT join's `join_probe_dense`,
+`join_probe_hash` and `join_probe_emit` once a shard), compiled for a
+described `v5e:2x2`.
 
 Nothing runs, so this says nothing about results or speed; it catches
 what interpret mode cannot (Mosaic refusing an op or a layout, a program
@@ -208,6 +210,26 @@ def test_dense_reduce_tail_compiles_for_v5e(shape):
         f"{temp >> 20} MiB of temporaries at {widest} slots"
 
 
+def test_hash_probe_tail_compiles_for_v5e(shape):
+    """TPC-H Q18's hash probe: 6,000,128 lineitem rows against a
+    256-slot table of its 70 large orders' keys. The walk over every row
+    hands its last 1/32 to a compacted walk under `lax.cond`, whose
+    results go back by one scatter of 187,504 rows (the compiler sorts
+    its indices: no sort of the probe's rows)."""
+    from bodo_tpu import relational as R
+    n, T, bcap = 6_000_128, 256, 128
+    fn = R._hash_probe_fn(None, 1, True, T, "inner", (True,),
+                          ("chip_compile", n, T))
+    compiled = _timed(fn.lower(
+        ((shape((n,), jnp.int64), None),), (),
+        (shape((bcap,), jnp.uint64), shape((bcap,), jnp.uint64)),
+        shape((T,), jnp.int32), shape((1,), jnp.int64)),
+        SORT_COMPILE_CEILING_S)
+    sorts = [ln for ln in compiled.as_text().splitlines() if " sort(" in ln]
+    assert all(f"[{n >> 5}]" in ln for ln in sorts), sorts
+    assert compiled.memory_analysis().temp_size_in_bytes < (1 << 30)
+
+
 def test_float64_group_key_compiles_for_v5e(shape):
     """TPC-H Q18's last group-by: a float64 key (`o_totalprice`) beside
     int64 ones and a string's codes. The hashed route's codes are the
@@ -396,3 +418,66 @@ def test_sort_sharded_float64_key_compiles_for_four_v5e(four_chips):
               (sharded(4 * cap, jnp.int32), None))
     fn = S4._build_sort_sharded(mesh_key, 2, 1, (False,), True, cap)
     fn.lower(arrays, sharded(4, jnp.int64)).compile()
+
+
+# a shard of `lineitem` at the next step (3,000,064 rows) probing, once a
+# shard, the dense LUT built on a replicated build: the region's
+# suppliers (a key range of 30,000; Q5's third join) and the year's
+# orders (a range of 3,000,000; its fourth), then emitting its hits, a
+# fifth of the rows, with the build's columns beside them; and a
+# dimension small enough for the one-hot gather's gate (4,096 slots),
+# which a sharded probe leaves to XLA's gather
+LUT_PROBE = [("suppliers", 30_000, 6_105), ("orders", 3_000_000, 455_361),
+             ("small_dimension", 4_096, 4_096)]
+
+
+@pytest.mark.parametrize("slots,brows", [c[1:] for c in LUT_PROBE],
+                         ids=[c[0] for c in LUT_PROBE])
+def test_sharded_lut_probe_compiles_for_four_v5e(four_chips, slots, brows):
+    """The LUT is replicated data and the probe a gather a row, so the
+    programs hold no sort and no collective; the emit's output is sized
+    by the largest shard's count, not by the probe's capacity."""
+    from bodo_tpu import relational as R
+    from bodo_tpu.table.table import round_capacity
+    mesh_key, sharded, replicated = four_chips
+    cap = _cap(4 * ORDERS_NEXT)
+    sig = ("chip_compile", cap, slots)
+    probe = R._dense_probe_fn(mesh_key, 1, (slots,), (1,), "inner", False,
+                              sig)
+    compiled = _timed(probe.lower(
+        _keyed(sharded, 4 * cap, 1, 0), (), replicated(slots, jnp.int32),
+        sharded(4, jnp.int64)), JOIN_BROADCAST_CEILING_S)
+    text = compiled.as_text()
+    assert " sort(" not in text and "all-gather" not in text
+    assert compiled.memory_analysis().temp_size_in_bytes < (256 << 20)
+
+    out_cap = R.rebucket_capacity(cap // 5, cap)
+    assert out_cap == round_capacity(cap // 5)
+    emit = R._emit_fn(mesh_key, out_cap, sig)
+    bcap = round_capacity(brows)
+    compiled = _timed(emit.lower(
+        _keyed(sharded, 4 * cap, 2, 2), _keyed(replicated, bcap, 1, 2),
+        sharded(4 * cap, jnp.bool_), sharded(4 * cap, jnp.int32)),
+        JOIN_BROADCAST_CEILING_S)
+    assert " sort(" not in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < (512 << 20)
+
+
+def test_sharded_hash_probe_compiles_for_four_v5e(four_chips):
+    """The hash LUT's probe once a shard: a shard of `lineitem` at the
+    next step against a replicated table of 131,072 slots (past the
+    Pallas probe's gate), the compacted tail walk under `lax.cond` inside
+    `shard_map`; no collective."""
+    from bodo_tpu import relational as R
+    mesh_key, sharded, replicated = four_chips
+    cap, T, bcap = _cap(4 * ORDERS_NEXT), 131_072, 65_536
+    fn = R._hash_probe_fn(mesh_key, 1, True, T, "inner", (True,),
+                          ("chip_compile", cap, T))
+    compiled = _timed(fn.lower(
+        _keyed(sharded, 4 * cap, 1, 0), (),
+        (replicated(bcap, jnp.uint64), replicated(bcap, jnp.uint64)),
+        replicated(T, jnp.int32), sharded(4, jnp.int64)),
+        JOIN_BROADCAST_CEILING_S)
+    text = compiled.as_text()
+    assert "all-gather" not in text and "all-to-all" not in text
+    assert compiled.memory_analysis().temp_size_in_bytes < (512 << 20)

@@ -37,6 +37,42 @@ def test_claim_slots_basic(mesh8):
     assert len(set(slots)) == len(by_key)
 
 
+@pytest.mark.parametrize("nb,m,max_rounds,unresolved", [
+    (70, 60_000, 64, False),    # a few keys, long tails of misses
+    (1000, 40_000, 64, False),  # load near 0.5
+    (1000, 40_000, 2, True),    # rounds run out with most rows walking
+    (1000, 40_000, 7, True),    # rounds run out inside the tail
+    (3, 5, 64, False),          # a probe smaller than one tail row
+], ids=["sparse_build", "half_load", "out_dense", "out_in_tail", "tiny"])
+def test_probe_slots_tail_walk(mesh8, nb, m, max_rounds, unresolved):
+    """`probe_slots` hands the last walkers to a compacted walk: every
+    live probe row still finds its build row (or -1), and rows still
+    walking when the rounds run out, in either walk, say unresolved."""
+    import jax.numpy as jnp
+
+    from bodo_tpu.ops import hashtable as HT
+
+    r = np.random.default_rng(nb + m + max_rounds)
+    bk = r.choice(10**9, nb, replace=False).astype(np.int64)
+    pk = np.where(r.random(m) < 0.2, bk[r.integers(0, nb, m)],
+                  r.integers(10**9, 2 * 10**9, m))
+    ok = r.random(m) < 0.95
+    bcodes, _ = HT.encode_columns([(jnp.asarray(bk), None)])
+    pcodes, _ = HT.encode_columns([(jnp.asarray(pk), None)])
+    T = HT.table_size(nb)
+    _slot, owner, _r, bunres = HT.claim_slots(bcodes, jnp.ones(nb, bool), T)
+    assert not bool(bunres)
+    idx, unres = HT.probe_slots(bcodes, owner, pcodes, jnp.asarray(ok), T,
+                                max_rounds=max_rounds)
+    assert bool(unres) == unresolved
+    if unresolved:
+        return
+    row = {int(k): i for i, k in enumerate(bk)}
+    want = np.array([row.get(int(k), -1) if o else -1
+                     for k, o in zip(pk, ok)])
+    np.testing.assert_array_equal(np.asarray(idx), want)
+
+
 def test_group_ids_matches_pandas_ngroups(mesh8):
     import jax.numpy as jnp
 

@@ -42,13 +42,13 @@ Rules:
       by design and out of scope.
 
   fusion-host-call
-      A host-sync call (`jax.device_get`, `.to_pandas()`,
-      `device_put`, `.block_until_ready()`) inside a function marked
-      `@fusion_stage` (plan/fusion.py). Fusion stages run INSIDE one
-      compiled whole-stage program; a host round-trip there either
-      fails to trace or silently splits the fused program at an
-      unsharded boundary — the exact materialization fusion exists to
-      eliminate.
+      A host-sync call (`jax.device_get`, `tracing.host_read`,
+      `.to_pandas()`, `device_put`, `.block_until_ready()`) inside a
+      function marked `@fusion_stage` (plan/fusion.py). Fusion stages
+      run INSIDE one compiled whole-stage program; a host round-trip
+      there either fails to trace or silently splits the fused program
+      at an unsharded boundary — the exact materialization fusion exists
+      to eliminate.
 
   rank-divergent-rng-seed
       An RNG seeded from process/shard identity (np.random.seed /
@@ -62,26 +62,28 @@ Rules:
       (jax.random.fold_in), never from seeding with the rank itself.
 
   divergent-host-sync
-      A host sync (`jax.device_get` / `.block_until_ready()`) under
-      control flow conditioned on process/shard identity. Fetching a
-      SHARDED array is a cross-host transfer on multi-host backends —
-      ranks that skipped the branch never enter it, so the fetching
-      rank wedges exactly like a skipped collective (the
-      rank-divergent-collective rule's host-side twin).
+      A host sync (`jax.device_get` / `tracing.host_read` /
+      `.block_until_ready()`) under control flow conditioned on
+      process/shard identity. Fetching a SHARDED array is a cross-host
+      transfer on multi-host backends — ranks that skipped the branch
+      never enter it, so the fetching rank wedges exactly like a skipped
+      collective (the rank-divergent-collective rule's host-side twin).
 
   stream-sync-unannotated
-      A host sync (`jax.device_get` / `.block_until_ready()`) inside a
-      streaming accumulator module (plan/streaming*.py), a fused-join
-      dispatch body (plan/fusion_join.py), or a view step/maintenance
-      body (runtime/views.py functions whose name carries step/
+      A host sync (`jax.device_get` / `tracing.host_read` /
+      `.block_until_ready()`) inside a streaming accumulator module
+      (plan/streaming*.py), a fused-join dispatch body
+      (plan/fusion_join.py), or a view step/maintenance body
+      (runtime/views.py functions whose name carries step/
       maintenance/tick/refresh/materialize) without a
       `# dispatch-boundary` comment on the call or an adjacent line.
       Streaming steps — and the view-maintenance path that rides the
       same executors — are dispatch-free by design — syncs per stage
       must stay O(1)-O(log batches), so every deliberate sync site is
-      annotated and counted in `stream_stats`; an unannotated sync is
-      either an accidental pipeline stall (O(batches) regression) or
-      an uncounted one no test can regress on.
+      annotated and counted in `stream_stats` (through `host_read`); an
+      unannotated sync is either an accidental pipeline stall
+      (O(batches) regression) or an uncounted one no test can regress
+      on.
 
 Suppressions: `# shardcheck: ignore[rule]` (or bare
 `# shardcheck: ignore` for all rules) on the finding's line or the
@@ -172,12 +174,12 @@ _CKPT_RECV_RE = re.compile(r"ckpt|checkpoint|store", re.IGNORECASE)
 
 # host-sync calls illegal inside a @fusion_stage body (whole-stage
 # fusion: the body runs inside ONE compiled program)
-_HOST_SYNC_NAMES = {"device_get", "to_pandas", "device_put",
+_HOST_SYNC_NAMES = {"device_get", "host_read", "to_pandas", "device_put",
                     "block_until_ready"}
 
 # host syncs that are cross-host transfers for sharded arrays — under
 # rank-divergent control flow they wedge like a skipped collective
-_DIVERGENT_SYNC_NAMES = {"device_get", "block_until_ready"}
+_DIVERGENT_SYNC_NAMES = {"device_get", "host_read", "block_until_ready"}
 
 # streaming accumulator modules: every host sync in a step body must be
 # a deliberate, annotated dispatch boundary (plan/streaming.py's
@@ -585,7 +587,8 @@ class _Checker(ast.NodeVisitor):
                     f"{t!r} in a streaming step body without a "
                     f"`# dispatch-boundary` annotation: streaming "
                     f"stages budget O(1)-O(log batches) syncs — mark "
-                    f"the site deliberate (and _note_sync() it) or "
+                    f"the site deliberate (read it through "
+                    f"tracing.host_read) or "
                     f"hoist the fetch out of the per-batch path")
         if t in _RNG_SEED_NAMES and (node.args or node.keywords) and \
                 any(_test_is_rank_divergent(a)

@@ -32,6 +32,7 @@ from jax import lax
 from bodo_tpu.ops import kernels as K
 from bodo_tpu.ops import sort_encoding as SE
 from bodo_tpu.utils.kernel_cache import bounded_jit
+from bodo_tpu.utils.tracing import host_read
 
 # ---------------------------------------------------------------------------
 # agg spec plumbing
@@ -685,7 +686,7 @@ def groupby_local_hashed(arrays, count, specs: Tuple[str, ...],
 
     seg, group_row, ok, n_groups, unresolved = _groupby_hashed_claim(
         arrays[:num_keys], count)
-    ng, unres = jax.device_get((n_groups, unresolved))
+    ng, unres = host_read("group_count", (n_groups, unresolved))
     if bool(unres):
         return None, None, 0, True
     cap = arrays[0][0].shape[0]

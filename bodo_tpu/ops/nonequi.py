@@ -37,6 +37,7 @@ _GRID_BUDGET = 1 << 22
 
 from bodo_tpu.utils.kernel_cache import (KERNEL_CACHE_SIZE, KernelCache,
                                          named_jit)
+from bodo_tpu.utils.tracing import host_read
 
 _jit_cache = KernelCache(maxsize=KERNEL_CACHE_SIZE,
                          subsystem="nonequi")
@@ -146,12 +147,12 @@ def nl_join_rep(left: Table, right: Table, pred: Expr,
                                     how == "left")
             res = fn(ltree, jnp.asarray(tile_rows), rtree, rcount)
             out, cnt = res[0], res[1]
-            n_match = int(jax.device_get(cnt))
+            n_match = int(host_read("nonequi_count", cnt))
             if n_match <= out_cap:
                 break
             out_cap = _pow2(n_match)
         if how == "left":
-            m = np.asarray(jax.device_get(res[2]))[:tile_rows]
+            m = np.asarray(host_read("nonequi_matched", res[2]))[:tile_rows]
             matched_host.append(m)
         cols: Dict[str, Column] = {}
         for n in names_l:
@@ -263,10 +264,10 @@ def nl_join_interval(left: Table, right: Table, pred: Expr,
     left_s = R.sort_table(R.shrink_to_fit(left), [probe_col])
     right_s = R.sort_table(R.shrink_to_fit(right), [lo_col])
     # host copy of the sort columns to size each tile's build prefix
-    p_host = np.asarray(jax.device_get(left_s.column(probe_col).data)
-                        )[:left_s.nrows]
-    lo_host = np.asarray(jax.device_get(right_s.column(lo_col).data)
-                         )[:right_s.nrows]
+    p_host, lo_host = host_read("nonequi_bounds", (
+        left_s.column(probe_col).data, right_s.column(lo_col).data))
+    p_host = np.asarray(p_host)[:left_s.nrows]
+    lo_host = np.asarray(lo_host)[:right_s.nrows]
     B_full = max(right_s.nrows, 1)
     T = _pow2(max(min(left_s.capacity, max(_GRID_BUDGET // B_full, 1)),
                   1))

@@ -26,6 +26,7 @@ from bodo_tpu.parallel import collectives as C
 from bodo_tpu.parallel import mesh as mesh_mod
 from bodo_tpu.utils.kernel_cache import (bounded_jit, cached_builder,
                                          named_jit)
+from bodo_tpu.utils.tracing import host_read
 
 # oversampling factor for splitter selection (samples per shard = OS * S)
 _OVERSAMPLE = 8
@@ -172,7 +173,7 @@ def sort_sharded(arrays, counts, num_keys: int, ascending: Tuple[bool, ...],
         fn = _build_sort_sharded(_mesh_key(m), len(arrays), num_keys,
                                  tuple(ascending), na_last, bucket_cap)
         out, cnts, ovf = fn(tuple(arrays), counts)
-        if not np.asarray(jax.device_get(ovf)).any():
+        if not np.asarray(host_read("sort_overflow", ovf)).any():
             return out, cnts
         if bucket_cap >= cap:
             raise RuntimeError("sort shuffle overflow at safe capacity")

@@ -31,6 +31,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from bodo_tpu.config import config
 from bodo_tpu.parallel import mesh as mesh_mod
 from bodo_tpu.runtime.resilience import maybe_inject as _inject
+from bodo_tpu.utils.tracing import host_read
 
 
 # --------------------------------------------------------------------------
@@ -174,7 +175,7 @@ def gather_host_rows(dev_arr, counts: np.ndarray) -> np.ndarray:
     with comm.collective_span(
             "gather_host",
             bytes_in=int(getattr(dev_arr, "nbytes", 0))) as sp:
-        host = np.asarray(jax.device_get(dev_arr))
+        host = np.asarray(host_read("gather", dev_arr))
         cap = host.shape[0] // s
         pieces = [host[i * cap: i * cap + int(counts[i])]
                   for i in range(s)]

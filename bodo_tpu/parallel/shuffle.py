@@ -35,6 +35,7 @@ from bodo_tpu.parallel import collectives as C
 from bodo_tpu.parallel import mesh as mesh_mod
 from bodo_tpu.plan.fusion import fusion_stage
 from bodo_tpu.utils.kernel_cache import cached_builder, named_jit
+from bodo_tpu.utils.tracing import host_read
 
 
 # ---------------------------------------------------------------------------
@@ -338,11 +339,11 @@ def _groupby_sharded_impl(arrays, counts, num_keys: int,
         partials, ngs, unres = _build_groupby_partial(
             mk, num_keys, specs, method)(tuple(arrays), counts)
         if method == "hash" and \
-                np.asarray(jax.device_get(unres)).any():
+                np.asarray(host_read("group_unresolved", unres)).any():
             method = "sort"  # pathological keys on some shard
             continue
         break
-    png = np.asarray(jax.device_get(ngs)).reshape(-1)
+    png = np.asarray(host_read("group_count", ngs)).reshape(-1)
     max_png = int(png.max()) if len(png) else 0
     safe_cap = round_capacity(max(max_png, 1))
     if bucket_cap is None:
@@ -354,7 +355,7 @@ def _groupby_sharded_impl(arrays, counts, num_keys: int,
         fn = _build_groupby_combine(mk, num_keys, specs, value_dtypes,
                                     bucket_cap, fcap)
         out, ng2, ovf = fn(partials, ngs)
-        if not np.asarray(jax.device_get(ovf)).any():
+        if not np.asarray(host_read("shuffle_overflow", ovf)).any():
             return out, ng2, ovf
         if bucket_cap >= safe_cap:
             raise RuntimeError("groupby shuffle overflow at safe capacity")

@@ -194,26 +194,26 @@ def _sample_key(t, name: str, m: int) -> Tuple[np.ndarray, int]:
     """Host sample of a 1D table's key column: a prefix slice per shard
     (biased only when rows are key-sorted — fine for a sketch). Returns
     (non-null sampled values, total sampled rows incl. nulls)."""
-    import jax
+    from bodo_tpu.utils import tracing
     c = t.column(name)
     per = t.shard_capacity
     take = max(m // max(t.num_shards, 1), 32)
-    datas, valids = [], []
+    slices = []
     total = 0
     for s in range(t.num_shards):
         n = min(int(t.counts[s]), take)
         if n <= 0:
             continue
         sl = slice(s * per, s * per + n)
-        datas.append(np.asarray(jax.device_get(c.data[sl])))
-        if c.valid is not None:
-            valids.append(np.asarray(jax.device_get(c.valid[sl])))
+        slices.append((c.data[sl],
+                       c.valid[sl] if c.valid is not None else None))
         total += n
-    if not datas:
+    if not slices:
         return np.empty(0), 0
-    d = np.concatenate(datas)
+    slices = tracing.host_read("key_sample", slices)
+    d = np.concatenate([np.asarray(sd) for sd, _ in slices])
     if c.valid is not None:
-        d = d[np.concatenate(valids)]
+        d = d[np.concatenate([np.asarray(sv) for _, sv in slices])]
     return d, total
 
 
@@ -459,14 +459,18 @@ def maybe_reoptimize_join(node, exec_cb):
     for r in rels:
         exec_cb(r)
     from bodo_tpu.plan import optimizer
-    new = optimizer.reorder_joins(node)
-    if new is node:
-        return None
-    try:
-        if new.key() == node.key():
+    from bodo_tpu.utils import tracing
+    # the re-plan's own host work, once the leaves (their node spans
+    # outside this one) have run
+    with tracing.event("exec.replan"):
+        new = optimizer.reorder_joins(node)
+        if new is node:
             return None
-    except Exception:
-        return None
+        try:
+            if new.key() == node.key():
+                return None
+        except Exception:
+            return None
     _mark_reoptimized(new)
     count("reoptimize:join_order")
     return new

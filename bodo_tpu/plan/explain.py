@@ -115,8 +115,10 @@ def record(node: L.Node, *, rows: int, wall_s: float,
     ({wall_s, wait_s, bytes} — inclusive, like wall_s), rendering the
     per-node comm-wait vs compute split. `xla` carries the compile &
     device-memory observatory's delta across the node ({compiles,
-    retraces, cause, dev_bytes}) rendered as
-    compiled|cached|retraced[cause] plus the node's net device bytes."""
+    retraces, cause, dispatches, syncs, dev_bytes}) rendered as
+    compiled|cached|retraced[cause], the host syncs (`syncs=N`: reads
+    of a device value the operator waited on) and the node's net device
+    bytes."""
     path = getattr(node, "_explain_path", None)
     if path is None:
         return
@@ -312,13 +314,18 @@ def _annotate(rec: Optional[dict]) -> str:
             parts.append(f"fused[{', '.join(bits)}]")
     x = rec.get("xla")
     if x:
+        bits = []
         if x.get("retraces"):
             cause = x.get("cause") or "unknown"
-            parts.append(f"xla=retraced[{cause}]")
+            bits.append(f"retraced[{cause}]")
         elif x.get("compiles"):
-            parts.append("xla=compiled")
+            bits.append("compiled")
         elif x.get("dispatches"):
-            parts.append("xla=cached")
+            bits.append("cached")
+        if x.get("syncs"):
+            bits.append(f"syncs={x['syncs']}")
+        if bits:
+            parts.append("xla=" + ",".join(bits))
         db = x.get("dev_bytes")
         if db:
             sign = "+" if db > 0 else "-"

@@ -827,12 +827,12 @@ def _run_chain(t: Table, steps, donate: bool = False) -> Table:
         with _mg.preadmission_charge(f"fused:{fp}"):
             if t.distribution == ONED:
                 out, cnts = fn(t.device_data(), t.counts_device())
-                counts = np.asarray(jax.device_get(cnts)).reshape(-1) \
-                    .astype(np.int64)
+                counts = np.asarray(tracing.host_read(
+                    "fused_count", cnts)).reshape(-1).astype(np.int64)
             else:
                 out, cnt = fn(t.device_data(), jnp.asarray(t.nrows))
                 counts = None
-                nrows = int(jax.device_get(cnt))
+                nrows = int(tracing.host_read("fused_count", cnt))
     except Exception as e:  # noqa: BLE001 - classified below
         _classify_dispatch_error(e, fp_sig, compiled)
         raise FusionFallback(str(e)) from e
@@ -1021,7 +1021,7 @@ def _run_fused_agg(t: Table, group: FusionGroup, donate: bool):
                 _mg.preadmission_charge(f"fused:{fp}"):
             out_keys, out_vals, ng = fn(t.device_data(),
                                         jnp.asarray(t.nrows))
-            nrows = int(jax.device_get(ng))
+            nrows = int(tracing.host_read("fused_group_count", ng))
     except Exception as e:  # noqa: BLE001 - classified below
         from bodo_tpu.runtime import resilience
         from bodo_tpu.runtime.memory_governor import governor

@@ -125,6 +125,7 @@ from bodo_tpu.table.table import (Column, ONED, REP, Table,
                                   round_capacity)
 from bodo_tpu.runtime import xla_observatory as xobs
 from bodo_tpu.utils.logging import log
+from bodo_tpu.utils.tracing import host_read
 
 # NOTE: same import rule as plan/fusion.py — relational, physical and
 # parallel/shuffle import the fusion layer at module level, so they may
@@ -423,7 +424,7 @@ def build_hash_table(right: Table, right_on, null_cols,
     bcodes, owner, bad = fn(karrays, jnp.asarray(right.nrows))
     _cstats["builds"] += 1
     # the one budgeted sync per build MISS (dup-key verdict)
-    if bool(jax.device_get(bad)):  # dispatch-boundary
+    if bool(host_read("build_dup", bad)):  # dispatch-boundary
         _cstats["negative"] += 1
         _cache_put(key, None)
         return None
@@ -926,13 +927,15 @@ def _dispatch_chain(t, b, group, body, bargs, bspecs, out_names,
             if t.distribution == ONED:
                 out, cnts, unres = fn(t.device_data(),
                                       t.counts_device(), bargs)
-                cnts_h, unres_h = jax.device_get((cnts, unres))  # dispatch-boundary
+                cnts_h, unres_h = host_read(  # dispatch-boundary
+                    "fused_join_count", (cnts, unres))
                 counts = np.asarray(cnts_h).reshape(-1).astype(np.int64)
                 bad = bool(np.asarray(unres_h).any())
             else:
                 out, cnt, unres = fn(t.device_data(),
                                      jnp.asarray(t.nrows), bargs)
-                cnt_h, unres_h = jax.device_get((cnt, unres))  # dispatch-boundary
+                cnt_h, unres_h = host_read(  # dispatch-boundary
+                    "fused_join_count", (cnt, unres))
                 counts = None
                 nrows = int(cnt_h)
                 bad = bool(unres_h)
@@ -1101,8 +1104,8 @@ def _dispatch_agg(t, b, group, body, bargs, bspecs, agg_plan,
             with _mg.preadmission_charge(f"fused:{fp}"):
                 res_out, ngs, ovf, unres = fn(
                     t.device_data(), t.counts_device(), bargs)
-                ngs_h, ovf_h, unres_h = jax.device_get(  # dispatch-boundary
-                    (ngs, ovf, unres))
+                ngs_h, ovf_h, unres_h = host_read(  # dispatch-boundary
+                    "fused_join_group_count", (ngs, ovf, unres))
         except Exception as e:  # noqa: BLE001 - classified below
             F._classify_dispatch_error(e, fp_sig, compiled)
             raise F.FusionFallback(str(e)) from e

@@ -135,43 +135,25 @@ def _place_source(node: L.FromPandas) -> Table:
 
 
 def _exec(node: L.Node) -> Table:
+    """Execute one node (children first, inside its span). The
+    executor's own bookkeeping around it is the span `exec.observe`,
+    once before the node (result-cache lookup, EXPLAIN snapshots) and
+    once after (EXPLAIN record, AQE and elastic observations, result-
+    cache record)."""
     traced = tracing.is_tracing()
-    if node._cached is not None:
-        if traced:
-            _record_node(node, node._cached, 0.0, cached=True)
-        return node._cached
-    key = _rcache.node_key(node)
-    hit = _rcache.lookup(key)
-    if hit is not None:
-        node._cached = hit
-        if traced:
-            _record_node(node, hit, 0.0, cached=True)
-        return hit
-    est_rows = aqe_before = comm_before = xla_before = None
-    if traced:
-        # pre-execution estimate + AQE decision snapshot, so the record
-        # can show est-vs-actual and which adaptive decisions this node
-        # triggered (EXPLAIN ANALYZE annotations)
-        try:
-            from bodo_tpu.plan import adaptive, stats
-            est_rows = stats.estimate(node)[0]
-            aqe_before = dict(adaptive.stats().get("decisions", {}))
-        except Exception:  # noqa: BLE001 - annotation is best-effort
-            pass
-        try:
-            # comm-observatory snapshot: the delta across the node's
-            # span is its inclusive comm-wait vs compute split
-            from bodo_tpu.parallel import comm
-            comm_before = comm.stats()
-        except Exception:  # noqa: BLE001
-            pass
-        try:
-            # observatory snapshot: compiles/retraces/device bytes that
-            # land during this node's span are attributed to it
-            from bodo_tpu.runtime import xla_observatory
-            xla_before = xla_observatory.head()
-        except Exception:  # noqa: BLE001
-            pass
+    with tracing.event("exec.observe"):
+        if node._cached is not None:
+            if traced:
+                _record_node(node, node._cached, 0.0, cached=True)
+            return node._cached
+        key = _rcache.node_key(node)
+        hit = _rcache.lookup(key)
+        if hit is not None:
+            node._cached = hit
+            if traced:
+                _record_node(node, hit, 0.0, cached=True)
+            return hit
+        before = _snapshot_node(node) if traced else {}
     span_args = {}
     path = getattr(node, "_explain_path", None)
     if path is not None:
@@ -182,40 +164,72 @@ def _exec(node: L.Node) -> Table:
         if ev is not None:
             ev["rows"] = t.nrows
     wall_s = _time.perf_counter() - t0
-    if traced:
-        _record_node(node, t, wall_s,
-                     est_rows=est_rows, aqe_before=aqe_before,
-                     comm_before=comm_before, xla_before=xla_before)
-    node._cached = t
-    # stage-boundary statistics feedback; a stage that came back from a
-    # degraded replicated re-run is tainted (execution artifact, not a
-    # data property) and must not feed the stats store
-    if getattr(_degrade_tls, "tainted", False):
-        _degrade_tls.tainted = False
-    else:
-        from bodo_tpu.plan import adaptive
-        adaptive.observe_stage(node, t)
-    _rcache.record(key, node.key(), t, wall_s)
-    try:
-        # elastic checkpoint anchor: the AQE observation point doubles
-        # as the resumable-suffix boundary (the result cache owns the
-        # bytes; elastic tracks registration + accounting for /healthz)
-        from bodo_tpu.runtime import elastic
-        elastic.observe_stage(node, wall_s)
-    except Exception:  # noqa: BLE001 - accounting never fails a query
-        pass
+    with tracing.event("exec.observe"):
+        if traced:
+            _record_node(node, t, wall_s, **before)
+        node._cached = t
+        # stage-boundary statistics feedback; a stage that came back
+        # from a degraded replicated re-run is tainted (execution
+        # artifact, not a data property) and must not feed the stats
+        # store
+        if getattr(_degrade_tls, "tainted", False):
+            _degrade_tls.tainted = False
+        else:
+            from bodo_tpu.plan import adaptive
+            adaptive.observe_stage(node, t)
+        _rcache.record(key, node.key(), t, wall_s)
+        try:
+            # elastic checkpoint anchor: the AQE observation point
+            # doubles as the resumable-suffix boundary (the result cache
+            # owns the bytes; elastic tracks registration + accounting
+            # for /healthz)
+            from bodo_tpu.runtime import elastic
+            elastic.observe_stage(node, wall_s)
+        except Exception:  # noqa: BLE001 - accounting never fails a query
+            pass
     return t
+
+
+def _snapshot_node(node: L.Node) -> dict:
+    """What `_record_node` subtracts from: the pre-execution estimate,
+    AQE decision counters (EXPLAIN ANALYZE annotations: est-vs-actual
+    and which adaptive decisions this node triggered), the comm and
+    compile observatories' totals and the host syncs so far."""
+    before = {"syncs_before": sum(tracing.sync_counts().values())}
+    try:
+        from bodo_tpu.plan import adaptive, stats
+        before["est_rows"] = stats.estimate(node)[0]
+        before["aqe_before"] = dict(adaptive.stats().get("decisions", {}))
+    except Exception:  # noqa: BLE001 - annotation is best-effort
+        pass
+    try:
+        # comm-observatory snapshot: the delta across the node's span
+        # is its inclusive comm-wait vs compute split
+        from bodo_tpu.parallel import comm
+        before["comm_before"] = comm.stats()
+    except Exception:  # noqa: BLE001
+        pass
+    try:
+        # observatory snapshot: compiles/retraces/device bytes that land
+        # during this node's span are attributed to it
+        from bodo_tpu.runtime import xla_observatory
+        before["xla_before"] = xla_observatory.head()
+    except Exception:  # noqa: BLE001
+        pass
+    return before
 
 
 def _record_node(node: L.Node, t: Table, wall_s: float,
                  cached: bool = False, est_rows=None,
                  aqe_before=None, comm_before=None,
-                 xla_before=None) -> None:
+                 xla_before=None, syncs_before=None) -> None:
     """EXPLAIN ANALYZE observation for one executed (or cache-hit) node:
     rows, result device bytes, inclusive wall, the delta of AQE
     decision counters and of the comm-observatory totals across the
-    node's execution. Best-effort — an annotation failure never fails
-    the query."""
+    node's execution, and in `xla` beside its dispatches its host syncs
+    (`tracing.host_read` calls, inclusive like the wall): how often the
+    operator stopped the device to read a value back. Best-effort — an
+    annotation failure never fails the query."""
     try:
         from bodo_tpu.plan import explain
         aqe_delta = None
@@ -251,10 +265,13 @@ def _record_node(node: L.Node, t: Table, wall_s: float,
                 retraces = after_x["retraces"] - xla_before["retraces"]
                 disp = after_x["dispatches"] - xla_before["dispatches"]
                 dev = after_x["live_bytes"] - xla_before["live_bytes"]
-                if compiles or retraces or disp or dev:
+                syncs = 0 if syncs_before is None else \
+                    sum(tracing.sync_counts().values()) - syncs_before
+                if compiles or retraces or disp or dev or syncs:
                     xla_delta = {"compiles": compiles,
                                  "retraces": retraces,
                                  "dispatches": disp,
+                                 "syncs": syncs,
                                  "dev_bytes": dev}
                     if retraces:
                         xla_delta["cause"] = after_x["last_cause"]

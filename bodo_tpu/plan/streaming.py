@@ -45,6 +45,7 @@ from bodo_tpu.parallel.shuffle import _finalize, _plan_decomposition
 from bodo_tpu.plan import logical as L
 from bodo_tpu.table import dtypes as dt
 from bodo_tpu.table.table import (Column, REP, Table, round_capacity)
+from bodo_tpu.utils import tracing
 from bodo_tpu.utils.kernel_cache import cached_builder, named_jit
 from bodo_tpu.utils.logging import log
 
@@ -61,18 +62,42 @@ def _bucket_cap(n: int) -> int:
 # ---------------------------------------------------------------------------
 # host-sync accounting
 # ---------------------------------------------------------------------------
-# Every `jax.device_get`/`block_until_ready` inside a streaming step body
-# stalls the pipeline: the host waits for the device instead of decoding
-# the next batch. The accumulators below are written so syncs per stage
-# are O(1)–O(log batches), not O(batches); each legitimate sync site is
-# annotated `# dispatch-boundary` (shardcheck lints unannotated ones) and
-# counted here so a test can regress on syncs-per-batch.
+# Every host read inside a streaming step body stalls the pipeline: the
+# host waits for the device instead of decoding the next batch. The
+# accumulators below are written so syncs per stage are O(1)–O(log
+# batches), not O(batches); each legitimate sync site is annotated
+# `# dispatch-boundary` (shardcheck lints unannotated ones) and reads
+# through `tracing.host_read` under a site named `stream.<what>`, so a
+# test can regress on syncs-per-batch.
 
-stream_stats: Dict[str, int] = {"host_syncs": 0, "batches": 0}
+def _stream_syncs() -> int:
+    return sum(n for site, n in tracing.sync_counts().items()
+               if site.startswith("stream."))
 
 
-def _note_sync(n: int = 1) -> None:
-    stream_stats["host_syncs"] += n
+class _StreamStats(dict):
+    """`batches` is counted here; `host_syncs` is read from
+    `tracing.sync_counts()`: host_read's calls at the `stream.*` sites
+    since `reset_stream_stats()`."""
+
+    def __init__(self):
+        super().__init__()
+        self.reset()
+
+    def reset(self) -> None:
+        self["batches"] = 0
+        self._syncs_at_reset = _stream_syncs()
+
+    def __getitem__(self, key):
+        if key == "host_syncs":
+            return _stream_syncs() - self._syncs_at_reset
+        return super().__getitem__(key)
+
+    def __repr__(self):
+        return repr({"host_syncs": self["host_syncs"], **self})
+
+
+stream_stats = _StreamStats()
 
 
 def _note_batch(n: int = 1) -> None:
@@ -80,8 +105,7 @@ def _note_batch(n: int = 1) -> None:
 
 
 def reset_stream_stats() -> None:
-    for k in stream_stats:
-        stream_stats[k] = 0
+    stream_stats.reset()
 
 
 def _with_capacity(t: Table, cap: int) -> Table:
@@ -317,14 +341,13 @@ class GroupbyAccumulator:
         self._drain_all()
         if self._n_state_dev is None:
             return 0
-        _note_sync()
-        return int(jax.device_get(self._n_state_dev))  # dispatch-boundary
+        return int(tracing.host_read(  # dispatch-boundary
+            "stream.state_count", self._n_state_dev))
 
     def _partial_names(self) -> List[str]:
         return [f"__p{i}" for i in range(len(self.partial_specs))]
 
     def push(self, batch: Table) -> None:
-        from bodo_tpu.utils import tracing
         nk = len(self.keys)
         if self._template is None:
             self._template = batch
@@ -356,7 +379,6 @@ class GroupbyAccumulator:
             self._drain_one()
 
     def _drain_one(self) -> None:
-        from bodo_tpu.utils import tracing
         nk = len(self.keys)
         partial, ng_dev, ng_bound = self._queue.pop(0)
 
@@ -407,8 +429,8 @@ class GroupbyAccumulator:
             # geometric sync: tighten the bound (and the state capacity)
             # to the actual group count, then double the interval so a
             # B-batch stream pays O(log B) of these round-trips total
-            _note_sync()
-            n = int(jax.device_get(ng2))  # dispatch-boundary
+            n = int(tracing.host_read(  # dispatch-boundary
+                "stream.group_count", ng2))
             self._bound = n
             self._since_sync = 0
             self._sync_interval *= 2
@@ -745,7 +767,6 @@ class ReduceAccumulator:
         arrays = tuple((batch.column(col).data, batch.column(col).valid)
                        for col, _, _ in self.aggs)
         step = _build_reduce_step(self._sig, batch.capacity, self._donate)
-        from bodo_tpu.utils import tracing
         old = self._carry
         with tracing.event("stream_reduce"):
             self._carry = step(old, arrays, jnp.asarray(batch.nrows))
@@ -758,9 +779,8 @@ class ReduceAccumulator:
         if self._carry is None:
             host: List = []
         else:
-            _note_sync()
-            host = [np.asarray(x)
-                    for x in jax.device_get(self._carry)]  # dispatch-boundary
+            host = [np.asarray(x) for x in tracing.host_read(
+                "stream.carry", self._carry)]  # dispatch-boundary
         res = {}
         ci = 0
         for i, (col, op, oname) in enumerate(self.aggs):

@@ -46,6 +46,7 @@ from bodo_tpu.parallel.shuffle import (_MESHES, _mesh_key,
 from bodo_tpu.plan.streaming import _bucket_cap as _pow2_cap
 from bodo_tpu.table import dtypes as dt
 from bodo_tpu.table.table import Column, ONED, REP, Table
+from bodo_tpu.utils import tracing
 from bodo_tpu.utils.kernel_cache import cached_builder, named_jit
 from bodo_tpu.utils.logging import log
 
@@ -231,7 +232,7 @@ class ShardedGroupbyAccumulator:
     Pipelining: push(k) dispatches step k FIRST; overflow flags and
     output group counts resolve in WINDOWS of RESOLVE_WINDOW dispatches
     — all of a window's flags plus the newest resolved count travel in
-    ONE batched `jax.device_get`, so host syncs per stage are
+    ONE batched `tracing.host_read`, so host syncs per stage are
     O(batches / W), not O(batches), and by the time a window retires its
     flags are long computed (no read ever stalls the pipe). The host
     therefore knows the exact per-shard group count with an at-most-W-
@@ -365,7 +366,6 @@ class ShardedGroupbyAccumulator:
 
     def _dispatch(self, inputs, bcap: int, bdicts) -> None:
         from bodo_tpu.parallel import comm
-        from bodo_tpu.utils import tracing
         arrays, counts = inputs
         pre_state = self._state
         step = _build_sharded_step(self._mk, len(self.keys), self.specs,
@@ -406,15 +406,13 @@ class ShardedGroupbyAccumulator:
     def _resolve_window(self, k: int) -> None:
         """Retire the oldest k dispatches with ONE batched host read:
         every flag in the window plus the newest retired dispatch's
-        group counts ride a single `jax.device_get`."""
-        from bodo_tpu.plan.streaming import _note_sync
+        group counts ride a single `tracing.host_read`."""
         if not self._queue or k <= 0:
             return
         k = min(k, len(self._queue))
         entries = self._queue[:k]
-        _note_sync()
-        got = jax.device_get(  # dispatch-boundary
-            [e["ovf"] for e in entries]
+        got = tracing.host_read(  # dispatch-boundary
+            "stream.resolve", [e["ovf"] for e in entries]
             + [e["out_counts"] for e in entries])
         flags = [np.asarray(f).reshape(-1) for f in got[:k]]
         counts = [int(np.asarray(c).reshape(-1).max(initial=0))
@@ -470,9 +468,9 @@ class ShardedGroupbyAccumulator:
                                                     self._state_cap)
                 self._dispatch(x["inputs"], x["bcap"], x["bdicts"])
                 e2 = self._queue.pop()
-                _note_sync()
                 f2, c2 = (np.asarray(a).reshape(-1) for a in
-                          jax.device_get(  # dispatch-boundary
+                          tracing.host_read(  # dispatch-boundary
+                              "stream.resolve",
                               [e2["ovf"], e2["out_counts"]]))
                 if not f2.any():
                     self._known = int(c2.max(initial=0))
@@ -529,16 +527,13 @@ class ShardedGroupbyAccumulator:
             self._state = ((tuple(cols[:nk]), tuple(cols[nk:])), cnts)
 
     def finish(self) -> Table:
-        from bodo_tpu.plan.streaming import _note_sync
         assert self._template is not None, "empty stream"
         while self._queue:
             self._resolve_window(len(self._queue))
         nk = len(self.keys)
         (mk, mv), cnts_dev = self._state
-        _note_sync()
-        counts = np.asarray(
-            jax.device_get(cnts_dev)).reshape(-1) \
-            .astype(np.int64)  # dispatch-boundary
+        counts = np.asarray(tracing.host_read(  # dispatch-boundary
+            "stream.group_count", cnts_dev)).reshape(-1).astype(np.int64)
         cols: Dict[str, Column] = {}
         for (name, dtype, dic, _), (d, v) in zip(self._state_meta[:nk],
                                                  mk):
@@ -908,10 +903,8 @@ def append_sharded(state: Optional[Table], batch: Table,
                        batch.shard_capacity, new_cap)
     out, cnts = fn(tuple(sflat), tuple(bflat), state.counts_device(),
                    batch.counts_device())
-    from bodo_tpu.plan.streaming import _note_sync
-    _note_sync()
-    counts = np.asarray(
-        jax.device_get(cnts)).reshape(-1).astype(np.int64)  # dispatch-boundary
+    counts = np.asarray(tracing.host_read(  # dispatch-boundary
+        "stream.append_count", cnts)).reshape(-1).astype(np.int64)
     cols: Dict[str, Column] = {}
     j = 0
     for n, has_v in zip(names, slots):
@@ -968,14 +961,11 @@ def _table_device_bytes(t: Table) -> int:
 
 def _host_cols(t: Table):
     """(data, valid) numpy copies of the live rows of a REP table."""
-    out = {}
-    for n in t.names:
-        c = t.column(n)
-        d = np.asarray(jax.device_get(c.data))[:t.nrows]  # dispatch-boundary
-        v = (np.asarray(jax.device_get(c.valid))[:t.nrows]  # dispatch-boundary
-             if c.valid is not None else None)
-        out[n] = (d, v)
-    return out
+    host = tracing.host_read(  # dispatch-boundary
+        "stream.spill_copy", t.device_data())
+    return {n: (np.asarray(d)[:t.nrows],
+                np.asarray(v)[:t.nrows] if v is not None else None)
+            for n, (d, v) in host.items()}
 
 
 def _table_from_host(host_cols, template: Table, nrows: int) -> Table:
@@ -1045,8 +1035,8 @@ def _key_membership(p: Table, b: Table, left_on, right_on,
     T = HT.table_size(b.capacity)
     slot, owner, _r, un1 = HT.claim_slots(bcodes, b_ok, T)
     idx, un2 = HT.probe_slots(bcodes, owner, pcodes, p_ok, T)
-    if bool(jax.device_get(un1 | un2)):  # dispatch-boundary
-        from bodo_tpu.utils import tracing
+    if bool(tracing.host_read(  # dispatch-boundary
+            "stream.membership_unresolved", un1 | un2)):
         log(1, "stream join drain: membership probe-round exhaustion — "
                f"falling back to host pandas merge ({p.nrows} probe x "
                f"{b.nrows} build rows leave the device)")
@@ -1062,7 +1052,8 @@ def _key_membership(p: Table, b: Table, left_on, right_on,
             if ev is not None:
                 ev["rows"] = p.nrows
         return matched
-    return np.asarray(jax.device_get(idx))[:p.nrows] >= 0  # dispatch-boundary
+    return np.asarray(tracing.host_read(  # dispatch-boundary
+        "stream.membership", idx))[:p.nrows] >= 0
 
 
 # ---------------------------------------------------------------------------
@@ -1353,7 +1344,8 @@ class ShardedStreamSort:
         padmask = jnp.arange(g.capacity) < g.nrows
         pk = _partition_key([(c0.data, c0.valid)], [self.ascending[0]],
                             self.na_last, padmask)
-        pk = np.asarray(jax.device_get(pk))[:g.nrows]  # dispatch-boundary
+        pk = np.asarray(tracing.host_read(  # dispatch-boundary
+            "stream.partition_key", pk))[:g.nrows]
         nbytes = _table_device_bytes(g)
         ot = self._comp.park(self._op, g)
         self.runs.append((ot, pk, nbytes))

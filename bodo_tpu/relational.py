@@ -108,7 +108,7 @@ def _sig(t: Table) -> Tuple:
 # ---------------------------------------------------------------------------
 
 from bodo_tpu.utils import tracing
-from bodo_tpu.utils.tracing import traced_table_op as _traced
+from bodo_tpu.utils.tracing import host_read, traced_table_op as _traced
 
 
 def _governed(name):
@@ -333,9 +333,10 @@ def assign_columns(t: Table, new: Dict[str, Expr]) -> Table:
         # format only the LIVE rows: padding would waste host formatting
         # and inject phantom dictionary entries ('0', '1970-01-01') —
         # or crash outright on garbage tail values
-        vals = np.asarray(jax.device_get(d))[:t.nrows]
-        host_v = (np.asarray(jax.device_get(v))[:t.nrows]
-                  if v is not None else np.ones(len(vals), bool))
+        hd, hv = host_read("to_char", (d, v))
+        vals = np.asarray(hd)[:t.nrows]
+        host_v = (np.asarray(hv)[:t.nrows]
+                  if hv is not None else np.ones(len(vals), bool))
         src_dt = infer_dtype(e.operand, _schema(t))
         fmt = e.strftime_fmt()
         if fmt is not None and src_dt not in (dt.DATETIME, dt.DATE):
@@ -522,13 +523,14 @@ def filter_table(t: Table, predicate: Expr) -> Table:
 
     if t.distribution == ONED:
         out_tree, cnts = fn(t.device_data(), t.counts_device())
-        counts = np.asarray(jax.device_get(cnts)).astype(np.int64)
+        counts = np.asarray(host_read("filter_count", cnts)).astype(
+            np.int64)
         return _keep_vranges(
             rebucket(t.with_device_data(out_tree, nrows=int(counts.sum()),
                                         counts=counts)), t)
     out_tree, cnt = fn(t.device_data(), jnp.asarray(t.nrows))
-    return _keep_vranges(rebucket(t.with_device_data(out_tree,
-                                                     nrows=int(cnt))), t)
+    return _keep_vranges(rebucket(t.with_device_data(
+        out_tree, nrows=int(host_read("filter_count", cnt)))), t)
 
 
 # ---------------------------------------------------------------------------
@@ -573,7 +575,7 @@ def _key_ranges(t: Table, keys: Sequence[str], use_bounds: bool = True):
         else:
             specs = [(k, "min", f"{k}__min") for k in need_reduce] + \
                 [(k, "max", f"{k}__max") for k in need_reduce]
-            stats = reduce_table(t, specs)
+            stats = reduce_table(t, specs, site="key_range")
         it = iter(need_reduce)
         for i, r in enumerate(ranges):
             if r == "reduce":
@@ -832,13 +834,14 @@ def groupby_agg(t: Table, keys: Sequence[str],
         # partial counts (with overflow retry) inside groupby_sharded
         (out_keys, out_vals), ngs, ovf = groupby_sharded(
             arrays, t.counts_device(), len(keys), specs)
-        counts = np.asarray(jax.device_get(ngs)).reshape(-1).astype(np.int64)
+        counts = np.asarray(host_read("group_count", ngs)).reshape(
+            -1).astype(np.int64)
         nrows, dist = int(counts.sum()), ONED
     else:
         with groupby_route("sort", len(keys), t.nrows):
             out_keys, out_vals, ng = groupby_local(
                 arrays, jnp.asarray(t.nrows), specs, t.capacity, len(keys))
-            nrows = int(ng)
+            nrows = int(host_read("group_count", ng))
         counts, dist = None, REP
 
     cols: Dict[str, Column] = {}
@@ -1232,7 +1235,7 @@ def _groupby_agg_dense(t: Table, keys, aggs, ranges) -> Table:
                        dense_route=route):
         out_keys, out_vals, ng = fn(tsel.device_data(),
                                     jnp.asarray(t.nrows))
-        nrows = int(jax.device_get(ng))
+        nrows = int(host_read("group_count", ng))
     cols: Dict[str, Column] = {}
     for kname, kd in zip(keys, out_keys):
         src = t.column(kname)
@@ -1280,7 +1283,8 @@ def _groupby_agg_colocated(t: Table, keys, aggs) -> Table:
         _jit_cache[key] = fn
 
     (out_keys, out_vals), ngs = fn(t.device_data(), t.counts_device())
-    counts = np.asarray(jax.device_get(ngs)).reshape(-1).astype(np.int64)
+    counts = np.asarray(host_read("group_count", ngs)).reshape(-1).astype(
+        np.int64)
     cols: Dict[str, Column] = {}
     for kname, (kd, kv) in zip(keys, out_keys):
         src = t.column(kname)
@@ -1324,7 +1328,8 @@ def sort_table(t: Table, by: Sequence[str], ascending=None,
         arrays = tuple((t.column(n).data, t.column(n).valid) for n in order)
         out, cnts = sort_sharded(arrays, t.counts_device(), len(by),
                                  tuple(ascending), na_last)
-        counts = np.asarray(jax.device_get(cnts)).reshape(-1).astype(np.int64)
+        counts = np.asarray(host_read("sort_count", cnts)).reshape(
+            -1).astype(np.int64)
         res_tree = {n: out[i] for i, n in enumerate(order)}
         res = shrink_to_fit(t.with_device_data(
             res_tree, nrows=int(counts.sum()), counts=counts))
@@ -1723,7 +1728,7 @@ def _join_dense_try(left, right, left_on, right_on, how, suffixes,
         _jit_cache[bkey] = bfn
 
     lut, dup = bfn(ba, jnp.asarray(right.nrows))
-    if bool(jax.device_get(dup)):
+    if bool(host_read("build_dup", dup)):
         return None  # duplicate build keys: not a perfect hash
 
     # the LUT gather is the probe's hot lookup: small LUTs route through
@@ -1751,9 +1756,11 @@ def _join_dense_try(left, right, left_on, right_on, how, suffixes,
             hit, safe, cnt = pfn(pa[:nk], (), lut, pc)
             return _join_emit(left, right, left_on, right_on, suffixes,
                               (lorder, rorder, pa, ba), hit, safe,
-                              *_result_rows(jax.device_get(cnt), sharded))
+                              *_result_rows(host_read("probe_count", cnt),
+                                            sharded))
         out_p, out_b, cnt = pfn(pa, ba, lut, pc)
-        nrows, counts = _result_rows(jax.device_get(cnt), sharded)
+        nrows, counts = _result_rows(host_read("probe_count", cnt),
+                                     sharded)
         res = _assemble_join(left, right, left_on, right_on, lorder, rorder,
                              out_p, out_b, nrows, counts, how, suffixes)
         return rebucket(res)
@@ -1854,7 +1861,7 @@ def _join_hash_try(left, right, left_on, right_on, how, suffixes,
             _jit_cache[bkey] = bfn
 
         bcodes, owner, bad = bfn(ba, jnp.asarray(right.nrows))
-        if bool(jax.device_get(bad)):
+        if bool(host_read("build_dup", bad)):
             return None  # duplicate build keys (or pathological probing)
 
     inner = how == "inner"
@@ -1870,7 +1877,7 @@ def _join_hash_try(left, right, left_on, right_on, how, suffixes,
             hit, safe, cnt, p_unres = pfn(pa[:nk], (), bcodes, owner, pc)
         else:
             out_p, out_b, cnt, p_unres = pfn(pa, ba, bcodes, owner, pc)
-        cnt, unres = jax.device_get((cnt, p_unres))
+        cnt, unres = host_read("probe_count", (cnt, p_unres))
         if np.any(unres):
             return None  # pathological probe chains (on any shard): sort
         nrows, counts = _result_rows(cnt, sharded)
@@ -2066,15 +2073,15 @@ def _join_rep(left, right, left_on, right_on, how, suffixes,
     for _ in range(4):
         out_p, out_b, cnt, ovf, unres = join_local(
             pa, ba, pc, bc, nk, how, out_cap, null_equal, method)
-        if method == "hash" and bool(jax.device_get(unres)):
+        if method == "hash" and bool(host_read("probe_unresolved", unres)):
             method = "sort"  # pathological probe chains: sort safety net
             continue
-        if not bool(jax.device_get(ovf)):
+        if not bool(host_read("join_overflow", ovf)):
             break
         total, _ = join_count(pa[:nk], ba[:nk], pc, bc, nk, how,
                               null_equal, method)
-        out_cap = round_capacity(int(jax.device_get(total)))
-    nrows = int(jax.device_get(cnt))
+        out_cap = round_capacity(int(host_read("join_count", total)))
+    nrows = int(host_read("probe_count", cnt))
     return _assemble_join(left, right, left_on, right_on, lorder, rorder,
                           out_p, out_b, nrows, None, how, suffixes)
 
@@ -2188,21 +2195,22 @@ def _join_sharded(left, right, left_on, right_on, how, suffixes,
         out_p, out_b, cnts, ovf, unres = fn(pa, ba, left.counts_device(),
                                             bcounts)
         if (method == "hash"
-                and np.asarray(jax.device_get(unres)).any()):
+                and np.asarray(host_read("probe_unresolved", unres)).any()):
             method = "sort"  # pathological probe chains on some shard
             continue
-        if not np.asarray(jax.device_get(ovf)).any():
+        if not np.asarray(host_read("join_overflow", ovf)).any():
             break
         # exact per-shard counts, then one final right-sized run
         cfn = _build_join_count_sharded_fn(_mesh_key(m), nk, how,
                                            broadcast, sig_key, null_equal,
                                            method)
-        exact = np.asarray(jax.device_get(
-            cfn(pa, ba, left.counts_device(), bcounts)))
+        exact = np.asarray(host_read(
+            "join_count", cfn(pa, ba, left.counts_device(), bcounts)))
         out_cap = round_capacity(int(exact.max()))
     else:
         raise RuntimeError("join output overflow after exact-count retry")
-    counts = np.asarray(jax.device_get(cnts)).reshape(-1).astype(np.int64)
+    counts = np.asarray(host_read("probe_count", cnts)).reshape(-1).astype(
+        np.int64)
     res = _assemble_join(left, right, left_on, right_on, lorder, rorder,
                          out_p, out_b, int(counts.sum()), counts, how,
                          suffixes)
@@ -2255,7 +2263,8 @@ def _cross_join(left, right, suffixes) -> Table:
             _jit_cache[key] = fn
         out_p, out_b, cnts = fn(pa, ba, left.counts_device(),
                                 jnp.asarray([right.nrows], dtype=jnp.int64))
-        counts = np.asarray(jax.device_get(cnts)).reshape(-1).astype(np.int64)
+        counts = np.asarray(host_read("cross_count", cnts)).reshape(
+            -1).astype(np.int64)
         res = _assemble_join(left, right, [], [], lorder, rorder, out_p,
                              out_b, int(counts.sum()), counts, "cross",
                              suffixes)
@@ -2264,7 +2273,7 @@ def _cross_join(left, right, suffixes) -> Table:
     out_cap = round_capacity(max(left.nrows * right.nrows, 1))
     out_p, out_b, cnt = cross_local(pa, ba, jnp.asarray(left.nrows),
                                     jnp.asarray(right.nrows), out_cap)
-    nrows = int(jax.device_get(cnt))
+    nrows = int(host_read("cross_count", cnt))
     return _assemble_join(left, right, [], [], lorder, rorder, out_p,
                          out_b, nrows, None, "cross", suffixes)
 
@@ -2739,8 +2748,11 @@ _REDUCE_PARTIALS = {"sum": ("sum",), "sumnull": ("sum", "count"),
                     "prod": ("prod",)}
 
 
-def reduce_table(t: Table, aggs: Sequence[Tuple[str, str, str]]) -> Dict:
+def reduce_table(t: Table, aggs: Sequence[Tuple[str, str, str]],
+                 site: str = "reduce") -> Dict:
     """Whole-column reductions → host scalars (Series.sum() analogue).
+    `site` names the read of the partials (`tracing.host_read`): a key
+    range for a gate reads as `key_range`.
 
     Per-shard partials are one fused jitted pass (masked reductions on the
     VPU); the tiny [S, n_partials] result combines on host — the same
@@ -2753,7 +2765,7 @@ def reduce_table(t: Table, aggs: Sequence[Tuple[str, str, str]]) -> Dict:
     if qaggs:
         aggs = [(c, op, o) for c, op, o in aggs
                 if not (op == "median" or op.startswith("quantile_"))]
-        out = reduce_table(t, aggs) if aggs else {}
+        out = reduce_table(t, aggs, site) if aggs else {}
         for c, op, o in qaggs:
             q = 0.5 if op == "median" else float(op[len("quantile_"):])
             out[o] = _reduce_quantile(t, c, q)
@@ -2766,7 +2778,7 @@ def reduce_table(t: Table, aggs: Sequence[Tuple[str, str, str]]) -> Dict:
     if gaggs:
         aggs = [(c, op, o) for c, op, o in aggs
                 if op in _REDUCE_PARTIALS]
-        out = reduce_table(t, aggs) if aggs else {}
+        out = reduce_table(t, aggs, site) if aggs else {}
         zeros = np.zeros((t.capacity,), np.int32)
         if t.distribution == ONED:
             kd = jax.device_put(zeros, mesh_mod.row_sharding())
@@ -2853,7 +2865,7 @@ def reduce_table(t: Table, aggs: Sequence[Tuple[str, str, str]]) -> Dict:
 
     counts_in = t.counts_device() if t.distribution == ONED \
         else jnp.asarray(t.nrows)
-    raw = jax.device_get(fn(t.device_data(), counts_in))
+    raw = host_read(site, fn(t.device_data(), counts_in))
     partials = [np.asarray(r).reshape(-1) for r in raw]
     out = {}
     for (col, op, oname), (off, parts) in zip(aggs, layout):
@@ -2918,12 +2930,12 @@ def _reduce_quantile(t: Table, col: str, q: float) -> float:
         fn = named_jit("reduce_quantile", body)
         _jit_cache[key] = fn
     s_val, cnt = fn(src.device_data(), jnp.asarray(src.nrows))
-    n = int(jax.device_get(cnt))
+    n = int(host_read("quantile", cnt))
     if n == 0:
         return float("nan")
     qpos = (n - 1) * q
     lo, hi = int(np.floor(qpos)), int(np.ceil(qpos))
-    vals = np.asarray(jax.device_get(s_val[lo:hi + 1]))
+    vals = np.asarray(host_read("quantile", s_val[lo:hi + 1]))
     out = float(vals[0]) if lo == hi else \
         float(vals[0] + (vals[1] - vals[0]) * (qpos - lo))
     if dt.is_decimal(src.column(col).dtype):
@@ -3072,8 +3084,8 @@ def shuffle_by_key(t: Table, key_cols: Sequence[str]) -> Table:
         karrays = tuple((t.column(n).data, t.column(n).valid)
                         for n in korder)
         out, cnts = fn(karrays, t.counts_device())
-        counts = np.asarray(jax.device_get(cnts)).reshape(-1).astype(
-            np.int64)
+        counts = np.asarray(host_read("shuffle_counts", cnts)).reshape(
+            -1).astype(np.int64)
         tree = {n: out[i] for i, n in enumerate(korder)}
         res = t.with_device_data(tree, nrows=int(counts.sum()),
                                  counts=counts)

@@ -316,7 +316,8 @@ class Table:
     def to_pandas(self) -> pd.DataFrame:
         from bodo_tpu.utils import tracing
         with tracing.event("to_pandas", rows=self.nrows):
-            t = self.gather() if self.distribution == ONED else self
+            t = self.gather(answer=True) if self.distribution == ONED \
+                else self
             out = {}
             with tracing.event("result.d2h"):
                 for name, col in t.columns.items():
@@ -357,7 +358,8 @@ class Table:
 
         def _scatter(arr, zero):
             if multi:
-                host = np.asarray(jax.device_get(arr))
+                from bodo_tpu.utils.tracing import host_read
+                host = np.asarray(host_read("scatter", arr))
 
                 def cb(idx):
                     sl = idx[0]
@@ -394,9 +396,12 @@ class Table:
                                     col.vrange)
         return Table(new_cols, self.nrows, ONED, counts)
 
-    def gather(self) -> "Table":
+    def gather(self, answer: bool = False) -> "Table":
         """1D -> REP: gather shards, trim padding, repack contiguous
-        (gatherv analogue, reference distributed_api.py:713)."""
+        (gatherv analogue, reference distributed_api.py:713). Every
+        column comes to the host in one read: a host sync (`gather`),
+        except where the gather is the answer's own copy (`answer`, from
+        `to_pandas`)."""
         if self.distribution == REP:
             return self
         from bodo_tpu.parallel import comm
@@ -404,17 +409,20 @@ class Table:
         with tracing.event("dist.gather", rows=self.nrows), \
                 comm.collective_span(
                     "gather", bytes_in=comm.table_bytes(self)) as _sp:
-            out = self._gather_inner()
+            tree = self.device_data()
+            out = self._gather_inner(
+                jax.device_get(tree) if answer
+                else tracing.host_read("gather", tree))
             _sp["bytes_out"] = comm.table_bytes(out)
         return out
 
-    def _gather_inner(self) -> "Table":
+    def _gather_inner(self, host_tree) -> "Table":
         s = self.num_shards
         per = self.shard_capacity
         cap = round_capacity(max(self.nrows, 1))
         new_cols = {}
         for name, col in self.columns.items():
-            host = np.asarray(jax.device_get(col.data))
+            host = np.asarray(host_tree[name][0])
             pieces = [host[i * per: i * per + int(self.counts[i])]
                       for i in range(s)]
             packed = np.concatenate(pieces) if pieces else host[:0]
@@ -422,7 +430,7 @@ class Table:
             padded[: self.nrows] = packed
             valid = None
             if col.valid is not None:
-                hv = np.asarray(jax.device_get(col.valid))
+                hv = np.asarray(host_tree[name][1])
                 vp = [hv[i * per: i * per + int(self.counts[i])]
                       for i in range(s)]
                 vpacked = np.concatenate(vp) if vp else hv[:0]

@@ -241,6 +241,38 @@ def event(name: str, **args):
                 span.set_metadata(**info)
 
 
+# host_read's calls by site since the process started (read as deltas)
+_sync_counts: Dict[str, int] = {}
+_sync_lock = threading.Lock()
+
+
+def host_read(site: str, x):
+    """`jax.device_get(x)` for any pytree, as one counted host sync: the
+    entry point for every read of a device value the engine makes to
+    decide its next step (a count, a flag, a key range). It drains the
+    device's queue, so the chip idles until the host dispatches again.
+    While a profiler session listens or tracing is on, the read is the
+    span `bodo:sync.<site>` (`event()`); always, it adds one to `site`'s
+    count in `sync_counts()`. With neither listening it formats no name
+    and builds no dict. The answer's own copy in `Table.to_pandas` is not
+    a sync and does not come through here."""
+    if is_tracing() or profiler_listening():
+        with event("sync." + site):
+            out = sys.modules["jax"].device_get(x)
+    else:
+        out = sys.modules["jax"].device_get(x)
+    with _sync_lock:
+        _sync_counts[site] = _sync_counts.get(site, 0) + 1
+    return out
+
+
+def sync_counts() -> Dict[str, int]:
+    """host_read's calls by site since the process started: take two
+    copies and subtract for the syncs of a span of work."""
+    with _sync_lock:
+        return dict(_sync_counts)
+
+
 def annotate(**args) -> None:
     """Add arguments to the innermost event open on this thread, from a
     frame that does not hold it (the route a kernel took, decided far

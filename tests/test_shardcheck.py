@@ -579,6 +579,22 @@ class TestLint:
         assert [f.rule for f in got] == ["stream-sync-unannotated"] * 2
         assert {f.func for f in got} == {"push"}
 
+    def test_stream_sync_host_read_unannotated(self, tmp_path):
+        # the engine's counted read is a sync like device_get: it needs
+        # the annotation too
+        got = self._lint_streaming_src(tmp_path, """
+            from bodo_tpu.utils import tracing
+
+            def push(self, batch):
+                n = int(tracing.host_read("stream.count", batch))
+                n += 1
+                m = int(tracing.host_read(  # dispatch-boundary
+                    "stream.count", batch))
+                return n + m
+        """)
+        assert [(f.rule, f.func) for f in got] == [
+            ("stream-sync-unannotated", "push")]
+
     def test_stream_sync_annotated_ok(self, tmp_path):
         # annotation on the call line, on an adjacent line, and after
         # the closing paren of a multi-line call all count

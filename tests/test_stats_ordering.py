@@ -459,10 +459,10 @@ def test_resident_key_range_is_reduced_once(mesh8):
     reduced = []
     orig = R.reduce_table
 
-    def spy(t, aggs):
+    def spy(t, aggs, **kw):
         reduced.extend(keys[id(c.data)] for c in t.columns.values()
                        if id(c.data) in keys)
-        return orig(t, aggs)
+        return orig(t, aggs, **kw)
     q = ("select cn, sum(amt) as s from fact, cust, supp "
          "where fk = ck and fs = sk and cn = sn group by cn")
     old = config.result_cache

@@ -32,11 +32,15 @@ Work split (who runs where):
     arrow), hybrid run-header walk. All O(pages), not O(values).
   * device: everything O(values) — bit unpack, run expansion, byte
     assembly, null scatter, dictionary gather — one jitted program per
-    (encoding, dtype, page-shape bucket) cached in
-    ``kernel_cache.DecodeProgramCache`` so page count, not page shape,
-    drives dispatch cost. Shapes bucket to powers of two to bound the
-    program population (XLA:CPU segfaults after thousands of pinned
-    executables; see utils/kernel_cache.py).
+    (encoding, dtype, page-shape bucket, page-count bucket, output
+    length) cached in ``kernel_cache.DecodeProgramCache``. A column
+    chunk's consecutive pages of one kind decode in ONE dispatch: their
+    operands stacked along a page axis and sent once, the page body
+    under ``vmap``, every page's values written straight to the column's
+    slots. Shapes bucket to powers of two (page counts past 16 and value
+    slots past 16,384 to four steps an octave) to bound the program
+    population (XLA:CPU segfaults after thousands of pinned executables;
+    see utils/kernel_cache.py).
 
 Decode kernels are jitted ``jnp`` bodies rather than raw Pallas: the
 decode is gather/cumsum/bitwise-bound (no MXU work), XLA lowers it well
@@ -59,7 +63,7 @@ import struct
 import threading
 import time
 from dataclasses import dataclass, field
-from typing import Dict, List, Optional, Sequence
+from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -393,21 +397,6 @@ def _bucket(n: int, lo: int = 16) -> int:
     return b
 
 
-def _pad_runs(rt: _RunTable, runs_bucket: int, sentinel: int) -> tuple:
-    """Pad run tables to the bucket; sentinel starts never win the
-    searchsorted, so padded runs are inert."""
-    k = len(rt.starts)
-    starts = np.full(runs_bucket, sentinel, np.int32)
-    starts[:k] = rt.starts
-    is_rle = np.zeros(runs_bucket, bool)
-    is_rle[:k] = rt.is_rle
-    vals = np.zeros(runs_bucket, np.int32)
-    vals[:k] = rt.vals
-    bits = np.zeros(runs_bucket, np.int32)
-    bits[:k] = rt.bits
-    return starts, is_rle, vals, bits
-
-
 # ---------------------------------------------------------------------------
 # jitted decode programs (cached per shape/encoding/dtype bucket)
 # ---------------------------------------------------------------------------
@@ -423,9 +412,10 @@ def _describe_spec(spec):
     return f"device_decode:{spec.kind}", {
         "dtype": spec.out_dtype,
         "shape": (spec.byte_bucket, spec.n_bucket, spec.def_runs,
-                  spec.val_runs, spec.dict_bucket),
+                  spec.val_runs, spec.dict_bucket, spec.n_pages, spec.out_len),
         "static": (spec.itemsize, spec.bit_width, spec.has_defs,
-                   spec.masked, spec.scale)}
+                   spec.masked, spec.scale, spec.want_valid,
+                   spec.accumulate)}
 
 
 _programs = DecodeProgramCache(subsystem="device_decode",
@@ -467,28 +457,37 @@ def clear_programs() -> None:
 
 @dataclass(frozen=True)
 class _PageSpec:
-    """Static configuration of one jitted page-decode program — the
-    decode-program cache key (encoding kind, output dtype, and the
-    power-of-two shape buckets)."""
+    """Static configuration of one jitted decode program for a run of
+    consecutive same-kind pages of a column chunk — the decode-program
+    cache key (encoding kind, output dtype, the element-wise largest of
+    the run's pages' power-of-two shape buckets, the page-count bucket
+    and the output length)."""
     kind: str            # 'plain' | 'dict' | 'boolplain' | 'boolrle'
     out_dtype: str       # numpy dtype name of the decoded values
     itemsize: int        # physical width for 'plain' (0 otherwise)
-    bit_width: int       # index/value bit width for hybrid kinds
+    bit_width: int       # widest bit width the run's gather window holds
     has_defs: bool       # definition levels present (optional column)
     masked: bool         # produce a validity mask + null scatter
     byte_bucket: int     # padded page-byte length
-    n_bucket: int        # padded output value count
+    n_bucket: int        # padded output value count of one page
     def_runs: int        # padded def-level run count
     val_runs: int        # padded value-stream run count (hybrid kinds)
     dict_bucket: int     # padded dictionary length (numeric dict gather)
     scale: int           # timestamp unit -> ns multiplier (1 otherwise)
+    n_pages: int         # page-count bucket of the run
+    out_len: int         # output length: the capacity's power of two
+    want_valid: bool     # return the validity mask beside the values
+    accumulate: bool     # take the chunk's earlier runs' output as input
 
 
 def _hybrid_expand_body(jnp, data, starts, is_rle, vals, bits, bw,
-                        n_bucket):
+                        n_bucket, width=None):
     """Device run expansion: RLE runs broadcast their value, bit-packed
     runs extract bw bits at bits[run] + (i - start)*bw through a
     little-endian gather window just wide enough for the bit width.
+    ``width``, where given, is the page's own traced bit width and ``bw``
+    the largest in its run, which sizes the window: a dictionary's index
+    width grows with the dictionary from page to page of one chunk.
 
     The output-index -> owning-run map exploits that the output domain
     is SORTED: scatter each run's index at its start position and take a
@@ -510,7 +509,8 @@ def _hybrid_expand_body(jnp, data, starts, is_rle, vals, bits, bw,
     # non-negative, so -1 is a free "take the unpacked bits" sentinel)
     rv = jnp.where(is_rle, vals, -1)[r]
     if bw > 0:
-        bp = (bits - starts * bw)[r] + i * bw
+        pw = bw if width is None else width
+        bp = (bits - starts * pw)[r] + i * pw
         byte0 = bp >> 3
         nb = data.shape[0]
         # ceil((7 + bw) / 8) bytes cover any bit phase: 1 byte for the
@@ -519,8 +519,10 @@ def _hybrid_expand_body(jnp, data, starts, is_rle, vals, bits, bw,
         for k in range(1, (bw + 14) // 8):
             w = w | (data[jnp.clip(byte0 + k, 0, nb - 1)]
                      .astype(jnp.uint32) << (8 * k))
+        mask = (1 << bw) - 1 if width is None else \
+            (jnp.uint32(1) << pw.astype(jnp.uint32)) - 1
         packed = ((w >> (bp & 7).astype(jnp.uint32))
-                  & ((1 << bw) - 1)).astype(jnp.int32)
+                  & mask).astype(jnp.int32)
     else:
         packed = jnp.zeros(n_bucket, jnp.int32)
     return jnp.where(rv >= 0, rv, packed)
@@ -554,9 +556,11 @@ def _assemble_plain_body(jnp, lax, data, val_off, itemsize, out_dtype,
 
 
 def _build_page_program(spec: _PageSpec):
-    """One jitted program decoding one page shape: def-level expansion,
-    value decode, null scatter, dtype conversion — a single dispatch per
-    page, no host round-trip. Traced-body rules apply (fusion_stage)."""
+    """One jitted program decoding a run of pages: the page body
+    (def-level expansion, value decode, null scatter, dtype conversion)
+    under ``vmap`` over the stacked pages, then every page's live values
+    written to its slots of the column — a single dispatch per run, no
+    host round-trip. Traced-body rules apply (fusion_stage)."""
     import jax
     import jax.numpy as jnp
     from jax import lax
@@ -565,15 +569,17 @@ def _build_page_program(spec: _PageSpec):
 
     out_np = np.dtype(spec.out_dtype)
     fill_nan = out_np.kind == "f"
+    has_vruns = spec.kind in _VAL_RUN_KINDS
 
     @fusion_stage
-    def _page_decode(data, n_values, dstarts, disrle, dvals, dbits,
-                     vstarts, visrle, vvals, vbits, val_off, dictvals):
+    def _one_page(data, n_values, druns, vruns, val_off, bit_width,
+                  dictvals):
         i = jnp.arange(spec.n_bucket, dtype=jnp.int32)
         in_rows = i < n_values
         if spec.has_defs:
             levels = _hybrid_expand_body(
-                jnp, data, dstarts, disrle, dvals, dbits, 1, spec.n_bucket)
+                jnp, data, druns[0], druns[1] > 0, druns[2], druns[3], 1,
+                spec.n_bucket)
             valid = (levels == 1) & in_rows
         else:
             valid = in_rows
@@ -591,8 +597,8 @@ def _build_page_program(spec: _PageSpec):
             vals_at = dense[pos]
         elif spec.kind == "dict":
             codes = _hybrid_expand_body(
-                jnp, data, vstarts, visrle, vvals, vbits, spec.bit_width,
-                spec.n_bucket)
+                jnp, data, vruns[0], vruns[1] > 0, vruns[2], vruns[3],
+                spec.bit_width, spec.n_bucket, width=bit_width)
             codes = codes[pos]
             # null rows carry raw code 0 (matches arrow_bridge's NaN->0
             # before the rank remap)
@@ -610,7 +616,8 @@ def _build_page_program(spec: _PageSpec):
                         >> (bits_i & 7).astype(jnp.uint8)) & 1) > 0
         elif spec.kind == "boolrle":
             dense = _hybrid_expand_body(
-                jnp, data, vstarts, visrle, vvals, vbits, 1, spec.n_bucket)
+                jnp, data, vruns[0], vruns[1] > 0, vruns[2], vruns[3], 1,
+                spec.n_bucket)
             vals_at = dense[pos] > 0
         else:  # pragma: no cover - spec construction guards this
             raise AssertionError(spec.kind)
@@ -626,6 +633,51 @@ def _build_page_program(spec: _PageSpec):
             out = jnp.where(valid, vals_at, jnp.zeros((), out_np))
         n_nulls = jnp.sum(in_rows & ~valid).astype(jnp.int32)
         return out, valid, n_nulls
+
+    @fusion_stage
+    def _page_decode(data, meta, *rest):
+        # operands: stacked page bytes [P, B], per-page (n_values,
+        # val_off, bit_width, first output slot) [P, 4], then the stacked
+        # run tables [P, 4, R] the kind reads, the chunk's dictionary,
+        # and the earlier runs' (values, nulls[, validity])
+        rest = list(rest)
+        druns = rest.pop(0) if spec.has_defs else None
+        vruns = rest.pop(0) if has_vruns else None
+        dictvals = rest.pop(0) if spec.dict_bucket else None
+        vals, valid, n_nulls = jax.vmap(
+            lambda d, m, dr, vr: _one_page(d, m[0], dr, vr, m[1], m[2],
+                                           dictvals),
+            in_axes=(0, 0, 0 if druns is not None else None,
+                     0 if vruns is not None else None))(
+            data, meta, druns, vruns)
+        # compaction: page p's value i lands in slot meta[p, 3] + i. A
+        # page's n_bucket-wide write spills its zero padding past its
+        # rows, which the next page's write covers; the buffer runs
+        # n_bucket past the output so no write is ever clamped, and
+        # padded pages (n_values 0) write zeros at the run's end
+        if spec.accumulate:
+            acc_vals, acc_nulls = rest[0], rest[1]
+        else:
+            acc_vals = jnp.zeros(spec.out_len, out_np)
+            acc_nulls = jnp.zeros((), jnp.int32)
+        tail = jnp.zeros(spec.n_bucket, out_np)
+        bufs = [jnp.concatenate([acc_vals, tail])]
+        pages = [vals]
+        if spec.want_valid:
+            acc_valid = rest[2] if spec.accumulate else \
+                jnp.zeros(spec.out_len, bool)
+            bufs.append(jnp.concatenate(
+                [acc_valid, jnp.zeros(spec.n_bucket, bool)]))
+            pages.append(valid)
+
+        def put(p, bufs):
+            return [lax.dynamic_update_slice(b, x[p], (meta[p, 3],))
+                    for b, x in zip(bufs, pages)]
+
+        bufs = lax.fori_loop(0, spec.n_pages, put, bufs)
+        # (values, nulls[, validity]): the order a later run takes them in
+        return ((bufs[0][:spec.out_len], acc_nulls + jnp.sum(n_nulls))
+                + tuple(b[:spec.out_len] for b in bufs[1:]))
 
     # stored into _programs (DecodeProgramCache) by _page_program
     # under its lock  # shardcheck: ignore[unregistered-jit]
@@ -649,48 +701,79 @@ def _page_program(spec: _PageSpec):
     return fn, True
 
 
-_ZERO_RUNS = 8  # run-table bucket floor
+# bucket floors of the small operands: run tables and a numeric
+# dictionary cost little device work past their live entries (a scatter
+# of the run starts, a gather from the table), so a high floor lets one
+# program serve files whose null patterns and dictionary sizes differ
+_RUNS_FLOOR = 64
+_DICT_FLOOR = 4096
+_VAL_RUN_KINDS = ("dict", "boolrle")
 
 
-def _run_page_program(spec: _PageSpec, page_bytes: bytes, n_values: int,
-                      def_runs: Optional[_RunTable],
-                      val_runs: Optional[_RunTable],
-                      val_off: int, dictvals: Optional[np.ndarray]):
-    """Dispatch one page through its cached program; returns device
-    (values[n_bucket], valid[n_bucket], n_nulls scalar)."""
-    import jax.numpy as jnp
+def _fine_bucket(n: int, lo: int, fine_from: int) -> int:
+    """Powers of two up to ``fine_from``, then four steps an octave: for
+    the sizes whose padding the device decodes, a run's page count (53
+    pages -> 56) and a page's value slots (20,000 -> 20,480). Past
+    ``fine_from`` the padding is device work worth saving, and rounding
+    up by at most a quarter keeps it small; below, one program serves
+    twice the sizes. The population stays logarithmic either way."""
+    b = _bucket(n, lo)
+    if b <= fine_from:
+        return b
+    step = b >> 3
+    return -(-n // step) * step
 
-    data = np.zeros(spec.byte_bucket, np.uint8)
-    data[:len(page_bytes)] = np.frombuffer(page_bytes, np.uint8)
-    sentinel = spec.n_bucket + 1
 
-    def runs_or_zero(rt, bucket):
+def _stack_runs(tables, n_pages: int, bucket: int,
+                sentinel: int) -> np.ndarray:
+    """Stack pages' run tables as int32 [n_pages, 4, bucket] rows
+    (starts, is_rle, vals, bits); sentinel starts never win the
+    run lookup, so padded runs and padded pages are inert."""
+    out = np.zeros((n_pages, 4, bucket), np.int32)
+    out[:, 0, :] = sentinel
+    for p, rt in enumerate(tables):
         if rt is None:
-            z = np.full(bucket, sentinel, np.int32)
-            return (z, np.zeros(bucket, bool), np.zeros(bucket, np.int32),
-                    np.zeros(bucket, np.int32))
-        return _pad_runs(rt, bucket, sentinel)
+            continue
+        k = len(rt.starts)
+        out[p, 0, :k] = rt.starts
+        out[p, 1, :k] = rt.is_rle
+        out[p, 2, :k] = rt.vals
+        out[p, 3, :k] = rt.bits
+    return out
 
-    ds, dr, dv, db = runs_or_zero(def_runs, spec.def_runs)
-    vs, vr, vv, vb = runs_or_zero(val_runs, spec.val_runs)
-    if spec.dict_bucket and dictvals is not None:
-        dpad = np.zeros(spec.dict_bucket, dictvals.dtype)
-        dpad[:len(dictvals)] = dictvals
-    else:
-        dpad = np.zeros(max(spec.dict_bucket, 1),
-                        np.dtype(spec.out_dtype) if spec.dict_bucket
-                        else np.int32)
+
+def _run_page_program(spec: _PageSpec, pages: Sequence["_Page"],
+                      first_slot: int, dict_dev, acc: tuple) -> tuple:
+    """Dispatch one run of pages through its cached program: stack each
+    operand along a leading page axis on the host, one transfer an
+    operand. Returns device (values[out_len], nulls[, validity[out_len]])
+    after the chunk's runs so far."""
+    n = len(pages)
+    data = np.zeros((spec.n_pages, spec.byte_bucket), np.uint8)
+    meta = np.zeros((spec.n_pages, 4), np.int32)
+    slot = first_slot
+    for p, pg in enumerate(pages):
+        data[p, :len(pg.data)] = np.frombuffer(pg.data, np.uint8)
+        meta[p] = (pg.num_values, pg.val_off, pg.bit_width, slot)
+        slot += pg.num_values
+    meta[n:, 3] = slot
+    sentinel = spec.n_bucket + 1
+    args_in = [data, meta]
+    if spec.has_defs:
+        args_in.append(_stack_runs([pg.def_runs for pg in pages],
+                                   spec.n_pages, spec.def_runs, sentinel))
+    if spec.kind in _VAL_RUN_KINDS:
+        args_in.append(_stack_runs([pg.val_runs for pg in pages],
+                                   spec.n_pages, spec.val_runs, sentinel))
+    if spec.dict_bucket:
+        args_in.append(dict_dev)
+    args_in.extend(acc)
     fn, compiled = _page_program(spec)
     t0 = time.perf_counter()
-    args_in = (jnp.asarray(data), np.int32(n_values),
-               jnp.asarray(ds), jnp.asarray(dr), jnp.asarray(dv),
-               jnp.asarray(db), jnp.asarray(vs), jnp.asarray(vr),
-               jnp.asarray(vv), jnp.asarray(vb), np.int32(val_off),
-               jnp.asarray(dpad))
     out = fn(*args_in)
     if compiled:
         h = _programs.handle_for(spec)
-        progcheck.check_jit(fn, args_in,
+        progcheck.check_jit(fn, tuple(args_in),
                             program=f"device_decode:{spec.kind}",
                             subsystem="device_decode", obs_handle=h)
         progcheck.mark_checked(h)
@@ -698,8 +781,8 @@ def _run_page_program(spec: _PageSpec, page_bytes: bytes, n_values: int,
             _programs.record_compile(f"device_decode:{spec.kind}",
                                      time.perf_counter() - t0,
                                      handle=_programs.handle_for(spec))
-    xobs.track_buffer(out[0], "device_decode")
-    xobs.track_buffer(out[1], "device_decode")
+    for x in (out[0],) + out[2:]:
+        xobs.track_buffer(x, "device_decode")
     return out
 
 
@@ -1051,89 +1134,121 @@ def _arrow_schema_of(md):
 # decode (consumer side): bundles -> device Tables
 # ---------------------------------------------------------------------------
 
-def _decode_column(rc: _RawColumn, cap: int) -> Column:
-    """Decode one column chunk's pages on device and assemble the padded
-    column. One program dispatch per page; concat + pad stay on device."""
+def _kind_runs(pages: List[_Page]) -> List[List[_Page]]:
+    """Consecutive pages of one kind: a chunk's dispatch units (one run,
+    or two where dictionary pages give way to PLAIN mid-chunk)."""
+    runs: List[List[_Page]] = []
+    for pg in pages:
+        if runs and runs[-1][0].kind == pg.kind:
+            runs[-1].append(pg)
+        else:
+            runs.append([pg])
+    return runs
+
+
+def _run_spec(rc: _RawColumn, run: List[_Page], cap: int,
+              accumulate: bool) -> _PageSpec:
+    """One run's program spec: the element-wise largest of its pages'
+    buckets (a page padded to a larger bucket decodes the same: run
+    starts past its rows are sentinels, values past n_values are
+    masked)."""
+    plan = rc.plan
+    stats_clean = plan.null_count == 0
+    masked = plan.max_def > 0 and not stats_clean
+    out_dtype = "int32" if plan.is_string else plan.out_dtype
+    kind = run[0].kind
+    n_bucket = max(_fine_bucket(pg.num_values, 128, 16384) for pg in run)
+    if kind == "plain":
+        itemsize = _PHYS_WIDTH[plan.phys]
+        # the value window, n_bucket * itemsize bytes from val_off, is
+        # most of the bytes a run sends: round up to a multiple of 4 KiB,
+        # not to a power of two
+        byte_need = max(max(len(pg.data), pg.val_off + n_bucket * itemsize)
+                        for pg in run)
+        byte_bucket = -(-byte_need // 4096) * 4096
+    elif kind == "dict":
+        itemsize = 0
+        byte_bucket = _bucket(max(len(pg.data) for pg in run) + 4, 4096)
+    else:
+        itemsize = 0
+        byte_bucket = _bucket(
+            max(max(len(pg.data), pg.val_off + n_bucket // 8 + 8)
+                for pg in run), 4096)
+    dict_bucket = 0
+    if kind == "dict" and rc.dictionary is not None and not plan.is_string:
+        dict_bucket = _bucket(len(rc.dictionary), _DICT_FLOOR)
+    # stats prove zero nulls -> every def level is 1, so the level
+    # expansion and the dense-position cumsum are identities: decode as
+    # if the pages had no def levels (the same stats trust that drops
+    # the validity mask)
+    has_defs = run[0].has_defs and not stats_clean
+
+    def runs_bucket(tables):
+        return max([_bucket(len(t.starts), _RUNS_FLOOR)
+                    for t in tables if t is not None] + [_RUNS_FLOOR])
+
+    bw = max(pg.bit_width for pg in run)
+    if kind == "dict" and bw:
+        # each page's own width is an operand: the program only needs the
+        # gather window's byte count, ceil((7 + bw) / 8), so key it by the
+        # widest width that many bytes hold (9, 17, 24)
+        bw = min(8 * ((bw + 14) // 8) - 7, _MAX_BITWIDTH)
+    return _PageSpec(
+        kind=kind, out_dtype=out_dtype, itemsize=itemsize, bit_width=bw,
+        has_defs=has_defs, masked=masked,
+        byte_bucket=byte_bucket, n_bucket=n_bucket,
+        def_runs=runs_bucket([pg.def_runs for pg in run])
+        if has_defs else _RUNS_FLOOR,
+        val_runs=runs_bucket([pg.val_runs for pg in run]),
+        dict_bucket=dict_bucket, scale=plan.scale,
+        n_pages=_fine_bucket(len(run), 1, 16),
+        out_len=_bucket(cap, 1024),
+        # mask presence must match arrow_bridge: floats never carry one
+        # (NaN is the null), others only when the chunk has nulls
+        want_valid=masked and np.dtype(out_dtype).kind != "f",
+        accumulate=accumulate)
+
+
+def _decode_column(rc: _RawColumn, cap: int) -> Tuple[Column, int]:
+    """Decode one column chunk's pages on device into the padded column:
+    one program dispatch per run of same-kind pages, each writing its
+    pages' values straight into the column's slots (after the chunk's
+    earlier runs). Returns the column and the number of dispatches."""
     import jax.numpy as jnp
 
     plan = rc.plan
-    parts = []
-    valid_parts = []
-    null_scalars = []
-    stats_clean = plan.null_count == 0
-    dict_numeric = rc.dictionary is not None and not plan.is_string
-    for pg in rc.pages:
-        masked = plan.max_def > 0 and not stats_clean
-        # stats prove zero nulls -> every def level is 1, so the level
-        # expansion and the dense-position cumsum are identities: decode
-        # as if the page had no def levels (the same stats trust that
-        # already drops the validity mask via stats_clean above)
-        skip_defs = pg.has_defs and stats_clean
-        n_bucket = _bucket(pg.num_values, 128)
-        if pg.kind == "plain":
-            itemsize = _PHYS_WIDTH[plan.phys]
-            byte_need = max(len(pg.data), pg.val_off + n_bucket * itemsize)
-            dict_bucket = 0
-        elif pg.kind == "dict":
-            itemsize = 0
-            byte_need = len(pg.data) + 4
-            dict_bucket = _bucket(len(rc.dictionary), 16) \
-                if dict_numeric else 0
-        else:
-            itemsize = 0
-            byte_need = max(len(pg.data), pg.val_off + n_bucket // 8 + 8)
-            dict_bucket = 0
-        spec = _PageSpec(
-            kind=pg.kind,
-            out_dtype=("int32" if plan.is_string else plan.out_dtype),
-            itemsize=itemsize, bit_width=pg.bit_width,
-            has_defs=pg.has_defs and not skip_defs, masked=masked,
-            byte_bucket=_bucket(byte_need, 4096),
-            n_bucket=n_bucket,
-            def_runs=_bucket(len(pg.def_runs.starts), _ZERO_RUNS)
-            if pg.def_runs is not None and not skip_defs else _ZERO_RUNS,
-            val_runs=_bucket(len(pg.val_runs.starts), _ZERO_RUNS)
-            if pg.val_runs is not None else _ZERO_RUNS,
-            dict_bucket=dict_bucket,
-            scale=plan.scale)
-        vals, valid, n_nulls = _run_page_program(
-            spec, pg.data, pg.num_values,
-            None if skip_defs else pg.def_runs, pg.val_runs,
-            pg.val_off, rc.dictionary if dict_numeric else None)
-        parts.append(vals[:pg.num_values])
-        valid_parts.append(valid[:pg.num_values])
-        null_scalars.append(n_nulls)
     out_np = np.dtype("int32" if plan.is_string else plan.out_dtype)
-    if not parts:
+    runs = _kind_runs(rc.pages)
+    acc: tuple = ()
+    dict_dev = None
+    slot = 0
+    for run in runs:
+        spec = _run_spec(rc, run, cap, accumulate=bool(acc))
+        if spec.dict_bucket and dict_dev is None:
+            # the chunk's numeric dictionary goes to the device once
+            dpad = np.zeros(spec.dict_bucket, rc.dictionary.dtype)
+            dpad[:len(rc.dictionary)] = rc.dictionary
+            dict_dev = jnp.asarray(dpad)
+        acc = _run_page_program(spec, run, slot, dict_dev, acc)
+        slot += sum(pg.num_values for pg in run)
+    valid = None
+    if not acc:
         data = jnp.zeros(cap, out_np)
-        valid_all = None
     else:
-        data = jnp.concatenate(parts) if len(parts) > 1 else parts[0]
-        n = data.shape[0]
-        if n < cap:
-            data = jnp.concatenate([data, jnp.zeros(cap - n, out_np)])
-        else:
+        data, n_nulls = acc[0], acc[1]
+        valid = acc[2] if spec.want_valid else None
+        if data.shape[0] != cap:
+            # the programs' output is the capacity's power of two, so
+            # that row groups of other sizes share them: the column is
+            # its head
             data = data[:cap]
-        valid_all = jnp.concatenate(valid_parts) \
-            if len(valid_parts) > 1 else valid_parts[0]
-        pad = cap - valid_all.shape[0]
-        if pad > 0:
-            valid_all = jnp.concatenate(
-                [valid_all, jnp.zeros(pad, bool)])
-        else:
-            valid_all = valid_all[:cap]
-    # mask presence must match arrow_bridge: floats never carry one
-    # (NaN is the null), others only when the chunk actually has nulls
+            valid = None if valid is None else valid[:cap]
     valid_out = None
-    if out_np.kind != "f" and plan.max_def > 0 and parts:
-        if stats_clean:
-            valid_out = None
-        elif plan.null_count is not None and plan.null_count > 0:
-            valid_out = valid_all
-        else:
-            total = sum(int(x) for x in np.asarray(
-                jnp.stack(null_scalars)))
-            valid_out = valid_all if total > 0 else None
+    if valid is not None:
+        if plan.null_count is not None and plan.null_count > 0:
+            valid_out = valid
+        elif int(tracing.host_read("decode_null_count", n_nulls)) > 0:
+            valid_out = valid
     dictionary = None
     if plan.is_string:
         raw_dict = rc.dictionary if rc.dictionary is not None \
@@ -1158,7 +1273,7 @@ def _decode_column(rc: _RawColumn, cap: int) -> Column:
                 remapped = lut[clipped]
             live = jnp.arange(cap, dtype=jnp.int32) < plan.num_values
             data = jnp.where(live, remapped, 0).astype(jnp.int32)
-    return Column(data, valid_out, plan.col_dtype, dictionary)
+    return Column(data, valid_out, plan.col_dtype, dictionary), len(runs)
 
 
 def decode_row_group(bundle: RawRowGroup,
@@ -1175,6 +1290,7 @@ def decode_row_group(bundle: RawRowGroup,
         bundle.nrows)
     cols: Dict[str, Column] = {}
     n_pages = 0
+    n_dispatches = 0
     dev_bytes = 0
     for name in bundle.names:
         rc = bundle.device_cols.get(name)
@@ -1182,8 +1298,9 @@ def decode_row_group(bundle: RawRowGroup,
             try:
                 with tracing.event("scan.column", pages=len(rc.pages),
                                    bytes=rc.raw_bytes):
-                    cols[name] = _decode_column(rc, cap)
+                    cols[name], n = _decode_column(rc, cap)
                 n_pages += len(rc.pages)
+                n_dispatches += n
                 dev_bytes += rc.plan.num_values * \
                     max(np.dtype(rc.plan.out_dtype).itemsize, 1)
                 continue
@@ -1214,6 +1331,7 @@ def decode_row_group(bundle: RawRowGroup,
     t = Table(cols, bundle.nrows, REP, None)
     t._device_decoded = bool(bundle.device_cols)
     io_pool.count("device_decode_pages", n_pages)
+    io_pool.count("device_decode_dispatches", n_dispatches)
     io_pool.count("device_decode_bytes", dev_bytes)
     io_pool.count("device_decode_cols", len(bundle.device_cols))
     io_pool.count("device_fallback_cols", len(set(bundle.host_cols)))

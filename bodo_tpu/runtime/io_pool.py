@@ -62,6 +62,7 @@ def _zero() -> dict:
         # device-side parquet decode (io/device_decode.py)
         "device_decode_s": 0.0,       # consumer-side on-chip decode time
         "device_decode_pages": 0,     # pages decoded by jitted programs
+        "device_decode_dispatches": 0,  # decode programs run (page runs)
         "device_decode_cols": 0,      # column chunks decoded on device
         "device_fallback_cols": 0,    # column chunks demoted to host
         "device_decode_errors": 0,    # planned-but-failed device decodes

@@ -162,11 +162,12 @@ class FusionProgramCache(KernelCache):
 
 class DecodeProgramCache(FusionProgramCache):
     """LRU of jitted parquet page-decode programs (io/device_decode.py),
-    keyed by the page spec: encoding kind x output dtype x power-of-two
-    shape buckets (page bytes, value count, run-table length, dictionary
-    length) x null handling x timestamp scale. Bucketing makes the live
-    program population a function of the SCHEMA, not the page count, so
-    a million-page scan dispatches a handful of executables. Shares the
+    keyed by the spec of a run of same-kind pages: encoding kind x
+    output dtype x power-of-two shape buckets (page bytes, value count,
+    run-table length, dictionary length) x page-count bucket x column
+    capacity x null handling x timestamp scale. Bucketing makes the live
+    program population a function of the SCHEMA and the row-group size,
+    not the page count. Shares the
     fusion cache's hit/miss/compile accounting (EXPLAIN ANALYZE, the
     metrics registry, and tracing.profile() read the same shape)."""
 

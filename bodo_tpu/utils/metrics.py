@@ -712,7 +712,7 @@ def sync_engine_metrics() -> None:
                     "stalls", "footer_hits", "footer_misses",
                     "parallel_units", "parallel_reads", "decode_batches",
                     "decode_bytes", "device_decode_pages",
-                    "device_decode_cols", "device_fallback_cols",
+                    "device_decode_dispatches", "device_decode_cols", "device_fallback_cols",
                     "device_decode_errors", "device_decode_bytes",
                     "host_decode_bytes", "raw_bytes"):
             g.labels(event=key).set(ios.get(key, 0))

@@ -636,8 +636,9 @@ def profile(query_id: Optional[str] = None) -> Dict[str, dict]:
     for key in ("prefetch_hits", "prefetch_streams", "prefetch_depth",
                 "stalls", "footer_hits", "footer_misses",
                 "parallel_units", "parallel_reads", "decode_batches",
-                "device_decode_pages", "device_decode_cols",
-                "device_fallback_cols", "device_decode_errors"):
+                "device_decode_pages", "device_decode_dispatches",
+                "device_decode_cols", "device_fallback_cols",
+                "device_decode_errors"):
         counters[f"io:{key}"] = ios.get((key,), 0)
     # time-valued io rows: decode seconds (worker-side), consumer stall
     # seconds, and the decode time hidden behind compute
@@ -655,14 +656,15 @@ def profile(query_id: Optional[str] = None) -> Dict[str, dict]:
                              "total_s": io_s.get(("overlap",), 0.0),
                              "max_s": 0.0, "rows": 0,
                              "ratio": round(ratio, 4)}
-    # device-side parquet decode: page programs dispatched, on-chip
-    # decode seconds, decoded bytes, and the device fraction of all
-    # decoded scan output
+    # device-side parquet decode: pages decoded, the programs dispatched
+    # for them (one a run of same-kind pages), on-chip decode seconds,
+    # decoded bytes, and the device fraction of all decoded scan output
     if ios.get(("device_decode_pages",)) or \
             ios.get(("device_fallback_cols",)):
         frac = series("bodo_tpu_scan_device_decode_frac").get((), 0.0)
         out["io:device_decode"] = {
             "count": int(ios.get(("device_decode_pages",), 0)),
+            "dispatches": int(ios.get(("device_decode_dispatches",), 0)),
             "total_s": io_s.get(("device_decode",), 0.0),
             "max_s": 0.0, "rows": 0,
             "bytes": int(ios.get(("device_decode_bytes",), 0)),

@@ -11,8 +11,14 @@ as units, multi-page/multi-row-group stitching, dict-page spill,
 codecs, the BODO_TPU_DEVICE_DECODE toggle, observability counters
 (io_stats / tracing.profile() / prometheus gauge), and the
 distribution sweep through the frontend.
+
+Every device read also checks the dispatch count: one decode program a
+run of consecutive same-kind pages of a column chunk
+(``device_decode_dispatches``), over all of the chunk's pages
+(``device_decode_pages``).
 """
 
+import itertools
 import os
 
 import numpy as np
@@ -29,6 +35,15 @@ from bodo_tpu.io import read_parquet
 from bodo_tpu.io.arrow_bridge import arrow_to_table
 from bodo_tpu.io.parquet import clear_footer_cache, footer_metadata
 from bodo_tpu.runtime import io_pool
+
+
+@pytest.fixture(scope="module", autouse=True)
+def _fresh_programs():
+    """The module starts and ends with no decode program and the whole
+    compile budget, whatever ran before it in the process."""
+    dd.clear_programs()
+    yield
+    dd.clear_programs()
 
 
 @pytest.fixture(autouse=True)
@@ -137,9 +152,25 @@ def test_hybrid_inexact_stream():
 # per-encoding parity (device + host-fallback routes vs pyarrow)
 # ---------------------------------------------------------------------------
 
-def _roundtrip(tmp_path, df, expect_fallback=0, **writer_kw):
-    path = str(tmp_path / "t.parquet")
-    df.to_parquet(path, engine="pyarrow", index=False, **writer_kw)
+def _page_runs(path):
+    """(pages, runs of consecutive same-kind pages) over the chunks the
+    page walker ships to the device: the decode counters' expectation."""
+    md = footer_metadata(path)
+    pages = runs = 0
+    kinds_by_col = {}
+    for rg in range(md.num_row_groups):
+        bundle = dd.fetch_row_group(path, rg, None, inject=False)
+        for name, rc in bundle.device_cols.items():
+            kinds = [k for k, _ in itertools.groupby(
+                p.kind for p in rc.pages)]
+            kinds_by_col.setdefault(name, []).append(kinds)
+            pages += len(rc.pages)
+            runs += len(kinds)
+    return pages, runs, kinds_by_col
+
+
+def _check_read(path, expect_fallback=0):
+    pages, runs, kinds = _page_runs(path)
     io_pool.reset_io_stats()
     t = read_parquet(path)
     _assert_table_parity(t, path)
@@ -149,16 +180,151 @@ def _roundtrip(tmp_path, df, expect_fallback=0, **writer_kw):
         assert st["device_decode_pages"] > 0
         assert st["device_decode_frac"] == 1.0
     assert st["device_decode_errors"] == 0
+    assert st["device_decode_pages"] == pages
+    assert st["device_decode_dispatches"] == runs
+    st["kinds"] = kinds
     return st
 
 
-def test_parity_dictionary(tmp_path):
-    _roundtrip(tmp_path, _mixed_frame(3000))
+def _roundtrip(tmp_path, df, expect_fallback=0, **writer_kw):
+    path = str(tmp_path / "t.parquet")
+    df.to_parquet(path, engine="pyarrow", index=False, **writer_kw)
+    return _check_read(path, expect_fallback)
 
 
-def test_parity_plain(tmp_path):
-    _roundtrip(tmp_path, _mixed_frame(3000).drop(columns=["s"]),
-               use_dictionary=False)
+# chunks of one page each: one dispatch a column chunk
+SINGLE_PAGE = {
+    "dictionary": (lambda: _mixed_frame(3000), {}),
+    "plain": (lambda: _mixed_frame(3000).drop(columns=["s"]),
+              {"use_dictionary": False}),
+    "def_levels": (lambda: _mixed_frame(3000, nulls=True), {}),
+}
+
+
+@pytest.mark.parametrize("case", list(SINGLE_PAGE))
+def test_parity_single_page(tmp_path, case):
+    make, kw = SINGLE_PAGE[case]
+    df = make()
+    st = _roundtrip(tmp_path, df, **kw)
+    assert st["device_decode_pages"] == st["device_decode_dispatches"] \
+        == df.shape[1]
+
+
+def _nullable_frame(n, seed):
+    rng = np.random.default_rng(seed)
+    miss = rng.random((4, n)) < 0.2
+    return pa.table({
+        "i64": pa.array(rng.integers(-10**12, 10**12, n), mask=miss[0]),
+        "f64": pa.array(rng.standard_normal(n), mask=miss[1]),
+        "s": pa.array(rng.choice(["alpha", "beta", "gamma", "delta"], n),
+                      mask=miss[2]),
+        "ts": pa.array(rng.integers(0, 10**18, n), pa.timestamp("ns"),
+                       mask=miss[3]),
+        "i32": rng.integers(-10**6, 10**6, n).astype(np.int32),
+    })
+
+
+def _dict_spill_frame(n, seed):
+    # ~4,000 distinct values a column overflow a 4 KiB dictionary page
+    # limit partway through the chunk: dictionary pages, then PLAIN
+    rng = np.random.default_rng(seed)
+    return pa.table({
+        "f64": rng.integers(0, 4000, n) / 7.0,
+        "i64": rng.integers(0, 4000, n),
+        "ts": pa.array(rng.integers(0, 4000, n) * 10**9,
+                       pa.timestamp("ns")),
+    })
+
+
+def _bool_frame(n, seed):
+    rng = np.random.default_rng(seed)
+    return pa.table({
+        "b": rng.integers(0, 2, n).astype(bool),
+        "runs": np.repeat([True, False, True], [n // 3, n // 3,
+                                                 n - 2 * (n // 3)]),
+        "bn": pa.array(rng.integers(0, 2, n).astype(bool),
+                       mask=rng.random(n) < 0.2),
+    })
+
+
+def _time_frame(n, seed):
+    rng = np.random.default_rng(seed)
+    return pa.table({
+        "ts_us": pa.array(rng.integers(0, 10**15, n), pa.timestamp("us"),
+                          mask=rng.random(n) < 0.1),
+        "ts_ms": pa.array(rng.integers(0, 10**12, n), pa.timestamp("ms")),
+        "d": pa.array(rng.integers(0, 20000, n).astype(np.int32),
+                      pa.date32()),
+    })
+
+
+def _string_frame(n, seed):
+    rng = np.random.default_rng(seed)
+    words = np.array([f"zone_{i:03d}" for i in range(179)])
+    return pa.table({
+        "s": pa.array(rng.choice(words, n), mask=rng.random(n) < 0.1),
+        "lic": rng.choice(["HV0002", "HV0003", "HV0004", "HV0005"], n),
+    })
+
+
+# small pages, so one column chunk holds many: (table, writer options,
+# the kinds of the runs every chunk must show, or None)
+_PAGES = {"max_rows_per_page": 400, "write_batch_size": 100}
+MANY_PAGES = {
+    "dict_to_plain": (_dict_spill_frame, dict(
+        _PAGES, dictionary_pagesize_limit=4096), ["dict", "plain"]),
+    "nulls_v1_stats": (_nullable_frame, dict(
+        _PAGES, data_page_version="1.0"), None),
+    "nulls_v1_nostats": (_nullable_frame, dict(
+        _PAGES, data_page_version="1.0", write_statistics=False), None),
+    "nulls_v2_stats": (_nullable_frame, dict(
+        _PAGES, data_page_version="2.0"), None),
+    "nulls_v2_nostats": (_nullable_frame, dict(
+        _PAGES, data_page_version="2.0", write_statistics=False), None),
+    "bool_plain": (_bool_frame, dict(
+        _PAGES, use_dictionary=False,
+        column_encoding={c: "PLAIN" for c in ("b", "runs", "bn")}),
+        ["boolplain"]),
+    "bool_rle": (_bool_frame, dict(
+        _PAGES, use_dictionary=False,
+        column_encoding={c: "RLE" for c in ("b", "runs", "bn")}),
+        ["boolrle"]),
+    "timestamp_scale": (_time_frame, dict(_PAGES, use_dictionary=False),
+                        ["plain"]),
+    "strings": (_string_frame, dict(_PAGES, row_group_size=3000),
+                ["dict"]),
+}
+
+
+def _write_many_pages(tmp_path, case, n=5000, seed=0):
+    make, kw, _ = MANY_PAGES[case]
+    path = str(tmp_path / f"mp_{case}_{seed}.parquet")
+    papq.write_table(make(n, seed), path, **kw)
+    return path
+
+
+@pytest.mark.parametrize("case", list(MANY_PAGES))
+def test_parity_many_pages(tmp_path, case):
+    """Many pages a chunk decode in one dispatch a run of same-kind
+    pages and equal pyarrow's read: nulls in v1 and v2 pages with and
+    without statistics, dictionary pages that give way to PLAIN, both
+    boolean encodings, scaled timestamps, strings. Each case's schema
+    compiles programs of its own, so each starts from an empty cache."""
+    dd.clear_programs()
+    st = _check_read(_write_many_pages(tmp_path, case))
+    assert st["device_decode_pages"] > 2 * st["device_decode_dispatches"]
+    want = MANY_PAGES[case][2]
+    if want is not None:
+        for name, chunks in st["kinds"].items():
+            assert all(k == want for k in chunks), (name, chunks)
+
+
+def test_parity_one_page_chunk(tmp_path):
+    """A chunk of exactly one page is a run of one page."""
+    path = str(tmp_path / "one.parquet")
+    papq.write_table(_nullable_frame(300, 1), path)
+    st = _check_read(path)
+    assert st["device_decode_pages"] == st["device_decode_dispatches"] == 5
 
 
 def test_parity_rle_bool_v2(tmp_path):
@@ -186,10 +352,6 @@ def test_arrow_schema_cache_pins_metadata(tmp_path):
         ent = dd._arrow_schema_cache[id(md)]
     assert ent[0] is md  # pinned: id(md) stays unique while cached
     assert dd._arrow_schema_of(md).names == ["x"]
-
-
-def test_parity_def_levels(tmp_path):
-    _roundtrip(tmp_path, _mixed_frame(3000, nulls=True))
 
 
 def test_fallback_delta_binary_packed(tmp_path):
@@ -342,6 +504,36 @@ def test_program_cache_reuse(tmp_path):
         read_parquet(path)
     st = dd.decode_program_stats()
     assert st["hits"] > st["misses"]
+
+
+def test_program_population_within_budget(tmp_path):
+    """A decode program is keyed by its run's page-count bucket and the
+    column's capacity besides the page shape. Over this module's
+    multi-file reads (every many-page case, the single-page files, the
+    codecs, many pages over row groups) the population stays inside the
+    process's compile budget, so no read is demoted to the host for want
+    of a program."""
+    dd.clear_programs()
+    io_pool.reset_io_stats()
+    for case in MANY_PAGES:
+        read_parquet(_write_many_pages(tmp_path, case))
+    path = str(tmp_path / "rg.parquet")
+    _mixed_frame(9000, nulls=True).to_parquet(
+        path, index=False, row_group_size=2500, data_page_size=2048)
+    read_parquet(path)
+    for case, (make, kw) in SINGLE_PAGE.items():
+        path = str(tmp_path / f"one_{case}.parquet")
+        make().to_parquet(path, index=False, **kw)
+        read_parquet(path)
+    for codec in ("NONE", "gzip", "zstd"):
+        path = str(tmp_path / f"codec_{codec}.parquet")
+        _mixed_frame(2000, nulls=True).to_parquet(
+            path, index=False, compression=codec)
+        read_parquet(path)
+    st = io_pool.io_stats()
+    assert st["device_decode_errors"] == 0
+    assert st["device_fallback_cols"] == 0
+    assert dd.decode_program_stats()["budget_left"] > 0
 
 
 def test_streaming_batches_flagged(tmp_path):

@@ -202,6 +202,9 @@ _stats = {"groups_planned": 0, "groups_executed": 0, "stream_chains": 0,
           # build gathered inside its program (`exchange_inprogram`)
           "exchange_shuffle": 0, "exchange_broadcast": 0,
           "exchange_inprogram": 0,
+          # runs of a sharded join again after its output bucket
+          # overflowed, at the exact count (`join_overflow_retried`)
+          "join_overflow_retries": 0,
           # group-bys by the realisation they took (`groupby_route`)
           "groupby_dense": 0, "groupby_packed": 0, "groupby_hashed": 0,
           "groupby_sort": 0, "groupby_fused": 0,
@@ -332,6 +335,15 @@ def exchange_inprogram(rows: int) -> None:
     arguments `exchange` and `build_rows` of the group's span."""
     _stats["exchange_inprogram"] += 1
     tracing.annotate(exchange="inprogram", build_rows=rows)
+
+
+def join_overflow_retried(retries: int) -> None:
+    """A sharded join (`relational._join_sharded`) whose first output
+    bucket, twice a probe shard's capacity, overflowed and which ran
+    again at the exact count: counted under `join_overflow_retries`, and
+    written as the argument `overflow_retries` of the join's span."""
+    _stats["join_overflow_retries"] += retries
+    tracing.annotate(overflow_retries=retries)
 
 
 def join_build_skipped() -> None:

@@ -36,7 +36,7 @@ from bodo_tpu.parallel.shuffle import (_mesh_key, _MESHES, groupby_sharded,
 from bodo_tpu.plan.expr import Expr, eval_expr, infer_dtype
 from bodo_tpu.plan.fusion import (exchange, fusion_stage, groupby_route,
                                   join_build_skipped, join_emitted,
-                                  join_route)
+                                  join_overflow_retried, join_route)
 from bodo_tpu.table import dtypes as dt
 from bodo_tpu.table.dict_utils import unify_dictionaries
 from bodo_tpu.table.table import Column, ONED, REP, Table, round_capacity
@@ -2188,6 +2188,7 @@ def _join_sharded(left, right, left_on, right_on, how, suffixes,
         bcounts = right.counts_device()
     sig_key = (_sig(left), _sig(right))
     method = "hash" if config.hash_join else "sort"
+    retries = 0
     for attempt in range(4):
         fn = _build_join_sharded_fn(_mesh_key(m), nk, how, out_cap,
                                     broadcast, sig_key, null_equal,
@@ -2207,8 +2208,11 @@ def _join_sharded(left, right, left_on, right_on, how, suffixes,
         exact = np.asarray(host_read(
             "join_count", cfn(pa, ba, left.counts_device(), bcounts)))
         out_cap = round_capacity(int(exact.max()))
+        retries += 1
     else:
         raise RuntimeError("join output overflow after exact-count retry")
+    if retries:
+        join_overflow_retried(retries)
     counts = np.asarray(host_read("probe_count", cnts)).reshape(-1).astype(
         np.int64)
     res = _assemble_join(left, right, left_on, right_on, lorder, rorder,
@@ -3024,7 +3028,9 @@ def _build_shuffle_fn(mesh_key, nk: int, cap: int, sig_key, has_valid):
     """The shard_map program of `shuffle_by_key`: the first `nk` arrays
     are the keys, `cap` a shard's capacity and every bucket's (a shard
     can receive at most what all hold, so no bucket overflows), and
-    `has_valid` says which arrays carry a null mask."""
+    `has_valid` says which arrays carry a null mask. Besides the rows it
+    gives each shard's count of what it received and of the rows it kept
+    (hashed to itself: the only ones that cross no chip)."""
     key = ("shuffle", mesh_key, sig_key, nk, cap)
     fn = _jit_cache.get(key)
     if fn is not None:
@@ -3037,10 +3043,12 @@ def _build_shuffle_fn(mesh_key, nk: int, cap: int, sig_key, has_valid):
         dest = dest_shard(hash_columns(arrs[:nk]), S)
         flat, _ = _flatten_with_valids(arrs)
         out, cnt2, _ = shuffle_rows(dest, flat, counts[0], S, cap, ax)
-        return _rebuild_from_flat(out, has_valid), cnt2[None]
+        kept = jnp.sum((dest == jax.lax.axis_index(ax))
+                       & K.row_mask(counts[0], cap))
+        return _rebuild_from_flat(out, has_valid), cnt2[None], kept[None]
 
     fn = named_jit("shuffle_by_key", C.smap(
-        body, in_specs=(P(ax), P(ax)), out_specs=(P(ax), P(ax)),
+        body, in_specs=(P(ax), P(ax)), out_specs=(P(ax), P(ax), P(ax)),
         mesh=mesh))
     _jit_cache[key] = fn
     return fn
@@ -3049,7 +3057,13 @@ def _build_shuffle_fn(mesh_key, nk: int, cap: int, sig_key, has_valid):
 def shuffle_by_key(t: Table, key_cols: Sequence[str]) -> Table:
     """Hash-partition rows over the mesh by key columns (the standalone
     shuffle_table analogue, reference bodo/libs/_shuffle.h:41). Rows with
-    equal keys land on the same shard."""
+    equal keys land on the same shard. Its span `bodo:shuffle_by_key`
+    says what moved: `rows` given, `rows_crossed` (those hashed to
+    another shard than their own), `a2a_bytes` (the whole `all_to_all`
+    buffer, every bucket at a shard's capacity, padding included),
+    `rows_max` / `rows_mean` a shard holds after it, and `columns`, the
+    names it carried, one space apart (a comma would end the profiler's
+    argument)."""
     if t.distribution != ONED:
         from bodo_tpu.analysis.plan_validator import PlanInvariantError
         raise PlanInvariantError(
@@ -3083,9 +3097,18 @@ def shuffle_by_key(t: Table, key_cols: Sequence[str]) -> Table:
             tuple(t.column(n).valid is not None for n in korder))
         karrays = tuple((t.column(n).data, t.column(n).valid)
                         for n in korder)
-        out, cnts = fn(karrays, t.counts_device())
-        counts = np.asarray(host_read("shuffle_counts", cnts)).reshape(
-            -1).astype(np.int64)
+        out, cnts, kept = fn(karrays, t.counts_device())
+        counts, kept = host_read("shuffle_counts", (cnts, kept))
+        counts = np.asarray(counts).reshape(-1).astype(np.int64)
+        if ev is not None:
+            S, cap = len(counts), t.shard_capacity
+            row_bytes = sum(a.dtype.itemsize for d, v in karrays
+                            for a in (d, v) if a is not None)
+            ev.update(rows_crossed=t.nrows - int(np.sum(kept)),
+                      a2a_bytes=S * S * cap * row_bytes,
+                      rows_max=int(counts.max()),
+                      rows_mean=float(counts.mean()),
+                      columns=" ".join(korder))
         tree = {n: out[i] for i, n in enumerate(korder)}
         res = t.with_device_data(tree, nrows=int(counts.sum()),
                                  counts=counts)

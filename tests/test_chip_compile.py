@@ -2,9 +2,9 @@
 ops/pallas_kernels.py at the shapes chip_smoke.py gives it, the
 multi-key sort, the dense aggregate tail at TPC-H Q1's shape, the
 group-by of TPC-H Q18's float64 key and its hash probe, and the programs
-that carry a join across the four chips of cell `tpch_q5_4chip`
-(`shuffle_by_key`, `join_sharded` shuffled and broadcast,
-`join_count_sharded`, and the LUT join's `join_probe_dense`,
+that carry a join across the four chips of cells `tpch_q5_4chip` and
+`tpch_q9_4chip` (`shuffle_by_key`, `join_sharded` shuffled and
+broadcast, `join_count_sharded`, and the LUT join's `join_probe_dense`,
 `join_probe_hash` and `join_probe_emit` once a shard), compiled for a
 described `v5e:2x2`.
 
@@ -327,46 +327,76 @@ def _timed(lowered, ceiling):
 
 
 def _keyed(make, rows, keys, payload):
-    """`keys` int64 key columns first, then float64 payload columns."""
+    """`keys` int64 key columns first, then the payload: a count of
+    float64 columns, or a tuple of (dtype, has a null mask) a column."""
+    if isinstance(payload, int):
+        payload = ((jnp.float64, False),) * payload
     return tuple((make(rows, jnp.int64), None) for _ in range(keys)) + \
-        tuple((make(rows, jnp.float64), None) for _ in range(payload))
+        tuple((make(rows, dtype), make(rows, jnp.bool_) if valid else None)
+              for dtype, valid in payload)
 
 
 # a shard of the sharded side of Q5's last join (0.123 rows an order)
 # and of the side it meets (0.10): the pair at the top step, one key at
-# the next
+# the next, with (shuffled, left, right) payloads of 4 - keys, 2 and 1
+# float64 columns
 LAST_JOIN = [("pair_top", 2, _cap(int(0.123 * ORDERS_TOP)),
-              _cap(int(0.10 * ORDERS_TOP))),
+              _cap(int(0.10 * ORDERS_TOP)), 2, 2, 1),
              ("one_key_next", 1, _cap(int(0.123 * ORDERS_NEXT)),
-              _cap(int(0.10 * ORDERS_NEXT)))]
+              _cap(int(0.10 * ORDERS_NEXT)), 3, 2, 1)]
+
+# cell `tpch_q9_4chip` (6,000,000 orders): the join on the pair
+# (suppkey, partkey) of the green parts' lineitem rows, which carry
+# nation, supplier and part columns from three LUT joins (a null mask
+# on each), with `partsupp`. A shard before the shuffle holds up to
+# 327,296 of those rows and 800,000 of `partsupp`, after it 329,216
+# and 800,256 (the generator's keys, hashed as the engine hashes them)
+I64, I32, F64 = jnp.int64, jnp.int32, jnp.float64
+Q9_LEFT = ((I64, True), (I32, True), (I64, True), (I64, True), (I64, False),
+           (F64, False), (F64, False), (F64, False), (I64, True))
+Q9_RIGHT = ((F64, False),)
+# the last of a case is the bound on the program's temporaries: four
+# buckets of `cap` rows a column, sent and received, under 1 GiB; beside
+# them `partition_rank`'s three [cap, 1] operands, laid out 128 lanes
+# wide at 512 bytes a row (ops/pallas_kernels.py `_PADDED_ROW_BYTES`),
+# which at partsupp's 800,000 rows are 1.23 GB of the 1.25 GB it holds
+SHUFFLES = [c[:3] + (c[4], 1 << 30) for c in LAST_JOIN] + [
+    ("q9_lineitem_top", 2, 327_296, Q9_LEFT, 1 << 30),
+    ("q9_partsupp_top", 2, 800_000, Q9_RIGHT, 2 << 30)]
+SHUFFLED_JOINS = [c[:4] + c[5:] for c in LAST_JOIN] + [
+    ("q9_pair_top", 2, 329_216, 800_256, Q9_LEFT, Q9_RIGHT)]
 
 
-@pytest.mark.parametrize("keys,cap", [c[1:3] for c in LAST_JOIN],
-                         ids=[c[0] for c in LAST_JOIN])
-def test_shuffle_by_key_compiles_for_four_v5e(four_chips, keys, cap):
+@pytest.mark.parametrize("keys,cap,payload,temp",
+                         [c[1:] for c in SHUFFLES],
+                         ids=[c[0] for c in SHUFFLES])
+def test_shuffle_by_key_compiles_for_four_v5e(four_chips, keys, cap,
+                                              payload, temp):
     """Hash of the 64-bit keys, `partition_rank`, the `all_to_all` of
     four buckets of a shard's capacity, the compaction of what came."""
     from bodo_tpu import relational as R
     mesh_key, sharded, _ = four_chips
+    arrays = _keyed(sharded, 4 * cap, keys, payload)
     fn = R._build_shuffle_fn(mesh_key, keys, cap, ("chip_compile", cap),
-                             (False,) * 4)
-    compiled = _timed(fn.lower(_keyed(sharded, 4 * cap, keys, 4 - keys),
-                               sharded(4, jnp.int64)), SHUFFLE_CEILING_S)
+                             tuple(v is not None for _, v in arrays))
+    compiled = _timed(fn.lower(arrays, sharded(4, jnp.int64)),
+                      SHUFFLE_CEILING_S)
     text = compiled.as_text()
     assert "all-to-all" in text and "tpu_custom_call" in text
-    # four buckets of `cap` rows a column, sent and received
-    assert compiled.memory_analysis().temp_size_in_bytes < (1 << 30)
+    assert compiled.memory_analysis().temp_size_in_bytes < temp
 
 
-@pytest.mark.parametrize("keys,cap,bcap", [c[1:] for c in LAST_JOIN],
-                         ids=[c[0] for c in LAST_JOIN])
-def test_join_sharded_compiles_for_four_v5e(four_chips, keys, cap, bcap):
+@pytest.mark.parametrize("keys,cap,bcap,lpay,rpay",
+                         [c[1:] for c in SHUFFLED_JOINS],
+                         ids=[c[0] for c in SHUFFLED_JOINS])
+def test_join_sharded_compiles_for_four_v5e(four_chips, keys, cap, bcap,
+                                            lpay, rpay):
     """Both sides co-located by the shuffle: `join_local` under
     `shard_map`, and the exact count its overflow retry asks for."""
     from bodo_tpu import relational as R
     mesh_key, sharded, _ = four_chips
-    args = (_keyed(sharded, 4 * cap, keys, 2),
-            _keyed(sharded, 4 * bcap, keys, 1),
+    args = (_keyed(sharded, 4 * cap, keys, lpay),
+            _keyed(sharded, 4 * bcap, keys, rpay),
             sharded(4, jnp.int64), sharded(4, jnp.int64))
     sig = ("chip_compile", cap, bcap)
     fn = R._build_join_sharded_fn(mesh_key, keys, "inner", 2 * cap, False,
